@@ -3,11 +3,9 @@
 One definition of the Higgs-shaped dataset (was duplicated between bench.py
 and helpers/prof_grow.py, with silently different feature distributions —
 their numbers were not comparable). bench.py re-exports
-:func:`make_higgs_like`, so existing ``from bench import make_higgs_like``
-call sites (helpers/tpu_bringup.py stages) keep working.
+:func:`make_higgs_like`; chip_smoke.py imports it from here.
 
-Stdlib + numpy only: importable from the bench orchestrator process, which
-must never touch jax.
+Stdlib + numpy only.
 """
 from __future__ import annotations
 

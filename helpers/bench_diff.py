@@ -42,9 +42,7 @@ Usage (also wired as ``helpers/check.sh --bench-diff``):
 
 ``--self-test`` runs the golden fixtures under tests/golden/bench_diff/:
 the synthetic ~10% regression must FAIL and the improvement must PASS —
-the gate gating itself. helpers/tpu_bringup.py imports :func:`compare` to
-stamp every bringup round with a regression verdict vs the previous
-BENCH_TPU.json.
+the gate gating itself.
 
 Stdlib only (no jax, no numpy): runs in driver processes that must never
 touch a backend.

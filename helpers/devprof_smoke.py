@@ -1,8 +1,6 @@
 """Device-timeline smoke: capture -> parse -> verdict in ONE invocation.
 
-Wired as ``helpers/check.sh --devprof`` and as the ``devprof`` bringup
-stage (helpers/tpu_bringup.py runs this file by path, driver stays
-jax-free). What it proves, end to end, on whatever backend is present:
+Wired as ``helpers/check.sh --devprof``. What it proves, end to end, on whatever backend is present:
 
  1. a scoped ``devprof.capture()`` window around real (already-compiled)
     boosting iterations emits a parseable XLA profile;
@@ -16,8 +14,8 @@ jax-free). What it proves, end to end, on whatever backend is present:
     ``device_timeline`` section lands in run_report(), and obs/report.py
     renders the section into HTML.
 
-Exit 0 and a final compact JSON line on success (the bringup stage
-records it into TPU_BRINGUP.json); exit 1 with the reason otherwise.
+Exit 0 and a final compact JSON line on success; exit 1 with the reason
+otherwise.
 """
 import json
 import os

@@ -29,7 +29,7 @@ HARD FAILURES: wrong reshard count/labels, wrong restart count, a missing
 or duplicated ulp warning, structural divergence or prefix/byte mismatch,
 or a controller that does not finish with rc 0.
 
-The last stdout line is a JSON result for helpers/tpu_bringup.py.
+The last stdout line is a JSON result.
 """
 import json
 import os

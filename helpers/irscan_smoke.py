@@ -1,9 +1,7 @@
 """graftir smoke: real-program scan + seeded-violation self-check in ONE
 invocation.
 
-Wired as ``helpers/check.sh --ir`` and as the ``irscan`` bringup stage
-(helpers/tpu_bringup.py runs this file by path, driver stays jax-free).
-What it proves, end to end, on whatever backend is present:
+Wired as ``helpers/check.sh --ir``. What it proves, end to end, on whatever backend is present:
 
  1. the registry bootstrap trains the tiny corpus, reaches the chunked
     device path, and traces EVERY registered entry point abstractly over
@@ -17,8 +15,8 @@ What it proves, end to end, on whatever backend is present:
     that can no longer see a poisoned program must fail here, not pass
     silently forever.
 
-Exit 0 and a final compact JSON line on success (the bringup stage
-records it into TPU_BRINGUP.json); exit 1 with the reason otherwise.
+Exit 0 and a final compact JSON line on success; exit 1 with the reason
+otherwise.
 """
 import json
 import os

@@ -1,5 +1,5 @@
 """Closed-loop continuous-training smoke: the REAL serve stack end to end
-(check.sh --loop, bringup `loop` stage).
+(check.sh --loop).
 
 One invocation proves the whole loop (docs/ContinuousTraining.md), with the
 runtime sanitizer armed (``LIGHTGBM_TPU_SAN=transfer,nan,locks``) so a full
@@ -246,8 +246,7 @@ def main() -> int:
     ok = kill_act(result) and ok
     result["ok"] = ok
     result["loop_smoke"] = "PASS" if ok else "FAIL"
-    # ONE compact line: the bringup driver's result parser reads the last
-    # JSON line of stdout (helpers/tpu_bringup.py _parse_result)
+    # ONE compact line: a driver reads the last JSON line of stdout
     print(json.dumps(result))
     return 0 if ok else 1
 

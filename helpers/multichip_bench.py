@@ -6,16 +6,17 @@ artifact for pod-scale data-parallel training (ROADMAP item 1: the paper's
 Higgs-1M-on-v5e-8 target is a scaling claim, so the scaling curve is the
 headline evidence). Two modes:
 
-  * ``--sweep 1,4,8``: the driver mode helpers/tpu_bringup.py's
-    ``bench_multichip`` stage runs. Each device count needs its own
-    process (the jax device world is fixed at backend init), so the sweep
-    re-execs this file once per count and emits ONE summary JSON line
+  * ``--sweep 1,4,8``: each device count needs its own process (the jax
+    device world is fixed at backend init), so the sweep — which never
+    touches jax itself — re-execs this file once per count, one child at a
+    time, and emits ONE summary JSON line
     (``RESULT {...}``) whose record carries a ``metric`` key — the shape
     obs/report.load_bench_records adopts, so MULTICHIP_r*.json charts next
     to the BENCH_r* series in the HTML run report.
-  * ``--devices D``: one measurement. On a CPU host the device world is
-    forced to D virtual devices (XLA_FLAGS, before backend init); on real
-    chips the mesh is capped with ``num_machines=D`` instead.
+  * ``--devices D``: one measurement on the real devices, the mesh capped
+    with ``num_machines=D``; fewer than D devices is an error. Only an
+    explicit ``JAX_PLATFORMS=cpu`` gets D virtual CPU devices (a dry run,
+    whose rates are not device numbers).
 
 Stays importable without jax until a single-measurement run starts.
 """
@@ -33,27 +34,23 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def measure(devices: int, rows: int, iters: int, chunk: int, leaves: int) -> dict:
     sys.path.insert(0, REPO)
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu") or not os.environ.get(
-        "JAX_PLATFORMS"
-    ):
-        from lightgbm_tpu.utils.platform import force_cpu_devices
+    from lightgbm_tpu.utils.platform import ensure_virtual_devices
 
-        jax = force_cpu_devices(devices)
-    else:
-        import jax
+    # the real devices — too few is an error — unless JAX_PLATFORMS=cpu
+    # asks for a virtual-device dry run by name
+    jax = ensure_virtual_devices(devices)
     import numpy as np
 
     import lightgbm_tpu as lgb
     from helpers.bench_data import make_higgs_like
     from lightgbm_tpu.models.model_text import model_fingerprint
 
-    n_dev = min(devices, len(jax.devices()))
     X, y = make_higgs_like(rows, 28)
     params = {
         "objective": "binary", "num_leaves": leaves, "max_bin": 255,
         "learning_rate": 0.1, "verbosity": -1,
-        "tree_learner": "data" if n_dev > 1 else "serial",
-        "num_machines": n_dev, "device_chunk_size": chunk,
+        "tree_learner": "data" if devices > 1 else "serial",
+        "num_machines": devices, "device_chunk_size": chunk,
     }
     ds = lgb.Dataset(X, label=y)
     bst = lgb.Booster(params=params, train_set=ds)
@@ -79,14 +76,14 @@ def measure(devices: int, rows: int, iters: int, chunk: int, leaves: int) -> dic
     _ = float(np.ravel(np.asarray(bst._gbdt.scores))[0])
     dt = time.time() - t0
     rec = {
-        "devices": n_dev,
+        "devices": devices,
         "iters_per_sec": round(iters / dt, 4),
         "first_dispatch_s": round(compile_s, 2),
         "model_hash": model_fingerprint(bst.model_to_string()),
         "platform": jax.default_backend(),
         "fallback_reason": bst._gbdt.device_chunk_fallback_reason(),
     }
-    if n_dev > 1:
+    if devices > 1:
         # compute-vs-collective attribution (obs/dist.py): the segmented
         # sharded profile says WHY scaling bends — comms_fraction,
         # per-segment seconds, per-device rows/waits; its bitwise check
@@ -186,7 +183,7 @@ def main() -> int:
     ap.add_argument("--chunk", type=int, default=0)
     ap.add_argument("--leaves", type=int, default=0)
     args = ap.parse_args()
-    on_chip = os.environ.get("JAX_PLATFORMS", "") not in ("", "cpu")
+    on_chip = os.environ.get("JAX_PLATFORMS") != "cpu"
     rows = args.rows or (1_000_000 if on_chip else 20_000)
     iters = args.iters or (16 if on_chip else 8)
     chunk = args.chunk or (16 if on_chip else 4)

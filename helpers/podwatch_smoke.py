@@ -21,7 +21,7 @@ ONE invocation proves the whole podwatch chain (docs/Observability.md
      model text (the recorder samples host state only).
 
 The parent stays jax-free (subprocesses do all jax work) so the driver can
-run on any box, matching the tpu_bringup stage contract.
+run on any box.
 """
 import hashlib
 import json

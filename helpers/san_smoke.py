@@ -1,5 +1,5 @@
 """graftsan concurrency stress smoke: the real serve stack under full
-sanitizer instrumentation (check.sh --san, bringup `san` stage).
+sanitizer instrumentation (check.sh --san).
 
 With ``LIGHTGBM_TPU_SAN=transfer,nan,locks`` armed BEFORE import (so every
 serve/obs lock is an order-recording _SanLock and the bucketed dispatch runs
@@ -145,8 +145,7 @@ def main() -> int:
         ]
         for t in threads:
             t.start()
-        # ONE shared deadline for all workers (well under the bringup
-        # stage's 1800s timeout), and a hung thread is a NAMED failure —
+        # ONE shared deadline for all workers, and a hung thread is a NAMED failure —
         # a deadlock is exactly the bug class this smoke exists to catch,
         # not something to mask behind a successful drain
         deadline = time.monotonic() + 240
@@ -202,8 +201,7 @@ def main() -> int:
         seeded["inversion"] = "caught"
 
     ok = not failures and all(v == "caught" for v in seeded.values())
-    # ONE compact line: the bringup driver's result parser reads the last
-    # JSON line of stdout (helpers/tpu_bringup.py _parse_result)
+    # ONE compact line: a driver reads the last JSON line of stdout
     print(json.dumps({
         "ok": ok,
         "san_smoke": "PASS" if ok else "FAIL",

@@ -11,10 +11,6 @@ Public API mirrors the LightGBM python package: Dataset, Booster, train, cv,
 sklearn-style estimators, and the callback set.
 """
 
-from .utils.platform import honor_jax_platforms_env
-
-honor_jax_platforms_env()  # must run before anything initializes a jax backend
-
 from .basic import Booster, Dataset
 from .callback import early_stopping, print_evaluation, record_evaluation, reset_parameter
 from .config import Config

@@ -27,6 +27,7 @@ from .resil.preempt import PREEMPT_EXIT_CODE, TrainingPreempted
 from .utils import log
 from .utils.vfile import vopen
 from .utils.log import LightGBMError
+from .utils.platform import place_compile_cache
 
 
 def parse_args(argv: List[str]) -> Dict[str, str]:
@@ -228,6 +229,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     try:
         params = parse_args(argv)
         config = Config.from_params(params)
+        # the grower is a multi-minute compile on a TPU: keep it across runs
+        # (JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache)
+        place_compile_cache()
         # task-level obs span: with LIGHTGBM_TPU_TRACE set, the whole CLI
         # task becomes the root span the training/serving spans nest under
         with trace_mod.span("cli.%s" % config.task, cat="cli"):
@@ -247,9 +251,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         # the boundary-latch contracts (docs/FaultTolerance.md): a durable
         # checkpoint was published at the last boundary, and the DISTINCT
         # exit code tells orchestrators what kind of relaunch is wanted —
-        # 75 "resume me as I was" (preempt; loop restart, tpu_bringup
-        # run_with_retry), 76 "relaunch me at current capacity" (flexctl
-        # drain, §Fleet orchestrator)
+        # 75 "resume me as I was" (preempt; loop restart), 76 "relaunch me
+        # at current capacity" (flexctl drain, §Fleet orchestrator)
         if getattr(e, "reason", "preempt") == "drain":
             log.warning(
                 "train drained for reshard (%s); checkpoint: %s — the "

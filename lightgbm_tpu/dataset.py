@@ -22,9 +22,8 @@ non-zero sampled values, drop trivial features, then bin every row.
 
 On the reference's 4-bit packing (dense_nbits_bin.hpp:42, max_bin <= 16):
 a measurement kernel exists (ops/hist_pallas.py histogram_pallas_packed4 —
-nibble-packed bins halve the dominant HBM stream of the histogram pass) and
-the TPU bring-up chain measures it against the u8 layout at the max_bin=15
-bench shape (helpers/tpu_bringup.py "pack4" stage -> PACK4_MEASURE.json).
+nibble-packed bins halve the dominant HBM stream of the histogram pass); it
+compiles on the v5e, but its speed against the u8 layout is not measured.
 Adoption is gated on that measurement showing >10%: the packed layout also
 complicates every row-gather in the partition path (two rows per byte), so
 the dense u8 matrix stays the storage format until the win is demonstrated.

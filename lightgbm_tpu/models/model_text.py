@@ -25,9 +25,8 @@ def model_fingerprint(text: str) -> str:
     """Stable identity of a model: sha1 of its serialized text.
 
     Shared by the serving registry (hot-swap version reporting,
-    serve/server.py), the generated-C++ provenance header (model_codegen.py)
-    and the bringup spec-vs-seq equality check (helpers/tpu_bringup.py) — one
-    hash, so "same model" means the same thing everywhere.
+    serve/server.py) and the generated-C++ provenance header
+    (model_codegen.py) — one hash, so "same model" means the same thing everywhere.
     """
     return hashlib.sha1(text.encode("utf-8")).hexdigest()
 

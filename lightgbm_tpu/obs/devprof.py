@@ -443,8 +443,10 @@ def analyze(
 ) -> Dict[str, object]:
     """The full device-timeline record (the ``device_timeline`` section).
 
-    ``device_kind``/``platform`` feed the roofline peak lookup
-    (costs.chip_peaks); ``iters`` — the number of boosting iterations the
+    ``device_kind`` feeds the roofline peak lookup (costs.chip_peaks) when
+    the capture came from a TPU (``platform`` "tpu", or unstated when
+    parsing a saved capture); a capture from any other platform gets no
+    peak fractions. ``iters`` — the number of boosting iterations the
     profiled window covered — adds per-iteration transfer rates.
     """
     from . import costs as costs_mod
@@ -581,7 +583,10 @@ def analyze(
         round(attributed / total_self, 4) if total_self else 0.0
     )
 
-    peaks = costs_mod.chip_peaks(device_kind, platform=platform)
+    peaks = (
+        costs_mod.chip_peaks(device_kind, platform=platform)
+        if device_kind and platform in (None, "tpu") else None
+    )
     top = sorted(op_groups.items(), key=lambda kv: -kv[1]["self_us"])
     top_ops = []
     for (name, seg), g in top[:top_k]:
@@ -596,14 +601,16 @@ def analyze(
             achieved = g["flops"] / (g["self_us"] / 1e6)
             row["flops"] = g["flops"]
             row["achieved_flops_per_s"] = round(achieved, 1)
-            row["peak_flops_fraction"] = round(
-                achieved / float(peaks["peak_flops"]), 6)
+            if peaks is not None:
+                row["peak_flops_fraction"] = round(
+                    achieved / float(peaks["peak_flops"]), 6)
         if g["bytes"] and g["self_us"]:
             bw = g["bytes"] / (g["self_us"] / 1e6)
             row["bytes"] = int(g["bytes"])
             row["achieved_bytes_per_s"] = round(bw, 1)
-            row["peak_bw_fraction"] = round(
-                bw / float(peaks["peak_bw"]), 6)
+            if peaks is not None:
+                row["peak_bw_fraction"] = round(
+                    bw / float(peaks["peak_bw"]), 6)
         top_ops.append(row)
     rec["top_ops"] = top_ops
 
@@ -617,7 +624,8 @@ def analyze(
         }
         pin.update(pin_extra)
         rec["mfu_pin"] = pin
-    rec["roofline_chip"] = peaks["chip"]
+    if peaks is not None:
+        rec["roofline_chip"] = peaks["chip"]
 
     # -- verdict -----------------------------------------------------------
     gaps = rec["dispatch_gaps"]
@@ -858,11 +866,7 @@ def _cmd_capture(args) -> int:
             for _ in range(args.iters):
                 booster.update()
             jax.block_until_ready(booster._gbdt.scores)
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = None
-    rec = analyze_dir(target, device_kind=kind,
+    rec = analyze_dir(target, device_kind=jax.devices()[0].device_kind,
                       platform=jax.default_backend(), iters=args.iters,
                       top_k=args.top)
     publish(rec)
@@ -888,8 +892,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                                    "target) or a trace.json(.gz) file")
     pp.add_argument("--top", type=int, default=15)
     pp.add_argument("--device-kind", default=None,
-                    help="roofline chip lookup (e.g. 'TPU v5e'); default "
-                         "cpu-nominal")
+                    help="the TPU the capture came from (e.g. 'TPU v5e'); "
+                         "without it no peak fractions are computed")
     pp.add_argument("--platform", default=None)
     pp.add_argument("--iters", type=int, default=None,
                     help="iterations the window covered (per-iter rates)")

@@ -51,9 +51,7 @@ Run::
     python -m lightgbm_tpu.obs.irscan --write-contract   # refresh fingerprints
     python -m lightgbm_tpu.obs.irscan --selfcheck  # seeded violations caught?
 
-Wired as ``helpers/check.sh --ir`` and the ``irscan`` bringup stage
-(helpers/tpu_bringup.py runs helpers/irscan_smoke.py by file path — the
-driver stays jax-free). Docs: docs/StaticAnalysis.md §Program-level audit.
+Wired as ``helpers/check.sh --ir`` (helpers/irscan_smoke.py). Docs: docs/StaticAnalysis.md §Program-level audit.
 """
 from __future__ import annotations
 
@@ -88,7 +86,8 @@ DEFAULT_CONVERT_BUDGET = 128
 #: tree legitimately contains; with a concrete destination/source or copy
 #: semantics it is an in-program transfer and IR001 fires.
 FORBIDDEN_PRIMS = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "callback",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
+    "callback",
     "infeed", "outfeed", "copy_to_host",
 })
 
@@ -205,11 +204,11 @@ def write_baseline(
 # ---------------------------------------------------------------------------
 def _sub_jaxprs(value) -> Iterable[Tuple[Any, list]]:
     """Yield (Jaxpr, consts) pairs reachable from an eqn param value."""
-    import jax
+    from jax.extend import core as jex_core  # jax.core lost these in 0.9
 
-    if isinstance(value, jax.core.Jaxpr):
+    if isinstance(value, jex_core.Jaxpr):
         yield value, []
-    elif isinstance(value, jax.core.ClosedJaxpr):
+    elif isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr, list(value.consts)
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -356,21 +355,24 @@ def _rule_ir003(spec: EntrySpec, shape: str, closed, hlo: str, audit: Audit):
     np_bytes = dev_bytes = 0
     for _, consts in iter_jaxprs(closed):
         for c in consts:
-            if isinstance(c, np.ndarray):
-                np_bytes += int(c.nbytes)
-                if c.nbytes > spec.np_const_limit:
+            if isinstance(c, jax.Array):
+                dev_bytes += int(getattr(c, "nbytes", 0))
+            elif hasattr(c, "shape") and hasattr(c, "dtype"):
+                # a host constant: np.ndarray, or the TypedNdArray jax 0.9
+                # wraps closed-over numpy values in (which has no nbytes)
+                nbytes = _aval_nbytes(c)
+                np_bytes += nbytes
+                if nbytes > spec.np_const_limit:
                     audit.findings.append(Finding(
                         "IR003", spec.name, shape,
-                        "const_bytes=%d" % int(c.nbytes),
+                        "const_bytes=%d" % nbytes,
                         "host constant of %d bytes (%s%s) baked into the "
                         "program (> %d limit) — re-folded on every trace "
                         "and duplicated per executable; hoist to a "
                         "device-resident argument or module-level buffer"
-                        % (int(c.nbytes), np.dtype(c.dtype).name,
+                        % (nbytes, np.dtype(c.dtype).name,
                            list(c.shape), spec.np_const_limit),
                     ))
-            elif isinstance(c, jax.Array):
-                dev_bytes += int(getattr(c, "nbytes", 0))
     audit.np_const_bytes = np_bytes
     audit.device_const_bytes = dev_bytes
 
@@ -468,10 +470,11 @@ def _producer_map(jaxpr) -> Dict[Any, Any]:
 
 def _is_select_producer(eqn) -> bool:
     """The update operand was produced by a per-row select — directly, or
-    through the jnp.where pjit wrapper (`_where`)."""
+    through the jnp.where jit wrapper (`_where`; the primitive jax 0.9
+    names ``jit``)."""
     if eqn.primitive.name == "select_n":
         return True
-    if eqn.primitive.name == "pjit":
+    if eqn.primitive.name == "jit":
         if "_where" in str(eqn.params.get("name", "")):
             return True
         sub = eqn.params.get("jaxpr")
@@ -562,7 +565,7 @@ def audit_program(spec: EntrySpec, shape: str, fn, args, kwargs) -> Audit:
 
     audit = Audit(entry=spec.name, shape=shape)
     ctx = (
-        jax.experimental.enable_x64()
+        jax.enable_x64(True)
         if spec.x64 else contextlib.nullcontext()
     )
     with warnings.catch_warnings():
@@ -1001,7 +1004,7 @@ def seeded_specs() -> List[Tuple[str, EntrySpec]]:
     is clean)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     f32 = np.float32

@@ -37,8 +37,7 @@ host learner, the Pallas split kernel — are refused via
 
 Env gating: ``LIGHTGBM_TPU_PROF_SEGMENTS=N`` makes ``engine.train`` run N
 profiling iterations after training (1 when set to a non-integer truthy
-value); bench.py and ``helpers/tpu_bringup.py``'s ``prof`` stage call
-:func:`profile_growth` directly. Results land in the default registry as
+value); bench.py calls :func:`profile_growth` directly. Results land in the default registry as
 ``growth_segment_seconds_total{segment=...}`` gauges, in ``run_report()``
 as a ``growth_segments_s`` section, and as ``prof.*`` Chrome-trace spans
 whenever the obs tracer is live (docs/Observability.md).

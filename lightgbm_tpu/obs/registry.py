@@ -15,8 +15,8 @@ the single spine every lightgbm_tpu metric hangs off:
 
 ``MetricsRegistry`` hands out get-or-create instruments by name and renders
 them all as Prometheus text exposition (``prometheus_text``) or a JSON-able
-run report (``run_report`` — the same block bench.py and tpu_bringup.py embed
-in their output JSON). ``REGISTRY`` is the process-wide default: training
+run report (``run_report`` — the block bench.py embeds in its output
+JSON). ``REGISTRY`` is the process-wide default: training
 (engine.py, utils/timer.py), the retrace watchdog and memwatch all publish
 here; each ServeApp keeps its own instance for isolation and the /metrics
 endpoint concatenates both (serve/server.py).
@@ -360,7 +360,7 @@ class MetricsRegistry:
 
     def run_report(self) -> Dict[str, object]:
         """JSON-able block of every instrument's current state — the shared
-        structured run report bench.py and helpers/tpu_bringup.py embed."""
+        structured run report bench.py embeds."""
         counters: Dict[str, float] = {}
         gauges: Dict[str, float] = {}
         summaries: Dict[str, Dict[str, float]] = {}

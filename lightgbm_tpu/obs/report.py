@@ -3,8 +3,7 @@
 ``python -m lightgbm_tpu.obs.report`` renders a training flight log
 (obs/flight.py), a metrics/run-report snapshot (obs/registry.py), optional
 BENCH_*.json series and a drift snapshot into a single HTML file a browser
-opens offline — the artifact a bringup round attaches next to
-TPU_BRINGUP.json, and what a perf investigation passes around instead of
+opens offline — what a perf investigation passes around instead of
 four JSON files and a plotting environment.
 
 Sections (each rendered only when its input is present):
@@ -705,8 +704,7 @@ def load_bench_records(pattern: str) -> List[Tuple[str, Dict]]:
     """(basename, record) for every bench JSON matching ``pattern``: the
     driver's BENCH_r*.json wrapper is unwrapped (record under "parsed"),
     bare bench.py records pass through, anything without a "metric" key is
-    skipped. The ONE adoption rule shared by the report CLI and
-    helpers/tpu_bringup.py's per-round report."""
+    skipped."""
     out: List[Tuple[str, Dict]] = []
     for p in sorted(glob.glob(pattern)):
         try:

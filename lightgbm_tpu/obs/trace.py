@@ -13,7 +13,6 @@ Span sites (cat → where):
   * ``train``         — per-iteration / per-chunk spans (engine._boost_loop)
   * ``serve``         — request lifecycle: queue wait → batch gather →
                         dispatch → reply (serve/server.py, serve/batcher.py)
-  * ``bringup``       — per-stage spans in helpers/tpu_bringup.py
   * ``cli``           — task-level spans (cli.py)
 
 Device correlation: when jax is already imported and a tracer is active,
@@ -22,8 +21,8 @@ host span shows up inside the XLA/TPU profile that ``LIGHTGBM_TPU_PROFILE``
 captures — the host and device timelines line up by annotation name.
 
 One trace file per PROCESS: a subprocess inheriting the env var would clobber
-the parent's file at exit, so drivers that fan out stages rewrite the path
-per child (helpers/tpu_bringup.py appends ``.stage_<name>``).
+the parent's file at exit, so drivers that fan out children rewrite the path
+per child (helpers/multichip_bench.py appends ``.dev<N>``).
 
 Disabled cost: one dict lookup per ``span()`` call. Thread-safe throughout.
 """
@@ -168,7 +167,7 @@ def start(path: Optional[str] = None) -> Tracer:
             # jax.distributed runs: every rank inherits the SAME env var, so
             # an env-derived default path gets a .rank<N> suffix — two ranks
             # must never clobber one trace file. Explicit paths are the
-            # caller's responsibility (bringup already appends .stage_*).
+            # caller's responsibility.
             target = rank_suffixed(target)
         _TRACER = Tracer(target)
         if not _ATEXIT_ARMED:
@@ -297,8 +296,7 @@ def instant(name: str, cat: str = "", **args) -> None:
 # ---------------------------------------------------------------------------
 
 def merge_traces(out_path: str, in_paths) -> Dict:
-    """Fold several Chrome-trace files (a bringup's per-stage ``.stage_*``
-    children, a pod's per-rank ``.rank<N>`` files, a sweep's ``.dev<D>``
+    """Fold several Chrome-trace files (a pod's per-rank ``.rank<N>`` files, a sweep's ``.dev<D>``
     workers) into ONE Perfetto-loadable timeline. Every source (file, pid)
     pair is remapped to a fresh DISJOINT pid with a ``process_name``
     metadata row naming its origin, so same-pid events from different

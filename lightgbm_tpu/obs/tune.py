@@ -14,8 +14,8 @@ family of histogram256.cl variants and selecting by workload (PAPER.md
 layer 4):
 
  * :func:`sweep` — micro-bench every supported impl
-   (ops/histogram.IMPLS, gated by ``impl_supported`` + the chip's
-   ``vmem_bytes`` from obs/costs.CHIP_PEAKS for the Pallas contenders) at
+   (ops/histogram.IMPLS, gated by ``impl_supported``; the Pallas
+   contenders size their own row chunk to the chip's ``vmem_bytes``) at
    the exact bucket-shape distribution the grower emits, recording
    per-shape medians and the winner.
  * a persisted JSON cache (``save_table`` / ``load_table``) published
@@ -24,9 +24,7 @@ layer 4):
    schema stamp makes stale caches REFUSE loudly instead of mis-routing.
  * :func:`active_table` — the adoption seam ``GBDT._setup_train`` calls to
    FREEZE the route for a run (param ``hist_tune`` > env
-   ``LIGHTGBM_TPU_HIST_TUNE`` > nothing); bench.py auto-adopts a
-   ``TUNE_HIST.json`` next to it, and the bringup ``tune`` stage
-   regenerates that file each chip window (helpers/tpu_bringup.py).
+   ``LIGHTGBM_TPU_HIST_TUNE`` > nothing).
 
 The CLI::
 
@@ -222,44 +220,16 @@ def sweep_shapes(
     ]
 
 
-def _vmem_ok(impl: str) -> bool:
-    """Gate Pallas contenders on this chip's VMEM ceiling: the kernels
-    budget ``hist_pallas._VMEM_BUDGET`` of scoped allocation per grid step,
-    and a chip whose ``vmem_bytes`` (obs/costs.CHIP_PEAKS — the same table
-    graftlint JX011 bounds blocks against) cannot hold that budget would
-    fail Mosaic lowering mid-sweep instead of being skipped."""
-    if not impl.startswith("pallas"):
-        return True
-    from ..ops import hist_pallas
-    from ..ops.histogram import _default_backend
-    from . import costs as costs_mod
-
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = None
-    backend = _default_backend()
-    peaks = costs_mod.chip_peaks(
-        kind, platform="tpu" if backend == "tpu" else None
-    )
-    return float(peaks.get("vmem_bytes", 0)) >= float(
-        hist_pallas._VMEM_BUDGET
-    )
-
-
 def candidate_impls(num_bins: int, backend: Optional[str] = None) -> List[str]:
     """The impls worth racing at a shape on this backend: supported
-    (ops/histogram.impl_supported — the router's own vocabulary) and
-    VMEM-feasible for the Pallas family."""
+    (ops/histogram.impl_supported — the router's own vocabulary)."""
     from ..ops import histogram as hist_mod
 
     b = backend if backend is not None else hist_mod._default_backend()
     return [
         impl
         for impl in hist_mod.IMPLS
-        if hist_mod.impl_supported(impl, num_bins, b) and _vmem_ok(impl)
+        if hist_mod.impl_supported(impl, num_bins, b)
     ]
 
 
@@ -297,7 +267,7 @@ def sweep(
 
     Each entry records the winner AND the per-impl medians (``times_ms``)
     so downstream gates — the tune smoke's "no slower anywhere, strictly
-    faster somewhere" assertion, the bringup stage record — can audit the
+    faster somewhere" assertion — can audit the
     decision without re-measuring."""
     import jax
     import jax.numpy as jnp
@@ -395,8 +365,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     for e in table["entries"]:
         winners["B=%d,dt=%s,rows=%d" % (e["B"], e["hist_dtype"],
                                         e["rows_bucket"])] = e["impl"]
-    # one-line JSON result: the bringup stage runner parses the first
-    # '{'-prefixed stdout line (helpers/tpu_bringup.py _parse_result)
+    # one-line JSON result
     print(json.dumps({
         "ok": bool(table["entries"]),
         "path": args.out,

@@ -76,8 +76,8 @@ from .split import (
 _ENV_LATTICE = env_choice("LIGHTGBM_TPU_LATTICE", ("pow2", "coarse"))
 
 # Opt-in single-launch Pallas kernel for the two-child split scan
-# (ops/split_pallas.py) — experimental until its Mosaic lowering and timing
-# are measured on silicon (bringup smoke_psplit stage). Default: XLA scan.
+# (ops/split_pallas.py). Mosaic refuses it on the v5e, so it runs in
+# interpret mode only (split_pallas.supported()). Default: XLA scan.
 _ENV_SPLIT_IMPL = env_choice("LIGHTGBM_TPU_SPLIT_IMPL", ("pallas",))
 
 # Speculative top-k batched growth ("spec" mode): each while_loop step
@@ -85,7 +85,7 @@ _ENV_SPLIT_IMPL = env_choice("LIGHTGBM_TPU_SPLIT_IMPL", ("pallas",))
 # and applies the longest prefix the sequential gain order would have
 # chosen — measured 3.7x fewer sequential loop steps at k=8 on real split
 # sequences (r5 study), attacking the dominant per-split fixed cost of the
-# r4 on-silicon breakdown (BENCH_NOTES.md). "spec"/"seq" force the mode on
+# 2026-07-31 on-chip breakdown (PERF.md §Before this round). "spec"/"seq" force the mode on
 # any backend (tests use monkeypatch + clear_caches like _ENV_SPLIT_IMPL);
 # the default is spec on TPU, sequential elsewhere.
 _ENV_GROW = env_choice("LIGHTGBM_TPU_GROW", ("spec", "seq"))
@@ -972,7 +972,7 @@ def grow_tree(
                     return find_best_split_pair_pallas(
                         hist2, sg2, sh2, nd2, mn2, mx2, feature_meta,
                         feature_mask, params, two_way=two_way,
-                        interpret=backend != "tpu",
+                        interpret=True,  # Mosaic refuses it (supported())
                     )
             return jax.vmap(
                 lambda h, sg, sh, nd, mn, mx: find_best_split(

@@ -60,43 +60,55 @@ from jax.experimental.pallas import tpu as pltpu
 
 LO = 8  # low-radix width: RHS one-hot lanes
 
-# Scoped-VMEM budget for one grid step. Mosaic's hard limit is 16MB; first
-# real-TPU contact (2026-07-31) measured ~1068 B/row of scoped allocation for
-# the f32 kernel at C=16384 — 17.5MB, a compile-time OOM. The model below
-# reproduces that measurement (est. 1007 B/row) from the live intermediates,
-# and _max_chunk caps C so the estimate stays under this budget with a
-# ~6MB margin for Mosaic's own stack.
-_VMEM_BUDGET = 10 * 1024 * 1024
-
-# The measured Mosaic overhead margin behind _VMEM_BUDGET: 16MiB chip VMEM
-# minus the 10MiB scoped budget above. The wide-bin kernels (onehot /
-# bitplane, ISSUE 17) derive their budget from THIS chip's vmem_bytes in
-# obs/costs.CHIP_PEAKS instead of hardcoding the 16MiB floor, so a v6e
-# (32MiB) gets double the chunk depth while v4/v5 reproduce _VMEM_BUDGET.
+# Scoped-VMEM budget for one grid step: Mosaic's limit (16MiB by default on
+# a v5e, obs/costs.CHIP_PEAKS vmem_bytes) less this margin for its own stack.
 _VMEM_MARGIN = 6 * 1024 * 1024
 
 
 def _vmem_budget() -> int:
-    """Per-grid-step scoped-VMEM budget from this chip's ``vmem_bytes``
-    (obs/costs.CHIP_PEAKS — the same table graftlint JX011 bounds static
-    blocks against and obs/tune gates Pallas contenders on), less the
-    measured Mosaic margin. Never below the proven 16MiB-chip budget."""
-    try:
-        import jax as _jax
-
-        kind = _jax.devices()[0].device_kind
-        platform = "tpu" if _jax.default_backend() == "tpu" else None
-    except Exception:
-        kind, platform = None, None
+    """Per-grid-step scoped-VMEM budget: this chip's ``vmem_bytes`` on a
+    TPU, the smallest row of the table anywhere else (interpret mode — so a
+    chunking chosen off-chip also lowers on every chip), less the margin."""
     from ..obs import costs as costs_mod
 
-    peaks = costs_mod.chip_peaks(kind, platform=platform)
-    vmem = int(peaks.get("vmem_bytes", 16 * 2 ** 20))
-    return max(vmem - _VMEM_MARGIN, _VMEM_BUDGET)
+    kind = (
+        jax.devices()[0].device_kind
+        if jax.default_backend() == "tpu" else None
+    )
+    return costs_mod.vmem_bytes(kind) - _VMEM_MARGIN
+
+
+# Scoped-VMEM bytes one row-chunk column costs each routed kernel, as Mosaic
+# itself reported them on a v5e (libtpu 0.0.34, PR 21 chip runs): the size in
+# its "Scoped allocation with size X and limit 16.00M" refusal over the chunk
+# it was given. They replace hand-written footprint models that were 1.7x-6x
+# low — those counted array elements, while VMEM holds (8,128) tiles: a
+# [C, 16] one-hot costs 128 lanes per row and bf16 pads to 16 sublanes (bf16
+# needs MORE than f32 here) — so every kernel was refused at the trainer's
+# tpu_hist_chunk=16384. One figure serves both operand dtypes, the larger.
+_BYTES_PER_COL = {
+    # bf16 24.65M / 16384 = 1578; f32 16.49M / 13824 = 1251
+    "pallas": 1580,
+    # bf16 17.68M / 12288 = 1509; f32 compiles at 6144, which this keeps
+    "pallas_onehot": 1640,
+    # f32, 16x16 split: 57.52M / 12800 = 4712 (and 18.82M / 4096); bf16 not
+    # measured, allowed the 1.25x the other kernels show
+    "pallas_bitplane": 5900,
+    # never refused: held to the 2048 packed columns proven to compile
+    "pallas_packed4": 5120,
+}
+
+
+def _max_chunk_for(impl: str) -> int:
+    """Largest row chunk (a multiple of 512) kernel ``impl`` may take."""
+    c = _vmem_budget() // _BYTES_PER_COL[impl]
+    return max(512, (c // 512) * 512)
 
 
 def _max_chunk(hi_n: int, k_n: int, dtype) -> int:
-    """Largest row-chunk C whose per-step VMEM footprint fits the budget."""
+    """Chunk cap of the per-feature-grid v1 kernel (the differential oracle,
+    not routed): a footprint model that reproduced the one allocation
+    Mosaic reported for it (est. 1007 against 1068 B/row, 2026-07-31)."""
     d = jnp.dtype(dtype).itemsize
     per_row = (
         1 + 2 * (1 + 4 * k_n)  # double-buffered bins [1,C] u8 + vt [K,C] f32
@@ -107,31 +119,13 @@ def _max_chunk(hi_n: int, k_n: int, dtype) -> int:
         # Precision.HIGHEST decomposes each f32 operand into bf16 hi/lo
         # shadows: two bf16 copies of lhs and of oh_lo
         per_row += 2 * 2 * (hi_n * k_n + LO)
-    c = _VMEM_BUDGET // per_row
+    c = _vmem_budget() // per_row
     return max(512, (c // 512) * 512)
 
 
 FB = 8  # features per grid step in the feature-batched kernel (sublane-aligned
 # i8 block: Mosaic cannot load a single dynamic u8 row, but an [8, C] block
 # starting at a multiple of 8 is provably aligned)
-
-
-def _max_chunk_fb(hi_n: int, k_n: int, dtype) -> int:
-    """Chunk cap for the feature-batched (v2) kernel: an [FB, C] bins block
-    plus one values block per step; per-feature intermediates are reused
-    across the static in-kernel unroll."""
-    d = jnp.dtype(dtype).itemsize
-    per_row = (
-        2 * FB  # double-buffered [FB, C] u8 bins block
-        + 2 * 4 * k_n  # double-buffered [K, C] f32 values block
-        + 8 * FB  # hi/lo int32 [FB, C]
-        + 32 + 4 * hi_n  # hoisted lo/hi iotas (i32)
-        + d * (LO + LO * k_n + hi_n)  # oh_lo, lhs, oh_hi (reused per feature)
-    )
-    if d == 4:
-        per_row += 2 * 2 * (LO * k_n + hi_n)  # HIGHEST bf16 operand shadows
-    c = _VMEM_BUDGET // per_row
-    return max(512, (c // 512) * 512)
 
 
 def _hi_for(num_bins: int) -> int:
@@ -237,7 +231,7 @@ def _histogram_pallas_fb(
     HI = _hi_for(B)
     dtype = jnp.dtype(dtype_name)
 
-    C = min(max(chunk, 512), max(512, N), _max_chunk_fb(HI, K, dtype))
+    C = min(max(chunk, 512), max(512, N), _max_chunk_for("pallas"))
     C = max(512, (C // 512) * 512)
     if N % C != 0:
         pad = (-N) % C
@@ -421,16 +415,7 @@ def histogram_pallas_packed4(
     K2 = values_packed.shape[1]
     K = K2 // 2
     dtype = jnp.dtype(dtype_name)
-    # VMEM footprint cap, same discipline as _max_chunk_fb: blocks (bins,
-    # values, both double-buffered) + b_all i32 + bin iota + two one-hots
-    # (+ f32 HIGHEST operand shadows) per packed column
-    d = jnp.dtype(dtype).itemsize
-    per_col = (
-        2 * FB + 2 * 4 * K2 + 4 * FB + 4 * num_bins
-        + d * (2 * num_bins + K2)
-        + (2 * 2 * (num_bins + K) if d == 4 else 0)
-    )
-    C = min(max(chunk, 512), max(512, N2), max(512, _VMEM_BUDGET // per_col))
+    C = min(max(chunk, 512), max(512, N2), _max_chunk_for("pallas_packed4"))
     C = max(512, (C // 512) * 512)
     if N2 % C != 0:
         pad = (-N2) % C
@@ -461,24 +446,6 @@ def histogram_pallas_packed4(
 
 
 BT = 128  # bin-tile width for the dense one-hot kernel: one MXU lane tile
-
-
-def _max_chunk_onehot(k_n: int, dtype) -> int:
-    """Chunk cap for the dense one-hot kernel: [FB, C] bins + [K, C] values
-    blocks per step, one [C, BT] one-hot tile reused across the feature
-    unroll; budgeted against this chip's CHIP_PEAKS vmem_bytes."""
-    d = jnp.dtype(dtype).itemsize
-    per_col = (
-        2 * FB  # double-buffered [FB, C] u8 bins block
-        + 2 * 4 * k_n  # double-buffered [K, C] f32 values block
-        + 4 * FB  # b_all int32 [FB, C]
-        + 4 * BT  # global-bin iota [C, BT] i32
-        + d * (BT + k_n)  # one-hot tile, vt cast
-    )
-    if d == 4:
-        per_col += 2 * 2 * (BT + k_n)  # HIGHEST bf16 operand shadows
-    c = _vmem_budget() // per_col
-    return max(512, (c // 512) * 512)
 
 
 def _kernel_onehot(bins_ref, vt_ref, out_ref, *, bt: int, dtype):
@@ -536,7 +503,7 @@ def histogram_pallas_onehot(
     Bp = -(-B // BT) * BT
     dtype = jnp.dtype(dtype_name)
 
-    C = min(max(chunk, 512), max(512, N), _max_chunk_onehot(K, dtype))
+    C = min(max(chunk, 512), max(512, N), _max_chunk_for("pallas_onehot"))
     C = max(512, (C // 512) * 512)
     if N % C != 0:
         pad = (-N) % C
@@ -583,23 +550,6 @@ def bitplane_split(num_bins: int):
     lob = 1 << (p // 2)
     hib = 1 << (p - p // 2)
     return lob, hib
-
-
-def _max_chunk_bitplane(lob: int, hib: int, k_n: int, dtype) -> int:
-    """Chunk cap for the bit-plane kernel: like :func:`_max_chunk_fb` but
-    with the split factor widths, budgeted against CHIP_PEAKS vmem_bytes."""
-    d = jnp.dtype(dtype).itemsize
-    per_col = (
-        2 * FB  # double-buffered [FB, C] u8 bins block
-        + 2 * 4 * k_n  # double-buffered [K, C] f32 values block
-        + 4 * FB  # b_all int32 [FB, C]
-        + 4 * lob + 4 * hib  # hoisted factor iotas (i32)
-        + d * (lob + lob * k_n + hib + k_n)  # oh_lo, lhs, oh_hi, vt cast
-    )
-    if d == 4:
-        per_col += 2 * 2 * (lob * k_n + hib)  # HIGHEST bf16 operand shadows
-    c = _vmem_budget() // per_col
-    return max(512, (c // 512) * 512)
 
 
 def _kernel_bitplane(bins_ref, vt_ref, out_ref, *, lob: int, hib: int, dtype):
@@ -667,7 +617,7 @@ def histogram_pallas_bitplane(
     lob, hib = bitplane_split(B)
     dtype = jnp.dtype(dtype_name)
 
-    C = min(max(chunk, 512), max(512, N), _max_chunk_bitplane(lob, hib, K, dtype))
+    C = min(max(chunk, 512), max(512, N), _max_chunk_for("pallas_bitplane"))
     C = max(512, (C // 512) * 512)
     if N % C != 0:
         pad = (-N) % C
@@ -743,10 +693,7 @@ def kernel_supported(
     if ignore_backend:
         return True
     if backend is None:
-        try:
-            backend = jax.default_backend()
-        except Exception:
-            return False
+        backend = jax.default_backend()
     return backend == "tpu"
 
 

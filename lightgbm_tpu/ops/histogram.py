@@ -15,15 +15,17 @@ single-precision accumulators (docs/GPU-Performance.rst:131-145).
 
 ``leaf_histogram`` dispatches at trace time, in precedence order:
 
-  1. an explicit ``impl=`` argument (tests, the bringup bake-off races);
+  1. an explicit ``impl=`` argument (tests, chip_smoke.py's kernel phase);
   2. the ``LIGHTGBM_TPU_HIST_IMPL`` env escape hatch (frozen at import);
   3. a frozen per-run :class:`HistRoute` — the measured, shape-keyed tune
      table (obs/tune.py sweep, persisted via resil/atomic, frozen at
      ``GBDT._setup_train``; docs/HistogramRouting.md);
   4. the static backend default (:func:`default_impl`): the chunked one-hot
-     contraction on TPU (measured winner over the pallas v1 kernel at every
-     r4 on-silicon full-N shape — BENCH_NOTES.md), the chunked scatter-add
-     on CPU.
+     contraction on TPU (faster than the pallas v1 kernel at the full-N
+     shape in the one 2026-07-31 measurement, PERF.md §Before this round;
+     on a TPU its default-precision MXU pass cuts grad/hess to bf16 — the
+     2^-8 operand rounding chip_smoke.py measures — whatever ``hist_dtype``
+     says), the chunked scatter-add on CPU.
 
 The route is a pure function of the call shape and the frozen table, and it
 rides the jit static args — so routing is deterministic for a training run
@@ -161,10 +163,9 @@ def _combine(hist, axis_name):
 
 
 def _default_backend() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    """The process-default backend. A backend that fails to initialise
+    raises here: it must never be mistaken for a CPU and routed as one."""
+    return jax.default_backend()
 
 
 # The full impl vocabulary leaf_histogram can route among. "pallas_packed4"
@@ -189,9 +190,7 @@ _XLA_IMPLS = frozenset(("xla", "xla_onehot", "xla_radix", "scatter"))
 # Resolved ONCE at import so routing is deterministic per process: leaf_histogram
 # is jitted with impl as a static arg, and an env var read at trace time would
 # silently keep stale routing for already-compiled shapes if it changed later.
-# Set LIGHTGBM_TPU_HIST_IMPL before importing lightgbm_tpu (bench.py's
-# Mosaic-failure escape hatch re-execs the worker process for exactly this
-# reason).
+# Set LIGHTGBM_TPU_HIST_IMPL before importing lightgbm_tpu.
 from ..utils.platform import env_choice
 
 _ENV_IMPL = env_choice("LIGHTGBM_TPU_HIST_IMPL", IMPLS)
@@ -456,19 +455,15 @@ def device_family() -> Optional[str]:
     """This process's normalized chip family (obs/costs.py's ONE device-kind
     vocabulary) — the tune table's device key, so a cache written on v5e is
     never adopted on v6e."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return None
     from ..obs.costs import normalize_device_kind
 
-    return normalize_device_kind(kind)
+    return normalize_device_kind(jax.devices()[0].device_kind)
 
 
 def _note_impl_fallback(requested: str, num_bins: int) -> None:
     """A forced impl (explicit, env, or a tune entry) that cannot serve this
     shape falls back to the XLA one-hot — loudly, once per (impl, B), and
-    counted so bench/bringup artifacts surface how often routing degraded."""
+    counted so bench artifacts surface how often routing degraded."""
     log.warn_once(
         "hist-impl-fallback:%s:%d" % (requested, num_bins),
         "impl=%r requested (explicitly, via LIGHTGBM_TPU_HIST_IMPL, or a "
@@ -600,11 +595,10 @@ def leaf_histogram(
     if impl == "auto" and _default_backend() == "tpu":
         # The STATIC fallback for shapes with no tune entry: the one-hot
         # contraction measured fastest at the full-N 1Mx28x255 pass on
-        # v5e-1 (16.8 ms vs pallas v1's 34.8 ms — BENCH_NOTES r4). Shapes
-        # the bringup `tune` stage has measured route through the frozen
-        # HistRoute above instead — per-shape winners are a persisted
-        # measurement (obs/tune.py, docs/HistogramRouting.md), no longer a
-        # hand-flipped default.
+        # v5e-1 (16.8 ms vs pallas v1's 34.8 ms, 2026-07-31 — PERF.md
+        # §Before this round). Shapes a tune sweep has measured route
+        # through the frozen HistRoute above instead (obs/tune.py,
+        # docs/HistogramRouting.md).
         impl = "xla"
     if impl == "scatter" or (impl == "auto" and _default_backend() == "cpu"):
         # CPU: a scatter-add is the dense_bin.hpp:71 loop XLA can actually run

@@ -142,11 +142,7 @@ def _bin_prefix(contrib: jax.Array) -> jax.Array:
     speedup). Per-process platform pinning — what tests/conftest.py and the
     bench worker do — is the supported way to select the CPU fold.
     """
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    if backend != "cpu":
+    if jax.default_backend() != "cpu":
         return jnp.cumsum(contrib, axis=1)
     xs = jnp.moveaxis(contrib, 1, 0)
 

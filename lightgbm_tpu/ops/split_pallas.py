@@ -1,4 +1,4 @@
-"""Pallas TPU kernel for the two-child best-split scan (opt-in).
+"""Pallas kernel for the two-child best-split scan (interpret mode only).
 
 The serial grower's per-split fixed cost on TPU is dominated by the
 ~100-150 tiny XLA kernels of the vectorized threshold scan
@@ -22,9 +22,11 @@ accumulation order not):
 
 Scope (the routing gate, ``supported()``): numerical features only (no
 ``is_categorical`` in the meta), no CEGB penalty, monotone constraints
-fine. OFF by default — enable with ``LIGHTGBM_TPU_SPLIT_IMPL=pallas``;
-first validated in interpret mode (tests/test_split_pallas.py), Mosaic
-lowering measured by the bringup's ``smoke_psplit`` stage.
+fine. OFF by default, and not offered on a TPU: Mosaic refuses the kernel
+(see ``supported()``, which chip_smoke.py asks before compiling it).
+``LIGHTGBM_TPU_SPLIT_IMPL=pallas`` with
+``LIGHTGBM_TPU_SPLIT_INTERPRET=1`` runs it in interpret mode
+(tests/test_split_pallas.py).
 
 Reference semantics carried over from feature_histogram.hpp:91-650 via
 ops/split.py; cite: kEpsilon seeds (:87), missing-direction scans, the
@@ -133,9 +135,10 @@ def _kernel(
         gains_pos = gains_for(lg_pos, lh_pos, rg_pos, rh_pos, lc_pos, rc_pos, valid_pos)
 
     # ---- dir = -1 --------------------------------------------------------
-    rg_neg = total[:, :, None, 0] - prefix[:, :, :, 0]
-    rh_neg = total[:, :, None, 1] - prefix[:, :, :, 1] + K_EPSILON
-    rc_neg = total[:, :, None, 2] - prefix[:, :, :, 2]
+    # (a mixed None/int index traces to a gather, which Mosaic refuses)
+    rg_neg = total[:, :, 0][:, :, None] - prefix[:, :, :, 0]
+    rh_neg = total[:, :, 1][:, :, None] - prefix[:, :, :, 1] + K_EPSILON
+    rc_neg = total[:, :, 2][:, :, None] - prefix[:, :, :, 2]
     lg_neg = sum_grad - rg_neg
     lh_neg = sum_hess_eff - rh_neg
     lc_neg = num_data - rc_neg
@@ -276,31 +279,41 @@ def find_best_split_pair_pallas(
     )
 
 
-_warned_interpret = False
-
-
 def supported(feature_meta: Dict, backend: str) -> bool:
-    """Routing gate: numerical-only metas. Off-TPU the kernel would run in
-    the (Python-interpreter) pallas interpret mode — orders of magnitude
-    slower than the XLA scan — so production training declines it there and
-    LIGHTGBM_TPU_SPLIT_IMPL=pallas falls back to the XLA scan. Tests and
-    debugging opt in with LIGHTGBM_TPU_SPLIT_INTERPRET=1."""
+    """Routing gate: numerical-only metas, and interpret mode only.
+
+    On a TPU the kernel is WITHDRAWN: Mosaic (jax 0.9.0 / libtpu 0.0.34,
+    v5e, PR 21 chip runs) refuses it — first "Only 2D gather is supported"
+    (the mixed None/int indices, since rewritten), then "infer-vector-layout:
+    unsupported shape cast vector<28xi1> -> vector<28x1xi1>": every [F]
+    vector in the body needs a 2-D layout, which is a rewrite, not a repair
+    (ROADMAP C3). Off a TPU it would run in the Python-interpreter pallas
+    mode — orders of magnitude slower than the XLA scan — so training
+    declines it there too unless LIGHTGBM_TPU_SPLIT_INTERPRET=1 (tests and
+    debugging). Either way LIGHTGBM_TPU_SPLIT_IMPL=pallas falls back to the
+    XLA scan, saying so once."""
     import os
+
+    from ..utils import log
 
     if "is_categorical" in feature_meta:
         return False
-    if backend != "tpu":
-        if os.environ.get("LIGHTGBM_TPU_SPLIT_INTERPRET") != "1":
-            global _warned_interpret
-            if not _warned_interpret:
-                _warned_interpret = True
-                from ..utils import log
-
-                log.warning(
-                    "LIGHTGBM_TPU_SPLIT_IMPL=pallas ignored on a %r backend "
-                    "(the kernel would run in Python interpret mode); using "
-                    "the XLA scan. Set LIGHTGBM_TPU_SPLIT_INTERPRET=1 to "
-                    "force interpret mode for tests/debugging." % backend
-                )
-            return False
+    if backend == "tpu":
+        log.warn_once(
+            "split-pallas-withdrawn",
+            "the Pallas split kernel is not offered on a TPU: Mosaic does "
+            "not compile it (unsupported shape cast of its [F] vectors); "
+            "the XLA scan is used and LIGHTGBM_TPU_SPLIT_IMPL=pallas has "
+            "no effect",
+        )
+        return False
+    if os.environ.get("LIGHTGBM_TPU_SPLIT_INTERPRET") != "1":
+        log.warn_once(
+            "split-pallas-interpret",
+            "LIGHTGBM_TPU_SPLIT_IMPL=pallas ignored on a %r backend (the "
+            "kernel would run in Python interpret mode); using the XLA "
+            "scan. Set LIGHTGBM_TPU_SPLIT_INTERPRET=1 to force interpret "
+            "mode for tests/debugging." % backend,
+        )
+        return False
     return True

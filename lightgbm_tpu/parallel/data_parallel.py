@@ -22,21 +22,8 @@ from __future__ import annotations
 from typing import Dict
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.30 stable name; takes check_vma
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, **kw):
-        return _shard_map(f, **kw)
-
-except ImportError:  # pragma: no cover
-    # older jax: experimental module spells the kwarg check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_exp  # type: ignore
-
-    def shard_map(f, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _shard_map_exp(f, **kw)
 
 from ..ops.grow import grow_tree
 from ..ops.split import CegbParams, SplitParams

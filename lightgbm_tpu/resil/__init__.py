@@ -21,22 +21,20 @@ rest of lightgbm_tpu *survive* the failures the obs layer reports:
                           recovery path is exercised by REAL induced failures
                           in tests rather than mocks.
  * ``resil.backoff``    — the one exponential-backoff helper shared by the
-                          serve dispatch retry and the bringup stage retry.
+                          serve dispatch retry and the loop controller.
  * ``resil.preempt``    — preemption-aware training: SIGTERM → emergency
                           boundary checkpoint → ``TrainingPreempted`` →
-                          documented exit code 75, which loop/bringup
-                          auto-resume from (jax-free by design).
+                          documented exit code 75, which the loop
+                          auto-resumes from (jax-free by design).
  * ``resil.coord``      — coordinated multi-process checkpointing: digest
                           barrier + rank-0-writes + per-rank heartbeats.
  * ``resil.watchdog``   — host-side deadline around sharded collective
                           dispatch (hang detection, warn-then-raise).
 
 Import discipline: this ``__init__`` pulls in only the jax-free modules
-(``backoff``, ``faults``) so host-side drivers (helpers/tpu_bringup.py) can
+(``backoff``, ``faults``) so host-side drivers can
 use them without paying a jax import; ``checkpoint``/``coord`` are imported
-lazily by their callers (engine.py), ``watchdog`` rides models/gbdt.py, and
-``preempt`` is additionally importable standalone by FILE path (the bringup
-driver reads the exit-code constant that way).
+lazily by their callers (engine.py), and ``watchdog`` rides models/gbdt.py.
 """
 from __future__ import annotations
 

@@ -1,14 +1,12 @@
 """Exponential backoff: the one retry-delay schedule for the whole package.
 
-Every consumer of retries — the serve dispatch retry (serve/server.py), the
-bringup stage retry (helpers/tpu_bringup.py) and the continuous-training
-controller's observe/retry loops (lightgbm_tpu/loop/) — draws its sleeps
+Every consumer of retries — the serve dispatch retry (serve/server.py) and
+the continuous-training controller's observe/retry loops
+(lightgbm_tpu/loop/) — draws its sleeps
 from ``delays`` so "how long do we wait after a transient failure" is
 decided in exactly one place; the retry LOOPS themselves stay with their
-callers (serve needs its asymmetric CPU-fallback arm, bringup signals
-failure through a result dict rather than exceptions, the loop controller
-journals between waits). Stdlib only (the bringup driver must not pay a
-jax/numpy import for it).
+callers (serve needs its asymmetric CPU-fallback arm, the loop controller
+journals between waits). Stdlib only.
 
 Two opt-in extensions (defaults preserve the historical schedule exactly):
 

@@ -12,9 +12,8 @@ machinery (atomic publish, fault site ``ckpt.emergency``), and raises
 :class:`TrainingPreempted`. Process entry points (``lightgbm_tpu`` CLI
 task=train, ``python -m lightgbm_tpu.loop``) translate that into exit code
 :data:`PREEMPT_EXIT_CODE`, which orchestrators — ``loop``'s restart
-contract and ``helpers/tpu_bringup.py``'s ``run_with_retry`` — recognize
-as "resume me", NOT "I failed": the re-run resumes from the emergency
-checkpoint instead of restarting the stage from scratch
+contract — recognize as "resume me", NOT "I failed": the re-run resumes
+from the emergency checkpoint instead of restarting from scratch
 (docs/FaultTolerance.md §Elastic training).
 
 The fleet orchestrator (``lightgbm_tpu/flex/``) shares the same
@@ -25,8 +24,7 @@ process exits :data:`RESHARD_EXIT_CODE` — "relaunch me at the current
 capacity", distinct from 75's "resume me as I was"
 (docs/FaultTolerance.md §Fleet orchestrator).
 
-This module is deliberately jax-free: the bringup driver imports it by
-FILE path for the exit-code constants, exactly like resil/backoff.py.
+This module is deliberately jax-free, like resil/backoff.py.
 """
 from __future__ import annotations
 

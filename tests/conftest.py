@@ -2,8 +2,8 @@
 
 Multi-chip TPU hardware is not available in CI; sharding/collective tests use
 virtual CPU devices, per the project testing strategy (SURVEY.md §4: in-process
-multi-worker simulation the reference lacks). Platform monkey-wiring lives in
-lightgbm_tpu.utils.platform (shared with __graft_entry__ and bench.py).
+multi-worker simulation the reference lacks). The virtual-device switch lives
+in lightgbm_tpu.utils.platform (shared with the CPU dry runs).
 """
 import os
 import resource
@@ -52,12 +52,12 @@ def rng():
 # ---------------------------------------------------------------------------
 # Quick tier: `pytest -m quick` runs a fast, high-signal subset (~3-5 min on
 # the 1-core runner) for the edit-test loop; the full 400+ test suite needs
-# >15 min there (VERDICT r4 weak #9). Membership is by module so new tests
+# >15 min there. Membership is by module so new tests
 # in these files inherit the tier.
 # ---------------------------------------------------------------------------
 _QUICK_MODULES = {
-    "test_api_surface", "test_bench_adopt", "test_binning",
-    "test_binning_equiv", "test_bringup_stages", "test_device_chunk",
+    "test_api_surface", "test_binning",
+    "test_binning_equiv", "test_chip_smoke", "test_device_chunk",
     "test_devprof", "test_dist_obs", "test_elastic",
     "test_errors", "test_feature_importance", "test_flex",
     "test_graftlint",
@@ -113,7 +113,7 @@ jax.distributed.initialize(coordinator_address="127.0.0.1:" + port,
                            num_processes=2, process_id=rank)
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 mesh = Mesh(np.array(jax.devices()), ("data",))
 arr = jax.make_array_from_process_local_data(
     NamedSharding(mesh, P("data")), np.ones(1, np.float32))

@@ -3,7 +3,7 @@ in the build-a-spec-then-splat PartitionSpec idiom."""
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def make_mesh(devices):

@@ -105,9 +105,9 @@ def test_golden_top_ops_and_roofline_placement():
     assert pin["segment"] == "hist_build"
     assert pin["self_s"] == pytest.approx(0.0015)
     assert pin["share"] == pytest.approx(1500 / 4800, abs=1e-4)
-    # 4e9 flops over 1.5ms against the v5e f32 peak (99 TF)
+    # 4e9 flops over 1.5ms against the v5e published peak (197 TF bf16)
     assert pin["peak_flops_fraction"] == pytest.approx(
-        (4e9 / 0.0015) / 99e12, rel=1e-3)
+        (4e9 / 0.0015) / 197e12, rel=1e-3)
     assert pin["achieved_bytes_per_s"] == pytest.approx(1e8 / 0.0015,
                                                         rel=1e-3)
     assert "v5e" in rec["roofline_chip"]

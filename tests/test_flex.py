@@ -18,7 +18,7 @@ Four layers under test:
 
 The end-to-end chain with REAL subprocess children (exit codes crossing
 process boundaries, SIGKILL mid-chunk, the flexctl CLI) lives in
-helpers/flex_smoke.py (check.sh --flex / tpu_bringup flex).
+helpers/flex_smoke.py (check.sh --flex).
 """
 import json
 import os

@@ -195,7 +195,7 @@ def test_jx007_shard_map_specs_no_double_report(tmp_path):
     src = (
         "import numpy as np\n"
         "from jax.sharding import Mesh, PartitionSpec as P\n"
-        "from jax.experimental.shard_map import shard_map\n\n"
+        "from jax import shard_map\n\n"
         "def make_mesh(devices):\n"
         "    return Mesh(np.array(devices), ('data',))\n\n"
         "def wrap(f, mesh):\n"

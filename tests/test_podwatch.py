@@ -14,7 +14,7 @@ Three layers under test:
 
 The 2-process world variant (live scrape of a separate process, straggler
 seeded by a real sleep, CLI aggregation in a fresh interpreter) lives in
-helpers/podwatch_smoke.py (check.sh --podwatch / tpu_bringup podwatch).
+helpers/podwatch_smoke.py (check.sh --podwatch).
 """
 import json
 import os
