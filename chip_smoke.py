@@ -21,9 +21,10 @@ against the default training.
 No child process touches JAX, no ``LIGHTGBM_TPU_*`` variable is set, no
 platform is selected in code. A failed phase is a non-zero exit; without
 ``--rehearse`` so is any backend other than a TPU whose ``device_kind``
-``obs.costs`` knows. The last line of a passing run is one JSON object with
-``"ok": true`` and the device as JAX reports it. The timings it prints are
-smoke observations, not a benchmark.
+``obs.costs`` knows. The last line of a run that reached the chip is exactly
+``{"ok": true|false, "device": {"platform", "kind", "count"}}``, the device
+as JAX reports it; what the phases observed is one ``report:`` object on the
+line before. The timings it prints are smoke observations, not a benchmark.
 """
 from __future__ import annotations
 
@@ -694,14 +695,18 @@ def main(argv=None) -> int:
         s.say("%d failure(s):" % len(s.failures))
         for f in s.failures:
             s.say("  - " + f)
-        return 1
-    line = json.dumps(dict({"ok": True}, **s.report))
+    ok = not s.failures
+    # everything printed above, as one object, on a line of its own; the
+    # result line after it holds "ok" and "device" and nothing else
+    s.say("report: " + json.dumps(dict(s.report, ok=ok)))
+    result = json.dumps({"ok": ok, "device": s.report["device"]})
     if args.rehearse:
-        s.say("would have printed: " + line)
-        s.say("rehearsal passed; this is not a chip run")
+        s.say("would have printed: " + result)
+        s.say("rehearsal %s; this is not a chip run"
+              % ("passed" if ok else "failed"))
     else:
-        print(line, flush=True)
-    return 0
+        print(result, flush=True)
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -7,6 +7,7 @@ is the only way through and brands every line, the compile cache has one
 home that does not move with the working directory, and the peak table has
 no row for a device it does not know.
 """
+import json
 import os
 import subprocess
 import sys
@@ -54,6 +55,14 @@ def test_rehearsal_passes_and_brands_every_line():
     # a rehearsal prints no bare result line a driver could take for a pass
     assert not _json_lines(proc.stdout)
     assert "rehearsal passed" in lines[-1]
+    # the result line a chip run ends with: "ok" and "device", nothing else
+    # (the driver refuses any other key); the observations ride the line before
+    result = json.loads(lines[-2].split("would have printed: ", 1)[1])
+    assert sorted(result) == ["device", "ok"] and result["ok"] is True
+    assert sorted(result["device"]) == ["count", "kind", "platform"]
+    assert isinstance(result["device"]["count"], int)
+    report = json.loads(lines[-3].split("report: ", 1)[1])
+    assert report["ok"] is True and {"train", "kernels", "serve"} <= set(report)
 
 
 def test_compile_cache_has_one_home(tmp_path, monkeypatch):
