@@ -21,6 +21,7 @@ from .metric import Metric, create_metric, default_metric_for_objective
 from .models import gbdt as gbdt_mod
 from .models.model_text import dump_model_to_json, load_model_from_string, save_model_to_string
 from .objective import create_objective, objective_from_model_string
+from .obs import trace as trace_mod
 from .resil.atomic import atomic_write_text
 from .utils import log
 from .utils.vfile import vopen
@@ -142,6 +143,11 @@ class Dataset:
     def construct(self, config: Optional[Config] = None) -> "Dataset":
         if self._binned is not None:
             return self
+        trace_mod.watch_compiles()
+        with trace_mod.span("dataset.construct", cat="setup"):
+            return self._construct(config)
+
+    def _construct(self, config: Optional[Config]) -> "Dataset":
         if config is None:
             config = Config.from_params(self.params)
         if isinstance(self.data, str):
@@ -229,7 +235,8 @@ class Dataset:
         if from_pandas is not None:
             data, feature_names, cats, self.pandas_categorical = from_pandas
         else:
-            data = _to_2d_float(self.data, allow_sparse=True)
+            with trace_mod.span("dataset.to_float", cat="setup"):
+                data = _to_2d_float(self.data, allow_sparse=True)
             if isinstance(self.feature_name, (list, tuple)):
                 feature_names = list(self.feature_name)
             if isinstance(self.categorical_feature, (list, tuple)):

@@ -44,6 +44,7 @@ from .binning import (
     BinMapper,
 )
 from .config import Config
+from .obs import trace as trace_mod
 from .utils import log
 from .utils.vfile import vopen
 
@@ -420,31 +421,33 @@ def construct_dataset(
         feature_names,
     )
 
-    sample_idx = _sample_rows(num_data, config.bin_construct_sample_cnt, config.data_random_seed)
-    sample = data[sample_idx]
+    with trace_mod.span("dataset.sample", cat="setup"):
+        sample_idx = _sample_rows(num_data, config.bin_construct_sample_cnt, config.data_random_seed)
+        sample = data[sample_idx]
     total_sample_cnt = len(sample_idx)
 
     mappers: List[BinMapper] = []
     used: List[int] = []
-    for j in range(num_cols):
-        col = np.asarray(sample[:, j], dtype=np.float64)
-        # keep NaN and non-zero values; zeros are counted implicitly
-        keep = np.isnan(col) | (np.abs(col) > K_ZERO_THRESHOLD)
-        vals = col[keep]
-        m = BinMapper()
-        m.find_bin(
-            vals,
-            total_sample_cnt,
-            config.max_bin,
-            config.min_data_in_bin,
-            config.min_data_in_leaf,
-            bin_type=BIN_CATEGORICAL if j in cat_idx else BIN_NUMERICAL,
-            use_missing=config.use_missing,
-            zero_as_missing=config.zero_as_missing,
-        )
-        if not m.is_trivial:
-            mappers.append(m)
-            used.append(j)
+    with trace_mod.span("dataset.find_bins", cat="setup", columns=num_cols):
+        for j in range(num_cols):
+            col = np.asarray(sample[:, j], dtype=np.float64)
+            # keep NaN and non-zero values; zeros are counted implicitly
+            keep = np.isnan(col) | (np.abs(col) > K_ZERO_THRESHOLD)
+            vals = col[keep]
+            m = BinMapper()
+            m.find_bin(
+                vals,
+                total_sample_cnt,
+                config.max_bin,
+                config.min_data_in_bin,
+                config.min_data_in_leaf,
+                bin_type=BIN_CATEGORICAL if j in cat_idx else BIN_NUMERICAL,
+                use_missing=config.use_missing,
+                zero_as_missing=config.zero_as_missing,
+            )
+            if not m.is_trivial:
+                mappers.append(m)
+                used.append(j)
     if not used:
         log.warning("There are no meaningful features, as all feature values are constant.")
     bins = _bin_matrix(data, mappers, used)
@@ -615,6 +618,8 @@ def _bin_matrix(data: np.ndarray, mappers: List[BinMapper], used: List[int]) -> 
     max_bin = max((m.num_bin for m in mappers), default=2)
     dtype = np.uint8 if max_bin <= 256 else np.int32
     out = np.zeros((len(used), data.shape[0]), dtype=dtype)
-    for f, (m, j) in enumerate(zip(mappers, used)):
-        out[f] = m.values_to_bins(np.asarray(data[:, j], dtype=np.float64)).astype(dtype)
+    with trace_mod.span("dataset.bin_matrix", cat="setup",
+                        rows=data.shape[0], columns=len(used)):
+        for f, (m, j) in enumerate(zip(mappers, used)):
+            out[f] = m.values_to_bins(np.asarray(data[:, j], dtype=np.float64)).astype(dtype)
     return out

@@ -53,299 +53,309 @@ def train(
     preempt_exit: Optional[bool] = None,
     flex_plan: Optional[str] = None,
 ) -> Booster:
-    params = dict(params) if params else {}
-    params = Config.canonicalize(params)
-    if "num_iterations" in params:
-        num_boost_round = int(params.pop("num_iterations"))
-    if "early_stopping_round" in params and early_stopping_rounds is None:
-        early_stopping_rounds = int(params.pop("early_stopping_round"))
-    # resilience params (docs/FaultTolerance.md) may ride in via params;
-    # explicit kwargs win. They are POPPED so the Booster's Config (and the
-    # model's parameters footer) stays independent of where a run was
-    # checkpointed/resumed — the footer byte-identity the crash tests assert.
-    if "checkpoint_path" in params:
-        v = str(params.pop("checkpoint_path"))
-        checkpoint_path = checkpoint_path or v
-    if "checkpoint_rounds" in params:
-        v = int(params.pop("checkpoint_rounds"))
-        checkpoint_rounds = checkpoint_rounds if checkpoint_rounds > 0 else v
-    if "resume_from" in params:
-        v = str(params.pop("resume_from"))
-        resume_from = resume_from or v
-    if "checkpoint_keep" in params:
-        v = int(params.pop("checkpoint_keep"))
-        checkpoint_keep = checkpoint_keep if checkpoint_keep > 0 else v
-    if "preempt_exit" in params:
-        v = config_mod.coerce_bool(params.pop("preempt_exit"))
-        preempt_exit = v if preempt_exit is None else preempt_exit
-    if preempt_exit is None:
-        preempt_exit = preempt_mod.env_enabled()
-    # fleet orchestration (lightgbm_tpu/flex/): same pop discipline. An
-    # EXPLICIT flex_plan="" disarms the env, mirroring preempt_exit=false.
-    if "flex_plan" in params:
-        v = str(params.pop("flex_plan"))
-        flex_plan = v if flex_plan is None else flex_plan
-    flex_dead_after_s = 60.0
-    if "flex_dead_after_s" in params:
-        flex_dead_after_s = float(params.pop("flex_dead_after_s"))
-    # controller-only flex knobs ride along when the flex CLI passes its
-    # whole argv to the child; pop them so the model footer stays clean
-    for _k in ("flex_world", "flex_min_world", "flex_max_restarts",
-               "flex_backoff_base_s", "flex_backoff_max_s",
-               "flex_force_cpu", "flex_seed", "flex_max_launches",
-               "flex_journal"):
-        params.pop(_k, None)
-    if flex_plan is None:
-        # the ONE env read flexctl costs when off (the inertness contract
-        # tests/test_flex.py pins); the name mirrors flex/capacity.ENV_PLAN
-        flex_plan = os.environ.get("LIGHTGBM_TPU_FLEX_PLAN")
-    flex_plan = flex_plan or None
-    # model/data observability params (docs/Observability.md): POPPED like
-    # the resil params so the model's parameters footer stays byte-identical
-    # with recording on or off — the bitwise-identity contract the
-    # flight-recorder tests assert
-    flight_path = None
-    if "flight_record" in params:
-        flight_path = str(params.pop("flight_record")) or None
-    flight_path = flight_path or flight_mod.env_path()
-    model_stats = False
-    if "model_stats" in params:
-        model_stats = config_mod.coerce_bool(params.pop("model_stats"))
-    if resume_from and not checkpoint_path:
-        # a resumed run keeps checkpointing to the file it resumed from: the
-        # crash that made the checkpoint necessary can strike again, and a
-        # second preemption must not throw away all post-resume progress
-        checkpoint_path = resume_from
-    if checkpoint_path and checkpoint_rounds <= 0:
-        # snapshot_freq parity: the reference's snapshot cadence doubles as
-        # the checkpoint cadence when no explicit rounds are given; absent
-        # both, default to ~10 checkpoints per run — a checkpoint serializes
-        # the full model text + score carries (+fsync), so a cadence of 1
-        # would turn a long run I/O-bound
-        snap = int(params.get("snapshot_freq", -1) or -1)
-        checkpoint_rounds = snap if snap > 0 else max(1, num_boost_round // 10)
-    if resume_from and init_model is not None:
-        raise LightGBMError(
-            "resume_from and init_model are mutually exclusive: a checkpoint "
-            "already carries its full model"
-        )
-    if fobj is not None:
-        params["objective"] = "none"
-    # continued training
-    predictor = None
-    if init_model is not None:
-        if isinstance(init_model, str):
-            predictor = Booster(model_file=init_model)
-        elif isinstance(init_model, Booster):
-            predictor = init_model
-    init_iteration = predictor.current_iteration if predictor is not None else 0
-
-    if feature_name != "auto":
-        train_set.feature_name = feature_name
-    if categorical_feature != "auto":
-        train_set.categorical_feature = categorical_feature
-    if predictor is not None:
-        train_set.set_predictor(predictor)
-
-    booster = Booster(params=params, train_set=train_set)
-    if predictor is not None:
-        booster._gbdt._merge_from(predictor._gbdt)
-
-    is_valid_contain_train = False
-    train_data_name = "training"
-    if valid_sets is not None:
-        if valid_names is None:
-            valid_names = ["valid_%d" % i for i in range(len(valid_sets))]
-        for i, vset in enumerate(valid_sets):
-            if vset is train_set:
-                is_valid_contain_train = True
-                train_data_name = valid_names[i]
-                continue
-            if vset.reference is None:
-                vset.reference = train_set
-            booster.add_valid(vset, valid_names[i])
-
-    # callbacks
-    cbs = set(callbacks or [])
-    if verbose_eval is True:
-        cbs.add(callback_mod.print_evaluation())
-    elif isinstance(verbose_eval, int) and verbose_eval > 0:
-        cbs.add(callback_mod.print_evaluation(verbose_eval))
-    if early_stopping_rounds is not None and early_stopping_rounds > 0:
-        cbs.add(
-            callback_mod.early_stopping(
-                early_stopping_rounds, bool(params.get("first_metric_only", False)),
-                verbose=bool(verbose_eval),
-            )
-        )
-    if learning_rates is not None:
-        cbs.add(callback_mod.reset_parameter(learning_rate=learning_rates))
-    if evals_result is not None:
-        cbs.add(callback_mod.record_evaluation(evals_result))
-    cbs_before = {c for c in cbs if getattr(c, "before_iteration", False)}
-    cbs_after = cbs - cbs_before
-    cbs_before = sorted(cbs_before, key=lambda c: getattr(c, "order", 0))
-    cbs_after = sorted(cbs_after, key=lambda c: getattr(c, "order", 0))
-
-    # crash-safe checkpoint/resume (resil/checkpoint.py). Restore happens
-    # AFTER valid sets attach (their score carries come from the checkpoint,
-    # not a tree replay) and after callbacks exist (the early-stopping bests
-    # restore into the live stoppers).
-    start_iteration = init_iteration
-    ckpt_writer = None
-    if resume_from or checkpoint_path:
-        from .resil import checkpoint as ckpt_mod
-
-        if resume_from:
-            ckpt = ckpt_mod.restore(booster, resume_from, cbs_after)
-            init_iteration = ckpt.begin_iteration
-            start_iteration = ckpt.iteration
-            # num_boost_round is a train() ARGUMENT, so restore()'s
-            # config-digest warning cannot catch a mismatched end bound —
-            # check it against the manifest's end_iteration here
-            ckpt_end = int(ckpt.manifest["end_iteration"])
-            live_end = init_iteration + num_boost_round
-            if live_end < start_iteration:
-                raise LightGBMError(
-                    "resume_from: num_boost_round=%d ends the run at "
-                    "iteration %d, BEFORE the checkpoint's position %d — "
-                    "nothing would train and the returned model would carry "
-                    "more iterations than requested; pass the original run's "
-                    "num_boost_round (%d)"
-                    % (num_boost_round, live_end, start_iteration,
-                       ckpt_end - init_iteration)
-                )
-            if live_end != ckpt_end:
-                log.warning(
-                    "resume: num_boost_round=%d ends the run at iteration %d "
-                    "but the checkpointed run ended at %d; the resumed run "
-                    "will NOT be bit-identical to the original"
-                    % (num_boost_round, live_end, ckpt_end)
-                )
-        if checkpoint_path:
-            # refuse unsupported configs (dart) NOW, not at the first cadence
-            # boundary checkpoint_rounds iterations in
-            ckpt_mod.check_checkpointable(booster._gbdt)
-            ckpt_writer = ckpt_mod.CheckpointWriter(
-                checkpoint_path, checkpoint_rounds, cbs_after,
-                keep=max(checkpoint_keep, 1),
-            )
-
-    # Device-resident chunked boosting (GBDT.train_chunk): up to
-    # device_chunk_size iterations fuse into one jitted dispatch; callbacks,
-    # eval and early stopping then observe chunk BOUNDARIES only
-    # (docs/DeviceResidentBoosting.md). Custom objectives and
-    # before-iteration callbacks (reset_parameter mutates per-iteration
-    # config) force the per-iteration loop; early stopping clamps the chunk
-    # so a stop can never overshoot its detection window.
-    chunk = 1
-    if fobj is None and not cbs_before:
-        chunk = booster._gbdt.device_chunk()
-        if chunk > 1 and early_stopping_rounds is not None and early_stopping_rounds > 0:
-            chunk = min(chunk, early_stopping_rounds)
-        # an early_stopping() instance handed in via callbacks= carries its
-        # window as an attribute — clamp to it too, or the stop check would
-        # run at chunk granularity instead of the requested one
-        for cb in cbs_after:
-            sr = getattr(cb, "stopping_rounds", 0)
-            if chunk > 1 and isinstance(sr, int) and sr > 0:
-                chunk = min(chunk, sr)
-
-    # training flight recorder (obs/flight.py): run manifest now — the
-    # checkpoint restore above already positioned a resumed run, so the
-    # manifest's provenance fields are final. start() returning None (bad
-    # path, nested run) silently leaves recording off.
-    flight_rec = None
-    if flight_path:
-        parent_fp = None
-        if predictor is not None:
-            # lineage edge for the manifest: the warm-start parent's
-            # fingerprint — the FILE's bytes when init_model was a path
-            # (matching the serve registry's file_sha), else the live
-            # booster's bare model-text fingerprint
-            from .models.model_text import model_fingerprint
-
-            try:
-                if isinstance(init_model, str):
-                    from .utils.vfile import vopen
-
-                    with vopen(init_model) as fh:
-                        parent_fp = model_fingerprint(fh.read())
-                else:
-                    parent_fp = model_fingerprint(predictor.model_to_string())
-            except Exception as e:  # lineage must never fail the run
-                log.debug("flight: parent fingerprint failed: %r" % (e,))
-        flight_rec = flight_mod.start(
-            flight_path,
-            flight_mod.build_manifest(
-                booster, num_boost_round, init_iteration,
-                resume_from=resume_from, checkpoint_path=checkpoint_path,
-                parent_fingerprint=parent_fp,
-            ),
-        )
-
-    # preemption-aware training (resil/preempt.py): SIGTERM latches a flag
-    # the boost loop honors at the next chunk boundary — emergency
-    # checkpoint, then TrainingPreempted (exit code 75 at the process entry
-    # points). Mirrors serve/__main__.py's drain contract for the trainer.
-    preempt_watcher = None
-    if preempt_exit:
-        if ckpt_writer is None:
-            log.warning(
-                "preempt: preempt_exit armed without checkpoint_path — a "
-                "SIGTERM will exit with the preemption code but WITHOUT an "
-                "emergency checkpoint to resume from"
-            )
-        preempt_watcher = preempt_mod.PreemptionWatcher()
-        preempt_watcher.install()
-
-    # live fleet telemetry (obs/podwatch.py): per-rank boundary recorder
-    # (LIGHTGBM_TPU_TELEMETRY=<dir>) + opt-in scrape endpoint
-    # (LIGHTGBM_TPU_TELEMETRY_PORT). Both unset costs one env read per
-    # gate here and nothing in the loop; the trained model is bitwise
-    # independent of telemetry either way (host-side sampling only).
-    telemetry_rec = podwatch_mod.maybe_start(preempt_watcher=preempt_watcher)
-
-    # fleet orchestration (lightgbm_tpu/flex/): a capacity plan arms a
-    # boundary-driven watcher that latches the SAME chunk-boundary latch
-    # preemption uses, with reason "drain" (exit RESHARD_EXIT_CODE so the
-    # flexctl controller relaunches at the new capacity). Threadless: its
-    # whole runtime cost is one check_boundary call per chunk boundary.
-    # flex_plan unset costs exactly the one env read above — no import, no
-    # latch, no objects (the inertness contract).
-    latch = preempt_watcher
-    flex_watcher = None
-    if flex_plan:
-        from .flex import watch as flexwatch_mod
-        from .obs import dist as dist_mod
-        from .resil import checkpoint as ckpt_mod
-
-        if ckpt_writer is None:
-            log.warning(
-                "flex: flex_plan armed without checkpoint_path — a drain "
-                "will exit with the reshard code but WITHOUT a checkpoint "
-                "for the relaunch to resume from"
-            )
-        rank, procs = dist_mod.process_info()
-        hb_base = None
-        if procs > 1:
-            # dead-rank evidence: the telemetry heartbeats refresh every
-            # boundary when podwatch is armed; the checkpoint-side ones
-            # only at checkpoint cadence (still usable, just coarser)
-            hb_base = (podwatch_mod.heartbeat_base(telemetry_rec.out_dir)
-                       if telemetry_rec is not None else checkpoint_path)
-        if latch is None:
-            latch = preempt_mod.BoundaryLatch()
-        flex_watcher = flexwatch_mod.maybe_watch(
-            flex_plan, latch,
-            checkpoint_path=checkpoint_path or flex_plan,
-            live_world=ckpt_mod.mesh_world_of(booster._gbdt),
-            procs=procs, rank=rank, hb_base=hb_base,
-            dead_after_s=flex_dead_after_s,
-        )
-
-    evaluation_result_list: List = []
+    trace_mod.watch_compiles()
+    # every span begun below with __enter__() (train.init here, the loop's
+    # train.boundary) is closed by the finally, whatever leaves this function
+    open_spans = trace_mod.open_depth()
+    preempt_watcher = flight_rec = telemetry_rec = None
     try:
+        # from entry to the loop's first pass: Booster/GBDT set-up, the
+        # binned matrix's way to the device, valid sets, resume
+        init_span = trace_mod.span("train.init", cat="setup").__enter__()
+        params = dict(params) if params else {}
+        params = Config.canonicalize(params)
+        if "num_iterations" in params:
+            num_boost_round = int(params.pop("num_iterations"))
+        if "early_stopping_round" in params and early_stopping_rounds is None:
+            early_stopping_rounds = int(params.pop("early_stopping_round"))
+        # resilience params (docs/FaultTolerance.md) may ride in via params;
+        # explicit kwargs win. They are POPPED so the Booster's Config (and the
+        # model's parameters footer) stays independent of where a run was
+        # checkpointed/resumed — the footer byte-identity the crash tests assert.
+        if "checkpoint_path" in params:
+            v = str(params.pop("checkpoint_path"))
+            checkpoint_path = checkpoint_path or v
+        if "checkpoint_rounds" in params:
+            v = int(params.pop("checkpoint_rounds"))
+            checkpoint_rounds = checkpoint_rounds if checkpoint_rounds > 0 else v
+        if "resume_from" in params:
+            v = str(params.pop("resume_from"))
+            resume_from = resume_from or v
+        if "checkpoint_keep" in params:
+            v = int(params.pop("checkpoint_keep"))
+            checkpoint_keep = checkpoint_keep if checkpoint_keep > 0 else v
+        if "preempt_exit" in params:
+            v = config_mod.coerce_bool(params.pop("preempt_exit"))
+            preempt_exit = v if preempt_exit is None else preempt_exit
+        if preempt_exit is None:
+            preempt_exit = preempt_mod.env_enabled()
+        # fleet orchestration (lightgbm_tpu/flex/): same pop discipline. An
+        # EXPLICIT flex_plan="" disarms the env, mirroring preempt_exit=false.
+        if "flex_plan" in params:
+            v = str(params.pop("flex_plan"))
+            flex_plan = v if flex_plan is None else flex_plan
+        flex_dead_after_s = 60.0
+        if "flex_dead_after_s" in params:
+            flex_dead_after_s = float(params.pop("flex_dead_after_s"))
+        # controller-only flex knobs ride along when the flex CLI passes its
+        # whole argv to the child; pop them so the model footer stays clean
+        for _k in ("flex_world", "flex_min_world", "flex_max_restarts",
+                   "flex_backoff_base_s", "flex_backoff_max_s",
+                   "flex_force_cpu", "flex_seed", "flex_max_launches",
+                   "flex_journal"):
+            params.pop(_k, None)
+        if flex_plan is None:
+            # the ONE env read flexctl costs when off (the inertness contract
+            # tests/test_flex.py pins); the name mirrors flex/capacity.ENV_PLAN
+            flex_plan = os.environ.get("LIGHTGBM_TPU_FLEX_PLAN")
+        flex_plan = flex_plan or None
+        # model/data observability params (docs/Observability.md): POPPED like
+        # the resil params so the model's parameters footer stays byte-identical
+        # with recording on or off — the bitwise-identity contract the
+        # flight-recorder tests assert
+        flight_path = None
+        if "flight_record" in params:
+            flight_path = str(params.pop("flight_record")) or None
+        flight_path = flight_path or flight_mod.env_path()
+        model_stats = False
+        if "model_stats" in params:
+            model_stats = config_mod.coerce_bool(params.pop("model_stats"))
+        if resume_from and not checkpoint_path:
+            # a resumed run keeps checkpointing to the file it resumed from: the
+            # crash that made the checkpoint necessary can strike again, and a
+            # second preemption must not throw away all post-resume progress
+            checkpoint_path = resume_from
+        if checkpoint_path and checkpoint_rounds <= 0:
+            # snapshot_freq parity: the reference's snapshot cadence doubles as
+            # the checkpoint cadence when no explicit rounds are given; absent
+            # both, default to ~10 checkpoints per run — a checkpoint serializes
+            # the full model text + score carries (+fsync), so a cadence of 1
+            # would turn a long run I/O-bound
+            snap = int(params.get("snapshot_freq", -1) or -1)
+            checkpoint_rounds = snap if snap > 0 else max(1, num_boost_round // 10)
+        if resume_from and init_model is not None:
+            raise LightGBMError(
+                "resume_from and init_model are mutually exclusive: a checkpoint "
+                "already carries its full model"
+            )
+        if fobj is not None:
+            params["objective"] = "none"
+        # continued training
+        predictor = None
+        if init_model is not None:
+            if isinstance(init_model, str):
+                predictor = Booster(model_file=init_model)
+            elif isinstance(init_model, Booster):
+                predictor = init_model
+        init_iteration = predictor.current_iteration if predictor is not None else 0
+
+        if feature_name != "auto":
+            train_set.feature_name = feature_name
+        if categorical_feature != "auto":
+            train_set.categorical_feature = categorical_feature
+        if predictor is not None:
+            train_set.set_predictor(predictor)
+
+        booster = Booster(params=params, train_set=train_set)
+        if predictor is not None:
+            booster._gbdt._merge_from(predictor._gbdt)
+
+        is_valid_contain_train = False
+        train_data_name = "training"
+        if valid_sets is not None:
+            if valid_names is None:
+                valid_names = ["valid_%d" % i for i in range(len(valid_sets))]
+            for i, vset in enumerate(valid_sets):
+                if vset is train_set:
+                    is_valid_contain_train = True
+                    train_data_name = valid_names[i]
+                    continue
+                if vset.reference is None:
+                    vset.reference = train_set
+                booster.add_valid(vset, valid_names[i])
+
+        # callbacks
+        cbs = set(callbacks or [])
+        if verbose_eval is True:
+            cbs.add(callback_mod.print_evaluation())
+        elif isinstance(verbose_eval, int) and verbose_eval > 0:
+            cbs.add(callback_mod.print_evaluation(verbose_eval))
+        if early_stopping_rounds is not None and early_stopping_rounds > 0:
+            cbs.add(
+                callback_mod.early_stopping(
+                    early_stopping_rounds, bool(params.get("first_metric_only", False)),
+                    verbose=bool(verbose_eval),
+                )
+            )
+        if learning_rates is not None:
+            cbs.add(callback_mod.reset_parameter(learning_rate=learning_rates))
+        if evals_result is not None:
+            cbs.add(callback_mod.record_evaluation(evals_result))
+        cbs_before = {c for c in cbs if getattr(c, "before_iteration", False)}
+        cbs_after = cbs - cbs_before
+        cbs_before = sorted(cbs_before, key=lambda c: getattr(c, "order", 0))
+        cbs_after = sorted(cbs_after, key=lambda c: getattr(c, "order", 0))
+
+        # crash-safe checkpoint/resume (resil/checkpoint.py). Restore happens
+        # AFTER valid sets attach (their score carries come from the checkpoint,
+        # not a tree replay) and after callbacks exist (the early-stopping bests
+        # restore into the live stoppers).
+        start_iteration = init_iteration
+        ckpt_writer = None
+        if resume_from or checkpoint_path:
+            from .resil import checkpoint as ckpt_mod
+
+            if resume_from:
+                ckpt = ckpt_mod.restore(booster, resume_from, cbs_after)
+                init_iteration = ckpt.begin_iteration
+                start_iteration = ckpt.iteration
+                # num_boost_round is a train() ARGUMENT, so restore()'s
+                # config-digest warning cannot catch a mismatched end bound —
+                # check it against the manifest's end_iteration here
+                ckpt_end = int(ckpt.manifest["end_iteration"])
+                live_end = init_iteration + num_boost_round
+                if live_end < start_iteration:
+                    raise LightGBMError(
+                        "resume_from: num_boost_round=%d ends the run at "
+                        "iteration %d, BEFORE the checkpoint's position %d — "
+                        "nothing would train and the returned model would carry "
+                        "more iterations than requested; pass the original run's "
+                        "num_boost_round (%d)"
+                        % (num_boost_round, live_end, start_iteration,
+                           ckpt_end - init_iteration)
+                    )
+                if live_end != ckpt_end:
+                    log.warning(
+                        "resume: num_boost_round=%d ends the run at iteration %d "
+                        "but the checkpointed run ended at %d; the resumed run "
+                        "will NOT be bit-identical to the original"
+                        % (num_boost_round, live_end, ckpt_end)
+                    )
+            if checkpoint_path:
+                # refuse unsupported configs (dart) NOW, not at the first cadence
+                # boundary checkpoint_rounds iterations in
+                ckpt_mod.check_checkpointable(booster._gbdt)
+                ckpt_writer = ckpt_mod.CheckpointWriter(
+                    checkpoint_path, checkpoint_rounds, cbs_after,
+                    keep=max(checkpoint_keep, 1),
+                )
+
+        # Device-resident chunked boosting (GBDT.train_chunk): up to
+        # device_chunk_size iterations fuse into one jitted dispatch; callbacks,
+        # eval and early stopping then observe chunk BOUNDARIES only
+        # (docs/DeviceResidentBoosting.md). Custom objectives and
+        # before-iteration callbacks (reset_parameter mutates per-iteration
+        # config) force the per-iteration loop; early stopping clamps the chunk
+        # so a stop can never overshoot its detection window.
+        chunk = 1
+        if fobj is None and not cbs_before:
+            chunk = booster._gbdt.device_chunk()
+            if chunk > 1 and early_stopping_rounds is not None and early_stopping_rounds > 0:
+                chunk = min(chunk, early_stopping_rounds)
+            # an early_stopping() instance handed in via callbacks= carries its
+            # window as an attribute — clamp to it too, or the stop check would
+            # run at chunk granularity instead of the requested one
+            for cb in cbs_after:
+                sr = getattr(cb, "stopping_rounds", 0)
+                if chunk > 1 and isinstance(sr, int) and sr > 0:
+                    chunk = min(chunk, sr)
+
+        # training flight recorder (obs/flight.py): run manifest now — the
+        # checkpoint restore above already positioned a resumed run, so the
+        # manifest's provenance fields are final. start() returning None (bad
+        # path, nested run) silently leaves recording off.
+        flight_rec = None
+        if flight_path:
+            parent_fp = None
+            if predictor is not None:
+                # lineage edge for the manifest: the warm-start parent's
+                # fingerprint — the FILE's bytes when init_model was a path
+                # (matching the serve registry's file_sha), else the live
+                # booster's bare model-text fingerprint
+                from .models.model_text import model_fingerprint
+
+                try:
+                    if isinstance(init_model, str):
+                        from .utils.vfile import vopen
+
+                        with vopen(init_model) as fh:
+                            parent_fp = model_fingerprint(fh.read())
+                    else:
+                        parent_fp = model_fingerprint(predictor.model_to_string())
+                except Exception as e:  # lineage must never fail the run
+                    log.debug("flight: parent fingerprint failed: %r" % (e,))
+            flight_rec = flight_mod.start(
+                flight_path,
+                flight_mod.build_manifest(
+                    booster, num_boost_round, init_iteration,
+                    resume_from=resume_from, checkpoint_path=checkpoint_path,
+                    parent_fingerprint=parent_fp,
+                ),
+            )
+
+        # preemption-aware training (resil/preempt.py): SIGTERM latches a flag
+        # the boost loop honors at the next chunk boundary — emergency
+        # checkpoint, then TrainingPreempted (exit code 75 at the process entry
+        # points). Mirrors serve/__main__.py's drain contract for the trainer.
+        preempt_watcher = None
+        if preempt_exit:
+            if ckpt_writer is None:
+                log.warning(
+                    "preempt: preempt_exit armed without checkpoint_path — a "
+                    "SIGTERM will exit with the preemption code but WITHOUT an "
+                    "emergency checkpoint to resume from"
+                )
+            preempt_watcher = preempt_mod.PreemptionWatcher()
+            preempt_watcher.install()
+
+        # live fleet telemetry (obs/podwatch.py): per-rank boundary recorder
+        # (LIGHTGBM_TPU_TELEMETRY=<dir>) + opt-in scrape endpoint
+        # (LIGHTGBM_TPU_TELEMETRY_PORT). Both unset costs one env read per
+        # gate here and nothing in the loop; the trained model is bitwise
+        # independent of telemetry either way (host-side sampling only).
+        telemetry_rec = podwatch_mod.maybe_start(preempt_watcher=preempt_watcher)
+
+        # fleet orchestration (lightgbm_tpu/flex/): a capacity plan arms a
+        # boundary-driven watcher that latches the SAME chunk-boundary latch
+        # preemption uses, with reason "drain" (exit RESHARD_EXIT_CODE so the
+        # flexctl controller relaunches at the new capacity). Threadless: its
+        # whole runtime cost is one check_boundary call per chunk boundary.
+        # flex_plan unset costs exactly the one env read above — no import, no
+        # latch, no objects (the inertness contract).
+        latch = preempt_watcher
+        flex_watcher = None
+        if flex_plan:
+            from .flex import watch as flexwatch_mod
+            from .obs import dist as dist_mod
+            from .resil import checkpoint as ckpt_mod
+
+            if ckpt_writer is None:
+                log.warning(
+                    "flex: flex_plan armed without checkpoint_path — a drain "
+                    "will exit with the reshard code but WITHOUT a checkpoint "
+                    "for the relaunch to resume from"
+                )
+            rank, procs = dist_mod.process_info()
+            hb_base = None
+            if procs > 1:
+                # dead-rank evidence: the telemetry heartbeats refresh every
+                # boundary when podwatch is armed; the checkpoint-side ones
+                # only at checkpoint cadence (still usable, just coarser)
+                hb_base = (podwatch_mod.heartbeat_base(telemetry_rec.out_dir)
+                           if telemetry_rec is not None else checkpoint_path)
+            if latch is None:
+                latch = preempt_mod.BoundaryLatch()
+            flex_watcher = flexwatch_mod.maybe_watch(
+                flex_plan, latch,
+                checkpoint_path=checkpoint_path or flex_plan,
+                live_world=ckpt_mod.mesh_world_of(booster._gbdt),
+                procs=procs, rank=rank, hb_base=hb_base,
+                dead_after_s=flex_dead_after_s,
+            )
+
+        evaluation_result_list: List = []
+        init_span.note(bytes=int(booster._gbdt.bins_dev.nbytes))
+        init_span.close()
         with timer_mod.maybe_profile():
             try:
                 evaluation_result_list = _boost_loop(
@@ -380,6 +390,7 @@ def train(
             booster, evaluation_result_list, flight_rec, model_stats
         )
     finally:
+        trace_mod.close_to(open_spans)
         if preempt_watcher is not None:
             preempt_watcher.uninstall()
         # a crashed/interrupted run (anywhere — the loop, the deferred stop
@@ -482,11 +493,13 @@ def _boost_loop(
         # would re-run eval + callbacks the uninterrupted run never had
         return evaluation_result_list
     iter_counter = obs_registry.REGISTRY.counter("train_iterations")
-    import time as _time
-
     flight_on = flight_mod.active() is not None
     telemetry_on = podwatch_mod.active() is not None
-    t_boundary = _time.perf_counter()
+    # train.boundary: from update's return to the next train.iteration, so
+    # the next pass's fault site and before-callbacks lie in it; left open
+    # by an exception, engine.train's finally closes it
+    boundary = trace_mod.span("train.boundary", cat="train")  # none open yet
+    t_boundary_us = trace_mod.now_us()
     while i < end:
         # named fault site: the crash tests SIGKILL here mid-run and prove
         # resume_from replays to a byte-identical model (resil/faults.py)
@@ -509,6 +522,7 @@ def _boost_loop(
         # math legitimately materializes python/numpy scalar constants,
         # which jax uploads through the same implicit path the guard
         # polices (obs/sanitize.py)
+        boundary.close()
         if chunk > 1 and end - i >= chunk:
             with trace_mod.span("train.chunk", cat="train", iteration=i,
                                 chunk=chunk):
@@ -525,6 +539,8 @@ def _boost_loop(
                 finished = booster.update(fobj=fobj)
             done = 1
         i += done
+        boundary = trace_mod.span("train.boundary", cat="train",
+                                  iteration=i - 1).__enter__()
         iter_counter.inc(done)
         if sanitize_mod.NAN:
             # boundary tripwire: a non-finite score carry fails HERE, named,
@@ -546,10 +562,12 @@ def _boost_loop(
             # only — the dispatch is async either way, so this is
             # dispatch+eval time, not a fence), shared by the flight
             # recorder and the telemetry ring so both attribute the SAME
-            # seconds to the same boundary
-            now = _time.perf_counter()
-            dt_boundary = now - t_boundary
-            t_boundary = now
+            # seconds to the same boundary: from the start of the last
+            # boundary to the start of this one, on train.boundary's own
+            # clock read (the tracer's clock where recording is off)
+            now_us = boundary.t0_us or trace_mod.now_us()
+            dt_boundary = (now_us - t_boundary_us) / 1e6
+            t_boundary_us = now_us
             if flight_on:
                 flight_mod.note_boundary(
                     i - 1, done, dt_boundary, evaluation_result_list
@@ -559,18 +577,21 @@ def _boost_loop(
                     i - 1, done, dt_boundary, gbdt=booster._gbdt
                 )
         try:
-            for cb in cbs_after:
-                cb(
-                    callback_mod.CallbackEnv(
-                        model=booster,
-                        params=params,
-                        iteration=i - 1,
-                        begin_iteration=init_iteration,
-                        end_iteration=end,
-                        evaluation_result_list=evaluation_result_list,
-                        chunk=done,
+            # a span of its own: a callback may block (the benchmark's
+            # does), and that is not the loop's time
+            with trace_mod.span("train.callbacks", cat="train"):
+                for cb in cbs_after:
+                    cb(
+                        callback_mod.CallbackEnv(
+                            model=booster,
+                            params=params,
+                            iteration=i - 1,
+                            begin_iteration=init_iteration,
+                            end_iteration=end,
+                            evaluation_result_list=evaluation_result_list,
+                            chunk=done,
+                        )
                     )
-                )
         except callback_mod.EarlyStopException as es:
             booster.best_iteration = es.best_iteration + 1
             evaluation_result_list = es.best_score
@@ -709,6 +730,7 @@ def _boost_loop(
             if flight_on:
                 flight_mod.note_event("no_split_stop", iteration=i - 1)
             break
+    boundary.close()
     return evaluation_result_list
 
 

@@ -30,10 +30,12 @@ from ..metric import Metric
 from ..obs import costs as costs_mod
 from ..obs import sanitize as sanitize_mod
 from ..obs import dist as dist_mod
-from ..obs import memwatch, retrace as retrace_mod
+from ..obs import memwatch, retrace as retrace_mod, trace as trace_mod
 from ..objective import ObjectiveFunction
 from ..ops import grow_native
-from ..ops.grow import grow_tree, grow_tree_scan, spec_batch_slots
+from ..ops.grow import (
+    COUNTER_NAMES, grow_tree, grow_tree_scan, spec_batch_slots,
+)
 from ..resil import faults as faults_mod
 from ..resil import watchdog as watchdog_mod
 from ..ops.histogram import route_rows_variant as hist_route_rows_variant
@@ -447,9 +449,10 @@ class GBDT:
         # reshape, not scores[0]: eager integer indexing converts-and-uploads
         # its index scalar EVERY iteration (the transfer sanitizer flags it);
         # the [1, N] -> [N] reshape is metadata-only and value-identical
-        grad, hess = self.objective.get_gradients(
-            self.scores if K > 1 else self.scores.reshape(-1)
-        )
+        with jax.named_scope("gradients"):
+            grad, hess = self.objective.get_gradients(
+                self.scores if K > 1 else self.scores.reshape(-1)
+            )
         if K == 1:
             grad, hess = grad[None, :], hess[None, :]
         return grad, hess
@@ -648,7 +651,8 @@ class GBDT:
             self._pending_chunk = None
             nl_dev, n = chunk_pend
             K = self.num_tree_per_iteration
-            nl = np.asarray(nl_dev).reshape(n, K)
+            with trace_mod.span("train.wait_prev_tree", cat="train"):
+                nl = np.asarray(nl_dev).reshape(n, K)
             grew = (nl > 1).any(axis=1)
             if bool(grew.all()):
                 return False
@@ -671,7 +675,11 @@ class GBDT:
         if not pend:
             return False
         self._pending_stop = None
-        if any(int(nl) > 1 for nl, _, _ in pend):
+        # the one place the host waits for the device in the loop: the read
+        # blocks until the previous tree is grown
+        with trace_mod.span("train.wait_prev_tree", cat="train"):
+            grew = any(int(nl) > 1 for nl, _, _ in pend)
+        if grew:
             return False
         K = self.num_tree_per_iteration
         log.warning(
@@ -1024,7 +1032,9 @@ class GBDT:
                 scores, bag, stopped = carry
                 it, fmask_k = xs
                 # _compute_gradients' exact shape logic, on the carry scores
-                grad, hess = obj.get_gradients(scores if K > 1 else scores[0])
+                with jax.named_scope("gradients"):
+                    grad, hess = obj.get_gradients(
+                        scores if K > 1 else scores[0])
                 if K == 1:
                     grad, hess = grad[None, :], hess[None, :]
                 if valid is not None:
@@ -1235,6 +1245,7 @@ class GBDT:
         # scan that output is DCE'd, so the chunk path's pin is the
         # per-row select on `valid`/`pin` below.
 
+        @jax.named_scope("score_update")
         def step(scores, leaf_value, internal_value, lid, bag, nl, rate,
                  valid=None, pin=None):
             if renew is not None:
@@ -1538,10 +1549,18 @@ class GBDT:
         # a deferred no-split iteration must roll back before its placeholder
         # trees can leak into model output (train_one_iter's deferred check)
         self._consume_pending_stop()
+        K = max(self.num_tree_per_iteration, 1)
         for i, (ta, k) in enumerate(self._device_trees):
             if self.models[i] is None:
                 self.models[i] = Tree.from_device(ta, self.train_set)
                 self.models[i].shrinkage = self.shrinkage_rate
+                if ta.counters is not None and trace_mod.recording("grow"):
+                    # the grower's work counters reach the trace here, with
+                    # the tree's other arrays: no fetch of their own in the loop
+                    trace_mod.counters(
+                        "grow.counters", cat="grow", tree=i, iteration=i // K,
+                        **dict(zip(COUNTER_NAMES,
+                                   np.asarray(ta.counters).tolist())))
 
     def num_trees(self) -> int:
         return len(self.models)

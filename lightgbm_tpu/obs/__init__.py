@@ -6,9 +6,14 @@ tier — :mod:`~lightgbm_tpu.obs.flight` (training flight recorder),
 leaf shape) and :mod:`~lightgbm_tpu.obs.report` (the self-contained HTML run
 report); the serve-side drift monitor lives in serve/drift.py. One spine:
 
- * :mod:`~lightgbm_tpu.obs.trace`    — structured span tracer; Chrome-trace
-   JSON via ``LIGHTGBM_TPU_TRACE=<path>``, Perfetto-viewable, device-aligned
-   through ``jax.profiler.TraceAnnotation``.
+ * :mod:`~lightgbm_tpu.obs.trace`    — the one in-program trace. On by
+   default: the training path's coarse spans (``dataset.*``, ``train.init``,
+   ``jit.*``, ``train.iteration`` with its phases and ``train.wait_prev_tree``,
+   ``train.boundary``) and the grower's ``grow.counters`` in a bounded ring,
+   each event with an id, its parent and its ``iteration``/``tree``;
+   ``trace.events()`` reads it. ``LIGHTGBM_TPU_TRACE=<path>`` also writes
+   Perfetto-viewable Chrome-trace JSON of every span, ``=0`` records nothing.
+   Device-aligned through ``jax.profiler.TraceAnnotation``.
  * :mod:`~lightgbm_tpu.obs.retrace`  — jit-compile watchdog; counts real XLA
    traces per entry point, ``LIGHTGBM_TPU_RETRACE=fail`` hard-fails on
    retraces after warmup.

@@ -532,7 +532,9 @@ def segmented_grow_tree(gbdt, grad, hess, bag_mask, fmask,
 
 
 def _trees_equal(ta_a, lid_a, ta_b, lid_b) -> bool:
-    for a, b in zip(ta_a, ta_b):
+    # the work counters say how a tree was grown, not what it is
+    for a, b in zip(ta_a._replace(counters=None),
+                    ta_b._replace(counters=None)):
         if not np.array_equal(np.asarray(a), np.asarray(b)):
             return False
     return bool(np.array_equal(np.asarray(lid_a), np.asarray(lid_b)))

@@ -1,35 +1,69 @@
-"""Structured span tracer: Chrome-trace-format JSON, Perfetto-viewable.
+"""The one in-program trace: spans and counters of the training path in a
+bounded in-memory ring, on by default, and Chrome-trace JSON on request.
 
-Env-gated with ``LIGHTGBM_TPU_TRACE=<path>``: when set, every ``span()``
-context in the process records a Chrome "complete" event (``ph: "X"`` with
-pid/tid/ts/dur, microseconds) and the buffer is written to ``<path>`` at
-``stop()``/``flush()`` or process exit. Load the file in Perfetto
-(https://ui.perfetto.dev) or chrome://tracing; events on one thread nest by
-time containment, so a ``train.iteration`` span visually contains its
-``tree growth`` / ``renew+score update`` phase spans.
+``LIGHTGBM_TPU_TRACE`` (read at every ``span()`` call, so it can change in a
+running process):
 
-Span sites (cat → where):
-  * ``train.phase``   — every PhaseTimers phase (utils/timer.py)
-  * ``train``         — per-iteration / per-chunk spans (engine._boost_loop)
-  * ``serve``         — request lifecycle: queue wait → batch gather →
-                        dispatch → reply (serve/server.py, serve/batcher.py)
-  * ``cli``           — task-level spans (cli.py)
+ * unset or empty: the **ring** is on. The coarse spans of the training path
+   (the categories in ``RING_CATS``: a fixed handful per ``Dataset`` and per
+   boosting iteration, one per compiled program, one counter event per
+   materialised tree; never one per row, column, split or request) go to a
+   ring of the last ``RING_EVENTS`` events, oldest dropped and counted.
+   ``events()`` copies it out, ``reset()`` empties it. Spans of every other
+   category (``serve``, ``loop``, ``cli``, the profilers') are not recorded
+   and cost what they did before the ring: one lookup.
+ * ``<path>``: **file mode**, as before: every ``span()`` of every category
+   also records into a buffer capped at ``MAX_EVENTS`` that is written to
+   ``<path>`` at ``stop()``/``flush()`` or process exit, Perfetto-viewable
+   (https://ui.perfetto.dev). ``enabled()``/``active()`` mean this mode.
+ * ``0``: nothing is recorded, ring included.
 
-Device correlation: when jax is already imported and a tracer is active,
-``span()`` additionally enters ``jax.profiler.TraceAnnotation(name)`` so the
-host span shows up inside the XLA/TPU profile that ``LIGHTGBM_TPU_PROFILE``
-captures — the host and device timelines line up by annotation name.
+Every event is a Chrome-trace dict: ``ph`` (``X`` complete span, ``C``
+counter, ``i`` instant), ``name``, ``cat``, ``pid``, ``tid``, ``ts`` and
+``dur`` in microseconds on ``now_us()``'s monotonic clock, plus ``id`` (unique
+in the process) and ``parent`` (the id of the span that was open on the same
+thread when this one began, None at the root: a thread-local stack, not time
+containment). ``args`` holds the keyword arguments and the identifier the
+event's work shares: ``iteration`` is inherited by everything inside one
+boosting iteration, ``tree`` rides on a tree's counters.
+
+Span and counter sites (name [cat], where):
+  * ``dataset.construct`` [setup] with children ``dataset.to_float``,
+    ``dataset.sample``, ``dataset.find_bins``, ``dataset.bin_matrix``
+    (basic.py, dataset.py)
+  * ``train.init`` [setup]: ``engine.train`` from entry to the loop's first
+    pass, ``bytes=`` of the binned matrix moved to the device
+  * ``jit.trace``, ``jit.lower``, ``jit.compile`` [compile]: one per program
+    jax traces, lowers and builds or loads from its cache, ``fun=`` its name
+    (``watch_compiles()``, a ``jax.monitoring`` listener)
+  * ``train.iteration`` / ``train.chunk`` [train] with the ``PhaseTimers``
+    phases [train.phase] (utils/timer.py) and ``train.wait_prev_tree``
+    [train], the host's blocking read of the previous tree's ``num_leaves``
+    (models/gbdt.py), as children
+  * ``train.boundary`` [train]: from ``update``'s return to the next
+    ``train.iteration``, child ``train.callbacks`` (engine._boost_loop)
+  * ``grow.counters`` [grow], ``ph: "C"``: the grower's work counters of one
+    tree (``ops/grow.COUNTER_NAMES``), emitted when the tree is materialised
+    on the host (``GBDT._materialize``)
+  * file mode only: ``serve.*`` [serve], ``loop.*`` [loop], ``cli.*`` [cli],
+    ``prof.*`` / ``dist.*``
+
+Device correlation: when jax is already imported, a recorded ``span()`` also
+enters ``jax.profiler.TraceAnnotation(name)``, so the program's spans lie in
+the host plane of every profile (``LIGHTGBM_TPU_PROFILE``, the benchmark's
+traced run) under their names, on the profiler's clock.
 
 One trace file per PROCESS: a subprocess inheriting the env var would clobber
 the parent's file at exit, so drivers that fan out children rewrite the path
 per child (helpers/multichip_bench.py appends ``.dev<N>``).
 
-Disabled cost: one dict lookup per ``span()`` call. Thread-safe throughout.
+Thread-safe throughout.
 """
 from __future__ import annotations
 
 import atexit
-import contextlib
+import collections
+import itertools
 import json
 import os
 import sys
@@ -54,6 +88,76 @@ def now_us() -> float:
 #: enough that a traced long-lived server cannot OOM from the tracer
 MAX_EVENTS = 1_000_000
 
+#: the default-on ring: ~40 events an iteration keeps the last ~400
+#: iterations, a few MB at most
+RING_EVENTS = 16_384
+#: what the ring records without file mode: the training path's coarse spans
+RING_CATS = frozenset({"setup", "compile", "train", "train.phase", "grow"})
+
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+
+_IDS = itertools.count(1)
+_LOCAL = threading.local()
+
+
+def _stack() -> list:
+    """This thread's open spans (``_Span``), outermost first."""
+    st = getattr(_LOCAL, "stack", None)
+    if st is None:
+        st = _LOCAL.stack = []
+    return st
+
+
+class _Ring:
+    """The last ``RING_EVENTS`` events and how many were pushed out."""
+
+    def __init__(self, size: int = RING_EVENTS) -> None:
+        self.events = collections.deque(maxlen=size)
+        self.dropped = 0
+        self.lock = sanitize_mod.make_lock("obs.trace.ring")
+        self.tids: Dict[int, int] = {}  # thread ident -> small stable tid
+
+    def append(self, ev: Dict) -> None:
+        with self.lock:
+            if len(self.events) == self.events.maxlen:
+                self.dropped += 1
+            self.events.append(ev)
+
+    def tid(self) -> int:
+        ident = threading.get_ident()
+        tid = self.tids.get(ident)
+        if tid is None:
+            with self.lock:
+                tid = self.tids.setdefault(ident, len(self.tids))
+        return tid
+
+
+_RING = _Ring()
+
+
+def events() -> List[Dict]:
+    """A copy of the ring, oldest first: what the run was doing. Each event
+    is a dict of its own (``args`` too); changing it changes nothing here."""
+    with _RING.lock:
+        snap = list(_RING.events)
+    return [dict(ev, args=dict(ev["args"])) for ev in snap]
+
+
+def dropped() -> int:
+    """Events the ring has pushed out since the last ``reset()``."""
+    return _RING.dropped
+
+
+def reset() -> None:
+    """Empty the ring and its drop count (file mode's buffer is untouched)."""
+    with _RING.lock:
+        _RING.events.clear()
+        _RING.dropped = 0
+
 
 class Tracer:
     """In-memory Chrome-trace event buffer bound to one output path.
@@ -71,63 +175,26 @@ class Tracer:
         self.dropped = 0
         self._events: List[Dict] = []
         self._lock = sanitize_mod.make_lock("obs.trace.buffer")
-        self._tids: Dict[int, int] = {}  # thread ident -> small stable tid
+        self._named: Dict[int, str] = {}  # tid -> thread name, for the file
 
     def _append(self, ev: Dict) -> None:
         with self._lock:
+            if ev["tid"] not in self._named:
+                self._named[ev["tid"]] = threading.current_thread().name
             if len(self._events) >= self.max_events:
                 self.dropped += 1
                 return
             self._events.append(ev)
 
-    def _tid(self) -> int:
-        ident = threading.get_ident()
-        with self._lock:
-            tid = self._tids.get(ident)
-            if tid is None:
-                tid = len(self._tids)
-                self._tids[ident] = tid
-                name = threading.current_thread().name
-                # metadata rides outside the cap: a handful of threads
-                self._events.insert(tid, {
-                    "ph": "M", "name": "thread_name", "pid": self.pid,
-                    "tid": tid, "args": {"name": name},
-                })
-            return tid
-
-    def complete(
-        self, name: str, cat: str, ts_us: float, dur_us: float,
-        args: Optional[Dict] = None, tid: Optional[int] = None,
-    ) -> None:
-        ev = {
-            "ph": "X", "name": name, "cat": cat or "lgbtpu",
-            "pid": self.pid, "tid": self._tid() if tid is None else tid,
-            "ts": round(ts_us, 3), "dur": round(max(dur_us, 0.0), 3),
-        }
-        if args:
-            ev["args"] = args
-        self._append(ev)
-
-    def instant(self, name: str, cat: str = "", args: Optional[Dict] = None) -> None:
-        ev = {
-            "ph": "i", "s": "t", "name": name, "cat": cat or "lgbtpu",
-            "pid": self.pid, "tid": self._tid(), "ts": round(now_us(), 3),
-        }
-        if args:
-            ev["args"] = args
-        self._append(ev)
-
-    def counter(self, name: str, value: float) -> None:
-        self._append({
-            "ph": "C", "name": name, "cat": "lgbtpu", "pid": self.pid,
-            "tid": 0, "ts": round(now_us(), 3),
-            "args": {"value": float(value)},
-        })
-
     def flush(self) -> str:
         """Write the full buffer (Chrome trace object form) to ``path``."""
         with self._lock:
-            events = list(self._events)
+            # metadata rides outside the cap: a handful of threads
+            events = [
+                {"ph": "M", "name": "thread_name", "pid": self.pid,
+                 "tid": tid, "args": {"name": name}}
+                for tid, name in sorted(self._named.items())
+            ] + self._events
             dropped = self.dropped
         payload = {
             "traceEvents": events,
@@ -152,13 +219,13 @@ _ATEXIT_ARMED = False
 
 
 def start(path: Optional[str] = None) -> Tracer:
-    """Start (or return) the process tracer; ``path`` defaults to the
+    """Start (or return) the process's file tracer; ``path`` defaults to the
     LIGHTGBM_TPU_TRACE env var. Idempotent while a tracer is live."""
     global _TRACER, _ATEXIT_ARMED
     with _LOCK:
         if _TRACER is not None:
             return _TRACER
-        target = path or os.environ.get(ENV_TRACE, "")
+        target = path or _env_path()
         if not target:
             raise ValueError(
                 "trace.start() needs a path (or set %s)" % ENV_TRACE
@@ -174,6 +241,12 @@ def start(path: Optional[str] = None) -> Tracer:
             _ATEXIT_ARMED = True
             atexit.register(_atexit_flush)
         return _TRACER
+
+
+def _env_path() -> str:
+    """The file LIGHTGBM_TPU_TRACE asks for ("" when unset or ``0``)."""
+    value = os.environ.get(ENV_TRACE, "")
+    return "" if value == "0" else value
 
 
 def rank_suffixed(target: str) -> str:
@@ -224,11 +297,11 @@ def _atexit_flush() -> None:
 
 
 def active() -> Optional[Tracer]:
-    """The live tracer, auto-starting from the env var on first use."""
+    """The live file tracer, auto-starting from the env var on first use."""
     tr = _TRACER
     if tr is not None:
         return tr
-    if os.environ.get(ENV_TRACE, ""):
+    if _env_path():
         try:
             return start()
         except (ValueError, OSError):
@@ -237,58 +310,233 @@ def active() -> Optional[Tracer]:
 
 
 def enabled() -> bool:
+    """File mode is on: spans of every category are recorded."""
     return active() is not None
 
 
-@contextlib.contextmanager
-def span(name: str, cat: str = "", **args):
-    """Record a complete event around the body; no-op without a tracer.
+def _sinks(cat: str):
+    """Where an event of this category goes now: (the file tracer or None,
+    whether the ring takes it)."""
+    return active(), (cat in RING_CATS
+                      and os.environ.get(ENV_TRACE, "") != "0")
 
-    Keyword args land in the event's ``args`` dict (JSON-able values only).
-    When jax is already imported, the span also enters
-    ``jax.profiler.TraceAnnotation`` so device profiles carry the same name.
-    """
-    tr = active()
-    if tr is None:
-        yield
-        return
-    ann = None
-    jx = sys.modules.get("jax")
-    if jx is not None:
-        try:
-            ann = jx.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        except Exception:
-            ann = None  # profiler unavailable on this backend/version
-    t0 = now_us()
-    try:
-        yield
-    finally:
-        t1 = now_us()
-        if ann is not None:
+
+def recording(cat: str) -> bool:
+    """Whether a span of this category would be recorded now, by the ring or
+    by file mode."""
+    tr, ring = _sinks(cat)
+    return ring or tr is not None
+
+
+def _emit(ph: str, name: str, cat: str, ts_us: float, args: Dict,
+          parent: Optional[int], tr: Optional[Tracer], ring: bool,
+          eid: Optional[int] = None, **more) -> None:
+    ev = {
+        "ph": ph, "name": name, "cat": cat or "lgbtpu", "pid": os.getpid(),
+        "tid": _RING.tid(), "ts": round(ts_us, 3),
+        "id": next(_IDS) if eid is None else eid, "parent": parent,
+    }
+    ev.update(more)
+    ev["args"] = args
+    if tr is not None:
+        tr._append(ev)
+    if ring:
+        _RING.append(ev)
+
+
+def _under_open_span(args: Dict) -> Optional[int]:
+    """The id of this thread's innermost open span, whose ``iteration``
+    ``args`` inherits unless it brings its own."""
+    st = _stack()
+    if not st:
+        return None
+    iteration = st[-1].args.get("iteration")
+    if iteration is not None and "iteration" not in args:
+        args["iteration"] = iteration
+    return st[-1].id
+
+
+class _NullSpan:
+    """What ``span()`` hands out when nothing records it."""
+
+    __slots__ = ()
+    t0_us = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+_NULL = _NullSpan()
+
+
+class _Span:
+    """One open span: a context manager, or ``__enter__()`` now and
+    ``close()`` later where the span outlives a block (the loop's boundary)."""
+
+    __slots__ = ("name", "cat", "args", "tr", "ring", "id", "parent",
+                 "t0_us", "_depth", "_ann")
+
+    def __init__(self, name: str, cat: str, args: Dict,
+                 tr: Optional[Tracer], ring: bool) -> None:
+        self.name, self.cat, self.args = name, cat, args
+        self.tr, self.ring = tr, ring
+        self.t0_us = None
+
+    def __enter__(self) -> "_Span":
+        st = _stack()
+        self._depth = len(st)
+        self.parent = _under_open_span(self.args)
+        self.id = next(_IDS)
+        st.append(self)
+        self._ann = None
+        jx = sys.modules.get("jax")
+        if jx is not None:
             try:
-                ann.__exit__(None, None, None)
+                self._ann = jx.profiler.TraceAnnotation(self.name)
+                self._ann.__enter__()
+            except Exception:
+                self._ann = None  # profiler unavailable on this backend/version
+        self.t0_us = now_us()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self.t0_us is None:
+            return False  # closed already
+        t1 = now_us()
+        if self._ann is not None:
+            try:
+                self._ann.__exit__(None, None, None)
             except Exception as e:
                 # annotation teardown must never mask the body's result
                 from ..utils import log
 
                 log.debug("trace: TraceAnnotation teardown failed: %r", e)
-        tr.complete(name, cat, t0, t1 - t0, args or None)
+        # also drops what a span abandoned by an exception left above this one
+        del _stack()[self._depth:]
+        _emit("X", self.name, self.cat, self.t0_us, self.args, self.parent,
+              self.tr, self.ring, eid=self.id,
+              dur=round(max(t1 - self.t0_us, 0.0), 3))
+        self.t0_us = None
+        return False
+
+    def note(self, **args) -> None:
+        """More ``args`` for the event, known only once the span is open."""
+        self.args.update(args)
+
+    def close(self) -> None:
+        """End a span begun with ``__enter__()``; once closed, a no-op."""
+        self.__exit__(None, None, None)
+
+
+def span(name: str, cat: str = "", **args):
+    """A context manager that records a complete event around its body; a
+    shared no-op where nothing records this category.
+
+    Keyword args land in the event's ``args`` dict (JSON-able values only).
+    When jax is already imported, the span also enters
+    ``jax.profiler.TraceAnnotation`` so device profiles carry the same name.
+    """
+    tr, ring = _sinks(cat)
+    if tr is None and not ring:
+        return _NULL
+    return _Span(name, cat, args, tr, ring)
+
+
+def open_depth() -> int:
+    """How many spans this thread has open: what ``close_to`` takes."""
+    return len(_stack())
+
+
+def close_to(depth: int) -> None:
+    """Close, innermost first, the spans this thread still has open above
+    ``depth``: the ``finally`` of a function that begins spans with
+    ``__enter__()`` and may be left by an exception."""
+    st = _stack()
+    while len(st) > depth:
+        st[-1].close()
 
 
 def complete_at(name: str, cat: str, t0_us: float, t1_us: float,
                 **args) -> None:
     """Record a complete event with explicit start/end (``now_us`` clock) —
     for spans measured across threads, e.g. a request's queue wait."""
-    tr = active()
-    if tr is not None:
-        tr.complete(name, cat, t0_us, t1_us - t0_us, args or None)
+    tr, ring = _sinks(cat)
+    if ring or tr is not None:
+        _emit("X", name, cat, t0_us, args, _under_open_span(args), tr, ring,
+              dur=round(max(t1_us - t0_us, 0.0), 3))
 
 
 def instant(name: str, cat: str = "", **args) -> None:
-    tr = active()
-    if tr is not None:
-        tr.instant(name, cat, args or None)
+    tr, ring = _sinks(cat)
+    if ring or tr is not None:
+        _emit("i", name, cat, now_us(), args, _under_open_span(args), tr,
+              ring, s="t")
+
+
+def counters(name: str, cat: str = "", **args) -> None:
+    """Record one counter event (``ph: "C"``): ``args`` holds the counts and
+    the identifier they belong to (``tree=``, ``iteration=``)."""
+    tr, ring = _sinks(cat)
+    if ring or tr is not None:
+        _emit("C", name, cat, now_us(), args, _under_open_span(args), tr,
+              ring)
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    name = _JIT_EVENTS.get(event)
+    if name is None or not recording("compile"):
+        return
+    t1 = now_us()
+    t0, fun = t1 - duration * 1e6, kwargs.get("fun_name")
+    if name == "jit.trace":
+        # jax reports every jitted function it traces on the way (a jnp call
+        # inside the grower is one, a kernel traced while lowering another):
+        # which of them was a program's own trace shows at its lowering
+        traced = getattr(_LOCAL, "traced", None)
+        if traced is None:
+            # bounded: traces that no lowering follows (make_jaxpr) pile up
+            traced = _LOCAL.traced = collections.deque(maxlen=4096)
+        traced.append((t0, t1, fun))
+        return
+    if name == "jit.lower":
+        # the enclosed traces end before the program's own does, and that one
+        # before the lowering starts (1 ms of slack between the two clocks)
+        traced = getattr(_LOCAL, "traced", None) or ()
+        own = [t for t in traced if t[1] <= t0 + 1e3]
+        if traced:
+            traced.clear()
+        if own:
+            complete_at("jit.trace", "compile", own[-1][0], own[-1][1],
+                        fun=own[-1][2])
+    complete_at(name, "compile", t0, t1, fun=fun)
+
+
+_WATCHING = False
+
+
+def watch_compiles() -> None:
+    """Register, once in the process, the ``jax.monitoring`` listener that
+    records ``jit.trace`` / ``jit.lower`` / ``jit.compile`` (a build and a
+    load from the persistent cache alike). Called where the training path
+    begins (``Dataset.construct``, ``engine.train``), so that importing this
+    package still touches no jax."""
+    global _WATCHING
+    with _LOCK:
+        if _WATCHING:
+            return
+        _WATCHING = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 # ---------------------------------------------------------------------------

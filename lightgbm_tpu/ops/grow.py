@@ -167,6 +167,33 @@ class TreeArrays(NamedTuple):
     leaf_parent: jax.Array  # [M] int32
     leaf_depth: jax.Array  # [M] int32
     cat_member: jax.Array  # [M-1, B] bool: left-side bin membership bitsets
+    # [len(COUNTER_NAMES)] f32: how the grower worked for this tree, not part
+    # of the model (None from the native host learner and the profilers)
+    counters: Optional[jax.Array] = None
+
+
+#: The grower's work counters, per tree, in ``TreeArrays.counters``' order.
+#: float32: at 10.5M rows x 8 lanes an int32 overflows within one tree.
+#: ``steps``: passes of the while_loop body; ``slots_computed``: candidate
+#: slots whose partition and histogram were computed (one per step when
+#: sequential); ``splits``: splits applied. ``hist_rows_streamed``: per
+#: histogram call, the row extent passed over times the lanes it ran for (the
+#: bucket the lattice switch chose, x KB for the speculative lanes; the flat
+#: form's concatenated length; N for the root and in masked mode);
+#: ``hist_rows_needed``: the same calls' live rows. ``part_rows_*``: the same
+#: pair for the partition. Under shard_map the largest shard's counts.
+COUNTER_NAMES = (
+    "steps", "slots_computed", "splits", "hist_rows_streamed",
+    "hist_rows_needed", "part_rows_streamed", "part_rows_needed",
+)
+
+
+def _counted(counters: jax.Array, **delta) -> jax.Array:
+    """``counters`` plus ``delta``, given by name."""
+    return counters + jnp.stack([
+        jnp.asarray(delta.get(name, 0.0), jnp.float32)
+        for name in COUNTER_NAMES
+    ])
 
 
 class PackedBest(NamedTuple):
@@ -230,6 +257,7 @@ class PackedTree(NamedTuple):
     node_b: jax.Array  # [M, 1 + B] bool: default_left | cat_member
     leaf_f: jax.Array  # [M, 3] f32: leaf_value, leaf_count, leaf_weight
     leaf_i: jax.Array  # [M, 2] i32: leaf_parent, leaf_depth
+    counters: Optional[jax.Array] = None  # TreeArrays.counters, COUNTER_NAMES
 
 
 def _unpack_tree(pt: PackedTree, M: int) -> TreeArrays:
@@ -249,6 +277,7 @@ def _unpack_tree(pt: PackedTree, M: int) -> TreeArrays:
         leaf_parent=pt.leaf_i[:, 0],
         leaf_depth=pt.leaf_i[:, 1],
         cat_member=pt.node_b[: M - 1, 1:],
+        counters=pt.counters,
     )
 
 
@@ -345,6 +374,14 @@ def _branch_steps(cap: int):
     return sorted({min(v, cap) for v in fam} | {cap})
 
 
+def _lattice_index(sizes_arr: jax.Array, n) -> jax.Array:
+    """Index of the smallest lattice size that holds ``n``: the branch a
+    ``lax.switch`` over the lattice takes, and the extent a counter books."""
+    return jnp.clip(
+        jnp.searchsorted(sizes_arr, n, side="left"), 0, sizes_arr.shape[0] - 1
+    )
+
+
 class BucketKernels(NamedTuple):
     """The bucketed grower's SEGMENT SEAMS: the per-split partition and
     segment-histogram kernels, extracted from grow_tree so the fused
@@ -359,6 +396,10 @@ class BucketKernels(NamedTuple):
     segment_histogram_batch: Callable
     sizes: Tuple[int, ...]  # gathered-segment bucket lattice
     part_sizes: Tuple[int, ...]  # flat-partition branch lattice
+    #: cnt[W] -> rows EACH of the W lanes of segment_histogram_batch passes
+    hist_extent: Callable
+    #: pcnt[W] -> rows the one flat pass of partition_batch runs over
+    part_extent: Callable
 
 
 def make_bucket_kernels(
@@ -436,6 +477,9 @@ def make_bucket_kernels(
     ]
     _part_sizes_arr = jnp.asarray(_part_sizes, jnp.int32)
 
+    def _part_padded(pcnt):
+        return ((pcnt + 255) // 256) * 256
+
     def partition_batch(order, begin, pcnt, feat, thr, dleft, member):
         """Stably partition W disjoint leaf segments in ONE flat segmented
         pass; returns (new order, left physical counts [W]). The W axis is
@@ -459,7 +503,7 @@ def make_bucket_kernels(
         rows_of = (gid_arr[feat] if bundled else feat).astype(jnp.int32)
         Frows = bins.shape[0]
 
-        padded = ((pcnt + 255) // 256) * 256  # [W]
+        padded = _part_padded(pcnt)  # [W]
         ends = jnp.cumsum(padded)
         offs = ends - padded
         L = ends[-1]
@@ -524,12 +568,9 @@ def make_bucket_kernels(
 
             return branch
 
-        idx = jnp.clip(
-            jnp.searchsorted(_part_sizes_arr, L, side="left"),
-            0, len(_part_sizes) - 1,
-        )
         return jax.lax.switch(
-            idx, [make_branch(Lb) for Lb in _part_sizes],
+            _lattice_index(_part_sizes_arr, L),
+            [make_branch(Lb) for Lb in _part_sizes],
             order, begin, pcnt, offs, ends, rows_of, feat, thr, dleft, miss,
             dbin, nanb, iscat, member,
         )
@@ -581,12 +622,9 @@ def make_bucket_kernels(
 
             return branch
 
-        idx = jnp.clip(
-            jnp.searchsorted(sizes_arr, jnp.max(cnt), side="left"),
-            0, len(SIZES) - 1,
-        )
         return jax.lax.switch(
-            idx, [make_branch(S) for S in SIZES], vals_all, order, begin, cnt
+            _lattice_index(sizes_arr, jnp.max(cnt)),
+            [make_branch(S) for S in SIZES], vals_all, order, begin, cnt
         )
 
     return BucketKernels(
@@ -594,6 +632,10 @@ def make_bucket_kernels(
         segment_histogram_batch=segment_histogram_batch,
         sizes=tuple(SIZES),
         part_sizes=tuple(_part_sizes),
+        hist_extent=lambda cnt: sizes_arr[
+            _lattice_index(sizes_arr, jnp.max(cnt))],
+        part_extent=lambda pcnt: _part_sizes_arr[
+            _lattice_index(_part_sizes_arr, jnp.sum(_part_padded(pcnt)))],
     )
 
 
@@ -851,8 +893,10 @@ def grow_tree(
             bins_nf=bins_nf, chunk=chunk, hist_dtype=hist_dtype,
             feature_sharded=feature_sharded, kb=KB, hist_route=hist_route,
         )
-        partition_batch = _kern.partition_batch
+        partition_batch = jax.named_scope("partition")(_kern.partition_batch)
+        hist_extent, part_extent = _kern.hist_extent, _kern.part_extent
 
+        @jax.named_scope("hist_build")
         def segment_histogram_batch(order, begin, cnt):
             # vals_all (the per-tree [N, 3] accumulands) binds below, before
             # the first call
@@ -889,6 +933,16 @@ def grow_tree(
         ]
         _flat_sizes_arr = jnp.asarray(_flat_sizes, jnp.int32)
 
+        def _flat_padded(cnt):
+            return ((cnt + C_FLAT - 1) // C_FLAT) * C_FLAT
+
+        def flat_extent(cnt):
+            """Rows the one concatenated pass of segment_histogram_flat runs
+            over: the branch its lattice switch takes."""
+            return _flat_sizes_arr[
+                _lattice_index(_flat_sizes_arr, jnp.sum(_flat_padded(cnt)))]
+
+        @jax.named_scope("hist_build")
         def segment_histogram_flat(order, begin, cnt):
             """[KB, F, B, 3] histograms of KB disjoint segments via ONE flat
             concatenated pass — unlike the vmapped-lane form, arithmetic is
@@ -900,7 +954,7 @@ def grow_tree(
             each C_FLAT-chunk lies inside exactly one slot, so a chunked
             one-hot scan attributes each partial to its slot row with one
             dynamic-index add."""
-            padded = ((cnt + C_FLAT - 1) // C_FLAT) * C_FLAT  # [KB]
+            padded = _flat_padded(cnt)  # [KB]
             ends = jnp.cumsum(padded)  # [KB]
             offs = ends - padded
             L = ends[-1]
@@ -946,18 +1000,16 @@ def grow_tree(
 
                 return branch
 
-            idx = jnp.clip(
-                jnp.searchsorted(_flat_sizes_arr, L, side="left"),
-                0, len(_flat_sizes) - 1,
-            )
             return jax.lax.switch(
-                idx, [make_branch(Lb) for Lb in _flat_sizes],
+                _lattice_index(_flat_sizes_arr, L),
+                [make_branch(Lb) for Lb in _flat_sizes],
                 order, begin, cnt, offs, ends,
             )
 
     coupled_arr = feature_meta.get("cegb_coupled")
     lazy_arr = feature_meta.get("cegb_lazy")
 
+    @jax.named_scope("split_find")
     def split2(hist2, sg2, sh2, nd2, mn2, mx2):
         """Best splits for the two children. vmapped over the child axis for
         the plain scan; custom split_fns stay unrolled (they may contain
@@ -1019,6 +1071,7 @@ def grow_tree(
             pen = pen + cegb.tradeoff * lazy_arr[None, :] * unused_cnt
         return pen
 
+    @jax.named_scope("split_find")
     def rescan_all(tree, hist, lsg, lsh, lnd, mn, mx, feature_used, unused_cnt):
         """Re-rank every leaf's best split under current CEGB penalties.
 
@@ -1046,6 +1099,7 @@ def grow_tree(
         gain = depth_gate(gain, tree.leaf_i[:, 1])
         return res._replace(gain=gain)
 
+    @jax.named_scope("split_find")
     def rescan_resident(
         tree, hist, slot_leaf, slot_age, laux, feature_used, unused_cnt,
         old_best, prev_feature_used, split_f,
@@ -1112,11 +1166,12 @@ def grow_tree(
     # (vals_all); masked_values(ones) would rebuild the identical array
     # (ones * bag_mask == bag_mask) — ~6ms/tree on TPU at 1M
     root_vals = vals_all if bucketed else masked_values(jnp.ones((N,), f32))
-    root_hist = leaf_histogram(
-        bins, root_vals, B_hist, chunk=chunk, axis_name=hist_axis,
-        hist_dtype=hist_dtype, feature_sharded=feature_sharded,
-        route=hist_route,
-    )
+    with jax.named_scope("hist_build"):
+        root_hist = leaf_histogram(
+            bins, root_vals, B_hist, chunk=chunk, axis_name=hist_axis,
+            hist_dtype=hist_dtype, feature_sharded=feature_sharded,
+            route=hist_route,
+        )
     # Root totals from the histogram of feature 0 would miss rows in padded bins;
     # sum the mask directly instead (psum'd under shard_map like GBDT's root sync,
     # serial_tree_learner.cpp:271 BeforeTrain).
@@ -1181,6 +1236,11 @@ def grow_tree(
             [jnp.full((M, 1), -1, jnp.int32), jnp.zeros((M, 1), jnp.int32)],
             axis=1,
         ),
+        # the root's histogram pass reads every row once
+        counters=_counted(
+            jnp.zeros((len(COUNTER_NAMES),), f32),
+            hist_rows_streamed=N, hist_rows_needed=N,
+        ),
     )
 
     # The [M, F, B, 3] carry only needs slice 0 initialized: every other
@@ -1235,11 +1295,12 @@ def grow_tree(
         best0 = _pack_best(root_best)
     else:
         root_kw = {"two_way": two_way} if split_fn is find_best_split else {}
-        root_split = split_fn(
-            root_hist, root_g, root_h, root_n,
-            no_con_min[0], no_con_max[0],
-            feature_meta, feature_mask, params, **root_kw,
-        )
+        with jax.named_scope("split_find"):
+            root_split = split_fn(
+                root_hist, root_g, root_h, root_n,
+                no_con_min[0], no_con_max[0],
+                feature_meta, feature_mask, params, **root_kw,
+            )
         best0 = expand_packed(root_split, 0)
 
     state0 = GrowState(
@@ -1274,6 +1335,9 @@ def grow_tree(
         ),
     )
 
+    # scopes: what is not inside partition / hist_build / split_find below is
+    # the carry writes, and reads as apply_split alone
+    @jax.named_scope("apply_split")
     def apply_split(s: GrowState, best_leaf, rec: SplitResult) -> GrowState:
         """Apply one split of ``best_leaf`` by ``rec`` (Split,
         serial_tree_learner.cpp:757-851 + the next iteration's FindBestSplits)."""
@@ -1294,6 +1358,7 @@ def grow_tree(
             leaf_phys = (
                 s.leaf_phys.at[best_leaf].set(left_phys).at[new_leaf].set(right_phys)
             )
+            part_rows = (part_extent(pphys[None]), pphys)
         else:
             row = gid_arr[f] if bundled else f
             col = jax.lax.dynamic_slice(bins, (row, 0), (1, N))[0].astype(jnp.int32)
@@ -1312,6 +1377,7 @@ def grow_tree(
             in_leaf = s.leaf_id == best_leaf
             leaf_id = jnp.where(in_leaf & ~go_left, new_leaf, s.leaf_id)
             order, leaf_begin, leaf_phys = s.order, s.leaf_begin, s.leaf_phys
+            part_rows = (N, jnp.sum(in_leaf))
 
         # ---- wire the tree (5 scatters, PackedTree) ----------------------
         t = s.tree
@@ -1342,6 +1408,7 @@ def grow_tree(
             ])
         )
         tree = PackedTree(
+            counters=t.counters,  # this split's work is booked below
             num_leaves=t.num_leaves + 1,
             node_f=t.node_f.at[node].set(
                 jnp.stack([rec.gain, parent_value, parent_aux[_LAUX_ND]])
@@ -1429,13 +1496,21 @@ def grow_tree(
                 # collective AFTER the bucket switch: shards may pick different
                 # bucket branches, so no psum may live inside them
                 small_hist = histogram_source(hist_axis).combine(small_hist)
+            # [streamed, needed] of the smaller child's pass and of the
+            # larger child's, where that one is summed from data too
+            small_rows = (hist_extent(small_cnt[None]), small_cnt)
+            large_cnt = pphys - small_cnt
+            large_rows = (hist_extent(large_cnt[None]), large_cnt)
         else:
             small_mask = (leaf_id == small_idx).astype(f32)
-            small_hist = leaf_histogram(
-                bins, masked_values(small_mask), B_hist, chunk=chunk,
-                axis_name=hist_axis, hist_dtype=hist_dtype,
-                feature_sharded=feature_sharded, route=hist_route,
-            )
+            with jax.named_scope("hist_build"):
+                small_hist = leaf_histogram(
+                    bins, masked_values(small_mask), B_hist, chunk=chunk,
+                    axis_name=hist_axis, hist_dtype=hist_dtype,
+                    feature_sharded=feature_sharded, route=hist_route,
+                )
+            small_rows = (N, jnp.sum(small_mask))
+            large_rows = (N, jnp.sum(leaf_id == large_idx))
         if bundled:
             if hist_axis is None and axis_name is not None:
                 # shard-local histograms: local remap (rec sums are global)
@@ -1459,11 +1534,12 @@ def grow_tree(
                     h = histogram_source(hist_axis).combine(h)
             else:
                 lmask = (leaf_id == large_idx).astype(f32)
-                h = leaf_histogram(
-                    bins, masked_values(lmask), B_hist, chunk=chunk,
-                    axis_name=hist_axis, hist_dtype=hist_dtype,
-                    feature_sharded=feature_sharded, route=hist_route,
-                )
+                with jax.named_scope("hist_build"):
+                    h = leaf_histogram(
+                        bins, masked_values(lmask), B_hist, chunk=chunk,
+                        axis_name=hist_axis, hist_dtype=hist_dtype,
+                        feature_sharded=feature_sharded, route=hist_route,
+                    )
             if bundled:
                 if hist_axis is None and axis_name is not None:
                     h = remap_hist_local(h)
@@ -1486,6 +1562,7 @@ def grow_tree(
             large_hist = jax.lax.cond(
                 cached, lambda: parent_hist - small_hist, large_direct
             )
+            direct = (~cached).astype(f32)  # the larger child read its rows
             # slots: the larger child inherits the parent's slot on a hit
             # (the reference's in-place Subtract); otherwise evict the LRU.
             ages = s.slot_age
@@ -1533,6 +1610,7 @@ def grow_tree(
                 large_hist = parent_hist - small_hist
             else:
                 large_hist = large_direct()
+            direct = 0.0 if use_subtract else 1.0
             slot_of, slot_leaf, slot_age = s.slot_of, s.slot_leaf, s.slot_age
             # ONE stacked scatter, not two chained .at[].set: XLA updates the
             # [M, F, B, 3] carry in place for a single scatter but inserts a
@@ -1576,6 +1654,12 @@ def grow_tree(
                 s.best.b.at[child_idx].set(pb2.b),
             )
 
+        tree = tree._replace(counters=_counted(
+            tree.counters, splits=1,
+            hist_rows_streamed=small_rows[0] + direct * large_rows[0],
+            hist_rows_needed=small_rows[1] + direct * large_rows[1],
+            part_rows_streamed=part_rows[0], part_rows_needed=part_rows[1],
+        ))
         return GrowState(
             it=s.it + 1,
             leaf_id=leaf_id,
@@ -1643,8 +1727,11 @@ def grow_tree(
     def body(s: GrowState) -> GrowState:
         best_leaf = jnp.argmax(s.best.f[:, 0]).astype(jnp.int32)
         rec = _unpack_best_row(s.best, best_leaf)
-        return apply_split(s, best_leaf, rec)
+        s = apply_split(s, best_leaf, rec)
+        return s._replace(tree=s.tree._replace(
+            counters=_counted(s.tree.counters, steps=1, slots_computed=1)))
 
+    @jax.named_scope("apply_split")
     def body_spec(s: GrowState) -> GrowState:
         """One speculative batch: compute the top-KB candidates' split work
         (skipping slots whose results are cached from an earlier batch),
@@ -1727,19 +1814,20 @@ def grow_tree(
         r_max = jnp.where(mono_f < 0, mid, pmax)
 
         ch_hist = jnp.concatenate([lhist, rhist], axis=0)  # [2KB, F, B, 3]
-        ch_res = jax.vmap(
-            lambda h, sg, sh, nd, mn, mx: find_best_split(
-                h, sg, sh, nd, mn, mx, feature_meta, feature_mask, params,
-                two_way=two_way,
+        with jax.named_scope("split_find"):
+            ch_res = jax.vmap(
+                lambda h, sg, sh, nd, mn, mx: find_best_split(
+                    h, sg, sh, nd, mn, mx, feature_meta, feature_mask, params,
+                    two_way=two_way,
+                )
+            )(
+                ch_hist,
+                jnp.concatenate([rf[:, 1], rf[:, 4]]),
+                jnp.concatenate([rf[:, 2], rf[:, 5]]),
+                jnp.concatenate([l_cnt, r_cnt]),
+                jnp.concatenate([l_min, r_min]),
+                jnp.concatenate([l_max, r_max]),
             )
-        )(
-            ch_hist,
-            jnp.concatenate([rf[:, 1], rf[:, 4]]),
-            jnp.concatenate([rf[:, 2], rf[:, 5]]),
-            jnp.concatenate([l_cnt, r_cnt]),
-            jnp.concatenate([l_min, r_min]),
-            jnp.concatenate([l_max, r_max]),
-        )
         depth_child = s.tree.leaf_i[b_top, 1] + 1  # [KB]
         ch_gain = depth_gate(
             ch_res.gain, jnp.concatenate([depth_child, depth_child])
@@ -1801,6 +1889,19 @@ def grow_tree(
             parent_aux[:, _LAUX_SG], parent_aux[:, _LAUX_SH], params
         )
         tree = PackedTree(
+            counters=_counted(
+                t.counters, steps=1, slots_computed=jnp.sum(compute),
+                splits=p,
+                # lanes: every one of the KB lanes passes the largest
+                # computing slot's bucket; flat: one concatenated pass
+                hist_rows_streamed=(
+                    flat_extent(small_cnt) if use_flat
+                    else hist_extent(small_cnt) * KB
+                ),
+                hist_rows_needed=jnp.sum(small_cnt),
+                part_rows_streamed=part_extent(pphys_c),
+                part_rows_needed=jnp.sum(pphys_c),
+            ),
             num_leaves=nl0 + p,
             node_f=t.node_f.at[nrow].set(
                 jnp.stack(
@@ -1894,7 +1995,12 @@ def grow_tree(
     else:
         out_leaf_id = final.leaf_id
 
-    out = (_unpack_tree(final.tree, M), out_leaf_id)
+    out_tree = _unpack_tree(final.tree, M)
+    if axis_name is not None:
+        # per-shard counts: the slowest shard sets the time
+        out_tree = out_tree._replace(
+            counters=jax.lax.pmax(out_tree.counters, axis_name))
+    out = (out_tree, out_leaf_id)
     if cegb_on:
         out = out + ((final.feature_used, final.used_in_data),)
     if hist_buf is not None:

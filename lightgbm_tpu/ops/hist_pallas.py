@@ -249,6 +249,7 @@ def _histogram_pallas_fb(
     kernel = functools.partial(_kernel_fb, hi_n=HI, dtype=dtype)
     out = pl.pallas_call(
         kernel,
+        name="hist_pallas_fb",
         grid=(Fp // FB, n_chunks),
         in_specs=[
             pl.BlockSpec((FB, C), lambda f8, c: (f8, c), memory_space=pltpu.VMEM),
@@ -328,6 +329,7 @@ def histogram_pallas_v1(
     kernel = functools.partial(_kernel, hi_n=HI, dtype=dtype)
     out = pl.pallas_call(
         kernel,
+        name="hist_pallas_v1",
         grid=(F, n_chunks),
         in_specs=[
             pl.BlockSpec((1, 1, C), lambda f, c: (f, 0, c), memory_space=pltpu.VMEM),
@@ -431,6 +433,7 @@ def histogram_pallas_packed4(
     kernel = functools.partial(_kernel_p4, num_bins=num_bins, dtype=dtype)
     out = pl.pallas_call(
         kernel,
+        name="hist_pallas_packed4",
         grid=(Fp // FB, n_chunks),
         in_specs=[
             pl.BlockSpec((FB, C), lambda f8, c: (f8, c), memory_space=pltpu.VMEM),
@@ -520,6 +523,7 @@ def histogram_pallas_onehot(
     kernel = functools.partial(_kernel_onehot, bt=BT, dtype=dtype)
     out = pl.pallas_call(
         kernel,
+        name="hist_pallas_onehot",
         grid=(Fp // FB, Bp // BT, n_chunks),
         in_specs=[
             pl.BlockSpec(
@@ -633,6 +637,7 @@ def histogram_pallas_bitplane(
     kernel = functools.partial(_kernel_bitplane, lob=lob, hib=hib, dtype=dtype)
     out = pl.pallas_call(
         kernel,
+        name="hist_pallas_bitplane",
         grid=(Fp // FB, n_chunks),
         in_specs=[
             pl.BlockSpec((FB, C), lambda f8, c: (f8, c), memory_space=pltpu.VMEM),

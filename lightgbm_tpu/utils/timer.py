@@ -23,10 +23,10 @@ Two numbers are recorded per phase:
 
 For kernel-level breakdowns use LIGHTGBM_TPU_PROFILE=<dir> instead, which
 wraps training in a ``jax.profiler`` trace readable in TensorBoard/Perfetto —
-the TPU-native counterpart of poking timers into the C++ learner. For host-
-side span timelines use LIGHTGBM_TPU_TRACE=<path> (obs/trace.py): every
-phase below also records a Chrome-trace span whenever that tracer is active,
-independent of whether the TIMETAG accumulators are on.
+the TPU-native counterpart of poking timers into the C++ learner. Every phase
+below also records a span in the in-program trace (obs/trace.py: its ring by
+default, a Chrome-trace file under LIGHTGBM_TPU_TRACE=<path>), independent of
+whether the TIMETAG accumulators are on.
 
 Clock: ``time.perf_counter`` throughout — monotonic. The pre-obs
 ``time.time()`` was wall-clock, so an NTP step mid-run silently corrupted
@@ -104,11 +104,11 @@ class PhaseTimers:
     @contextlib.contextmanager
     def phase(self, name: str):
         # the obs tracer records a span for every phase even when the
-        # TIMETAG accumulators are off — routed through trace_mod.span so
-        # the phase ALSO enters jax.profiler.TraceAnnotation and lines up
-        # with LIGHTGBM_TPU_PROFILE device timelines; span cost is paid
-        # only while a tracer is live, disabled cost is one global read
-        if not self.enabled and trace_mod.active() is None:
+        # TIMETAG accumulators are off (its ring is on by default) — routed
+        # through trace_mod.span so the phase ALSO enters
+        # jax.profiler.TraceAnnotation and lines up with device timelines;
+        # under LIGHTGBM_TPU_TRACE=0 the cost is one lookup
+        if not self.enabled and not trace_mod.recording("train.phase"):
             yield _NOOP
             return
         with trace_mod.span(name, cat="train.phase"):

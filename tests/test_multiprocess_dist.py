@@ -146,7 +146,12 @@ TRAIN_WORKER = textwrap.dedent(
         mesh, bins_g, row(grad), row(hess), row(ones),
         rep(np.ones(F, bool)), meta_g, **kw,
     )
-    tree_np = [np.asarray(x) for x in jax.device_get(tree)]
+    def model_arrays(t):
+        # the tree itself: its work counters (the last field) say how it
+        # was grown, and a shard of the rows is not grown like all of them
+        return [np.asarray(x) for x in jax.device_get(t)[:-1]]
+
+    tree_np = model_arrays(tree)
     blob = json.dumps([t.tolist() for t in tree_np], sort_keys=True)
     lid_local = np.asarray(
         [s.data for s in leaf_id.addressable_shards][0]
@@ -161,7 +166,7 @@ TRAIN_WORKER = textwrap.dedent(
         mesh, bins_g, row(grad), row(hess), row(ones),
         rep(np.ones(F, bool)), meta_g, top_k=F, **kw,
     )
-    vp_np = [np.asarray(x) for x in jax.device_get(tree_vp)]
+    vp_np = model_arrays(tree_vp)
 
     # ---- single-process serial oracle on this rank's own device --------
     meta_l = {k: jnp.asarray(v) for k, v in meta_np.items()}
@@ -169,7 +174,7 @@ TRAIN_WORKER = textwrap.dedent(
         jnp.asarray(ds.bins), jnp.asarray(grad), jnp.asarray(hess),
         jnp.asarray(ones), jnp.ones((F,), bool), meta_l, **kw,
     )
-    s_np = [np.asarray(x) for x in jax.device_get(tree_s)]
+    s_np = model_arrays(tree_s)
     blob_s = json.dumps([t.tolist() for t in s_np], sort_keys=True)
     lid_match = bool(
         (np.asarray(leaf_s)[shard] == lid_local).all()

@@ -14,6 +14,7 @@
   * satellites: perf_counter-based phase timers, log.warn_once with ISO
     timestamps, spec_rhist donation reuse.
 """
+import collections
 import json
 import os
 import re
@@ -122,11 +123,304 @@ def test_trace_spans_nest_inside_iteration(clean_obs, monkeypatch, tmp_path):
         ), ph
 
 
-def test_trace_disabled_is_silent(clean_obs, tmp_path):
+def test_trace_disabled_is_silent(clean_obs, monkeypatch):
+    """``LIGHTGBM_TPU_TRACE=0`` records nothing, ring included; without it a
+    span of no ring category stays out of the ring."""
+    trace.reset()
     assert trace.active() is None
-    with trace.span("nothing"):
+    with trace.span("nothing"):  # no category of the ring, no file mode
         pass
-    assert trace.stop() is None
+    assert trace.events() == [] and trace.stop() is None
+    monkeypatch.setenv("LIGHTGBM_TPU_TRACE", "0")
+    assert trace.active() is None and not trace.enabled()
+    assert not trace.recording("train")
+    _train_small(rounds=1)
+    with trace.span("nothing", cat="train", iteration=0):
+        trace.counters("grow.counters", cat="grow", tree=0)
+    assert trace.events() == [] and trace.stop() is None
+
+
+def test_ring_is_on_by_default_and_bounded(clean_obs):
+    trace.reset()
+    assert trace.active() is None and not trace.enabled()
+    assert trace.recording("train") and not trace.recording("serve")
+    with trace.span("first", cat="train"):
+        pass
+    assert [e["name"] for e in trace.events()] == ["first"]
+    extra = 10
+    for k in range(trace.RING_EVENTS + extra - 1):
+        trace.counters("fill", cat="grow", tree=k)
+    events = trace.events()
+    assert len(events) == trace.RING_EVENTS
+    assert trace.dropped() == extra            # the oldest went, and are counted
+    assert events[0]["args"]["tree"] == extra - 1
+    assert events[-1]["args"]["tree"] == trace.RING_EVENTS + extra - 2
+    trace.reset()
+    assert trace.events() == [] and trace.dropped() == 0
+
+
+def test_events_is_a_copy(clean_obs):
+    trace.reset()
+    with trace.span("kept", cat="train", iteration=4):
+        pass
+    first = trace.events()
+    first[0]["name"] = "changed"
+    first[0]["args"]["iteration"] = 99
+    first.clear()
+    again = trace.events()
+    assert [e["name"] for e in again] == ["kept"]
+    assert again[0]["args"] == {"iteration": 4}
+
+
+SETUP_CHILDREN = {"dataset.to_float", "dataset.sample", "dataset.find_bins",
+                  "dataset.bin_matrix"}
+
+
+def test_ids_and_parents_form_a_tree_over_a_training_run(clean_obs):
+    jax.clear_caches()  # so that this run compiles, and jit.* has parents
+    trace.reset()
+    _train_small(rounds=3)
+    events = trace.events()
+    by_id = {e["id"]: e for e in events}
+    assert len(by_id) == len(events)  # ids are unique
+    for e in events:
+        for key in ("name", "cat", "ts", "id", "parent", "pid", "tid"):
+            assert key in e, (key, e)
+        seen = set()
+        while e["parent"] is not None:  # every chain ends at a root
+            assert e["id"] not in seen
+            seen.add(e["id"])
+            e = by_id[e["parent"]]
+
+    def parent_name(e):
+        return None if e["parent"] is None else by_id[e["parent"]]["name"]
+
+    names = collections.Counter(e["name"] for e in events)
+    assert names["train.iteration"] == names["train.boundary"] == 3
+    assert names["train.init"] == names["dataset.construct"] == 1
+    for e in events:
+        if e["name"] in PHASES | {"valid scores"}:
+            assert parent_name(e) == "train.iteration", e
+            assert e["args"]["iteration"] == by_id[e["parent"]]["args"]["iteration"]
+        elif e["name"] == "train.callbacks":
+            assert parent_name(e) == "train.boundary", e
+        elif e["name"] in SETUP_CHILDREN:
+            assert parent_name(e) == "dataset.construct", e
+        elif e["name"] in ("train.iteration", "train.boundary", "train.init"):
+            assert e["parent"] is None, e
+        elif e["name"].startswith("jit."):
+            # a program is traced, lowered and built where it is first
+            # called: under a phase, set-up, or the boundary's eval
+            assert e["parent"] is not None and e["cat"] == "compile", e
+            assert "dur" in e and "fun" in e["args"], e
+    # the deferred stop check waits inside the NEXT iteration, and once more
+    # after the loop (engine._finish_train), outside any iteration
+    waits = [e for e in events if e["name"] == "train.wait_prev_tree"]
+    assert [parent_name(w) for w in waits] == ["train.iteration"] * 2 + [None]
+    assert [w["args"].get("iteration") for w in waits] == [1, 2, None]
+    assert all(names[child] == 1 for child in SETUP_CHILDREN)
+    init = next(e for e in events if e["name"] == "train.init")
+    assert init["args"]["bytes"] == 500 * 4  # the binned matrix, one byte a cell
+    # a fixed handful per iteration and per Dataset, never per row or split
+    per_iteration = collections.Counter(
+        e["args"]["iteration"] for e in events
+        if e["cat"] != "compile" and "iteration" in e["args"])
+    assert max(per_iteration.values()) < 20
+
+
+def test_compile_spans_on_the_first_train_only(clean_obs):
+    jax.clear_caches()
+    trace.reset()
+    _train_small(rounds=2)
+    first = [e for e in trace.events() if e["cat"] == "compile"]
+    built = [e["args"]["fun"] for e in first if e["name"] == "jit.compile"]
+    assert "jit(grow_tree)" in built
+    # one top-level jit.trace a program, not one per jitted call inside it
+    per_name = collections.Counter(e["name"] for e in first)
+    assert per_name["jit.trace"] <= per_name["jit.lower"] == per_name["jit.compile"]
+    trace.reset()
+    _train_small(rounds=2)
+    again = [e["args"]["fun"] for e in trace.events() if e["name"] == "jit.compile"]
+    # the same shapes: the grower is not built again (the per-Booster
+    # closure of the score update is: GBDT._finish_fns)
+    assert "jit(grow_tree)" not in again and len(again) < len(built) / 4
+
+
+def test_a_programs_own_trace_is_told_from_the_enclosed_ones(
+        clean_obs, monkeypatch):
+    """jax reports every jitted function traced on the way, enclosed ones
+    first, and kernels traced while another program is lowered: jit.trace is
+    the one that ended last before the lowering began."""
+    trace.reset()
+    clock = [0.0]
+    monkeypatch.setattr(trace, "now_us", lambda: clock[0])
+
+    def fire(kind, end_s, seconds, fun):
+        clock[0] = end_s * 1e6
+        trace._on_jax_duration(
+            "/jax/core/compile/%s_duration" % kind, seconds, fun_name=fun)
+
+    fire("jaxpr_trace", 1.2, 0.1, "where")             # inside grow_tree's
+    fire("jaxpr_trace", 2.0, 1.5, "grow_tree")         # the program's own
+    fire("jaxpr_trace", 2.6, 0.2, "kernel")            # while lowering
+    fire("jaxpr_to_mlir_module", 3.0, 0.99, "jit(grow_tree)")
+    fire("backend_compile", 9.0, 6.0, "jit(grow_tree)")
+    fire("some/other", 9.5, 0.1, "ignored")
+    fire("jaxpr_to_mlir_module", 10.0, 0.5, "jit(aot)")  # lowered, not traced
+    got = [(e["name"], e["args"]["fun"], e["ts"], e["dur"]) for e in trace.events()]
+    assert got == [
+        ("jit.trace", "grow_tree", 0.5e6, 1.5e6),
+        ("jit.lower", "jit(grow_tree)", 2.01e6, 0.99e6),
+        ("jit.compile", "jit(grow_tree)", 3.0e6, 6.0e6),
+        ("jit.lower", "jit(aot)", 9.5e6, 0.5e6),
+    ]
+
+
+def test_serve_spans_stay_out_of_the_ring(clean_obs, tmp_path):
+    trace.reset()
+    bst, X = _train_small()
+    model = str(tmp_path / "m.txt")
+    bst.save_model(model)
+    from lightgbm_tpu.serve.server import ServeApp
+
+    app = ServeApp(max_delay_ms=1.0, min_bucket_rows=8)
+    try:
+        app.registry.load("m", model)
+        out, _ = app.predict(X[:5])
+        assert out.shape[0] == 5
+    finally:
+        app.close()
+    names = {e["name"] for e in trace.events()}
+    assert "train.iteration" in names
+    assert not [n for n in names if n.startswith(("serve.", "loop.", "cli."))]
+
+
+def test_file_mode_still_writes_a_loadable_file_beside_the_ring(
+        clean_obs, monkeypatch, tmp_path):
+    path = str(tmp_path / "trace.json")
+    monkeypatch.setenv("LIGHTGBM_TPU_TRACE", path)
+    trace.reset()
+    assert trace.enabled() and trace.recording("serve")
+    _train_small(rounds=2)
+    with trace.span("serve.request", cat="serve"):
+        pass
+    assert trace.stop() == path
+    doc = json.load(open(path))
+    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    in_file = {e["name"] for e in spans}
+    assert {"train.iteration", "train.boundary", "serve.request"} <= in_file
+    assert all("id" in e and "parent" in e for e in spans)
+    assert any(e.get("ph") == "M" for e in doc["traceEvents"])  # thread names
+    in_ring = {e["name"] for e in trace.events()}
+    assert "train.iteration" in in_ring and "serve.request" not in in_ring
+
+
+def test_an_abandoned_span_is_closed_by_its_frame(clean_obs):
+    """``__enter__()`` without ``with`` (engine.train's train.init, the
+    loop's train.boundary): ``close_to`` in the caller's ``finally`` ends what
+    an exception left open, so later spans do not hang under it."""
+    trace.reset()
+    depth = trace.open_depth()
+    try:
+        outer = trace.span("train.init", cat="setup").__enter__()
+        trace.span("train.boundary", cat="train", iteration=7).__enter__()
+        raise RuntimeError("a callback failed")
+    except RuntimeError:
+        trace.close_to(depth)
+    outer.close()  # closing twice records once
+    with trace.span("after", cat="train"):
+        pass
+    events = trace.events()
+    assert [e["name"] for e in events] == ["train.boundary", "train.init", "after"]
+    assert events[2]["parent"] is None and events[2]["args"] == {}
+    assert trace.open_depth() == depth
+    with pytest.raises(LightGBMError):  # engine.train itself, left by an error
+        lgb.train({"objective": "no-such-objective", "verbosity": -1},
+                  lgb.Dataset(np.zeros((10, 2)), label=np.zeros(10)))
+    assert trace.open_depth() == depth
+
+
+# ---------------------------------------------------------------------------
+# the grower's work counters and scopes
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def grow_mode(monkeypatch):
+    def set_mode(mode):
+        monkeypatch.setattr(grow_mod, "_ENV_GROW", mode)
+        jax.clear_caches()
+
+    yield set_mode
+    monkeypatch.setattr(grow_mod, "_ENV_GROW", "")
+    jax.clear_caches()
+
+
+def _recount(tree, rows):
+    """What a tree's splits needed, from the materialised tree alone."""
+    def count(child):
+        return (tree.leaf_count[-(child + 1)] if child < 0
+                else tree.internal_count[child])
+
+    splits = tree.num_leaves - 1
+    smaller = sum(min(count(int(l)), count(int(r)))
+                  for l, r in zip(tree.left_child[:splits], tree.right_child[:splits]))
+    return {"splits": splits, "hist_rows": rows + smaller,
+            "part_rows": int(sum(tree.internal_count[:splits]))}
+
+
+@pytest.mark.parametrize("mode", ["seq", "spec"])
+def test_grower_counters_against_a_host_recount(clean_obs, grow_mode, mode):
+    grow_mode("seq")
+    reference = _train_small(rounds=3, n=1500, leaves=31)[0].model_to_string()
+    grow_mode(mode)
+    trace.reset()
+    bst, X = _train_small(rounds=3, n=1500, leaves=31)
+    assert grow_mod._LAST_GROW_MODE == mode
+    assert not [e for e in trace.events() if e["name"] == "grow.counters"]
+    text = bst.model_to_string()  # materialises the trees: one event each
+    assert text == reference  # counting changes no tree
+    counted = [e for e in trace.events() if e["name"] == "grow.counters"]
+    assert [e["args"]["tree"] for e in counted] == [0, 1, 2]
+    assert [e["args"]["iteration"] for e in counted] == [0, 1, 2]
+    assert all(e["ph"] == "C" and e["cat"] == "grow" for e in counted)
+    bst.model_to_string()  # nothing is emitted twice
+    assert len([e for e in trace.events() if e["name"] == "grow.counters"]) == 3
+    for e, tree in zip(counted, bst._gbdt.trees()):
+        c, want = e["args"], _recount(tree, len(X))
+        assert set(grow_mod.COUNTER_NAMES) <= set(c)
+        assert c["splits"] == want["splits"] > 0
+        assert c["slots_computed"] >= c["splits"]
+        assert c["hist_rows_streamed"] >= c["hist_rows_needed"]
+        assert c["part_rows_streamed"] >= c["part_rows_needed"]
+        if mode == "seq":
+            assert c["steps"] == c["slots_computed"] == c["splits"]
+            assert c["hist_rows_needed"] == want["hist_rows"]
+            assert c["part_rows_needed"] == want["part_rows"]
+        else:
+            # a pass applies a batch; a slot computed and never applied read
+            # its rows for nothing
+            assert c["steps"] < c["splits"]
+            assert c["hist_rows_needed"] >= want["hist_rows"]
+            assert c["part_rows_needed"] >= want["part_rows"]
+            wasted = c["slots_computed"] - c["splits"]
+            assert (wasted == 0) == (c["hist_rows_needed"] == want["hist_rows"])
+
+
+def test_grow_tree_lowers_with_its_four_scopes(clean_obs):
+    bst, _ = _train_small(rounds=1)
+    g = bst._gbdt
+    cfg = g.config
+    n = g.num_data
+    lowered = grow_mod.grow_tree.lower(
+        g.bins_dev, jnp.zeros((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+        g._bag_mask, g._fmask_all, g.feature_meta,
+        num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
+        num_bins=g.num_bins, params=g.split_params,
+    )
+    text = lowered.as_text(debug_info=True)
+    for scope in ("hist_build", "partition", "split_find", "apply_split"):
+        assert "/%s/" % scope in text or "/%s\"" % scope in text, scope
 
 
 def test_phase_spans_without_timetag(clean_obs, monkeypatch, tmp_path):
