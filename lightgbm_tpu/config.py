@@ -405,9 +405,11 @@ class Config:
     # (full-N masked passes; the differential oracle, ops/grow.py).
     tpu_hist_mode: str = "bucketed"
     # TPU-only: MXU operand dtype for the Pallas histogram kernel —
-    # "float32" (exact, 3-pass MXU) or "bfloat16" (single pass, ~3x faster;
-    # grad/hess operands round to bf16, accumulation stays f32 — the
-    # reference GPU path's single-precision trade, GPU-Performance.rst:131).
+    # "float32" (exact: each value goes to the MXU as three bf16 pieces
+    # that sum to it bit for bit, in one pass; ops/hist_pallas.split_bf16)
+    # or "bfloat16" (one piece: grad/hess operands round to bf16, ~1.35x
+    # faster on a v5e; accumulation stays f32 either way — the reference
+    # GPU path's single-precision trade, GPU-Performance.rst:131).
     tpu_hist_dtype: str = "float32"
     # Device-resident boosting: fuse this many boosting iterations into ONE
     # jitted lax.scan dispatch (models/gbdt.py train_chunk). 1 = the

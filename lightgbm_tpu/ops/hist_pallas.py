@@ -8,32 +8,50 @@ reformulated as a matmul the MXU can run, with the accumulator block resident
 in VMEM across the row-chunk grid (the analogue of the OpenCL kernel's
 workgroup-local shared-memory sub-histograms).
 
-Why not the plain one-hot contraction (ops/histogram.py)? Its LHS has M=3 rows
-(grad, hess, count), so every 128-wide MXU pass computes 3 useful rows — a
-~40x utilization waste at 256 bins. This kernel uses a *radix factorization*:
+Why not the plain one-hot contraction (ops/histogram.py)? It contracts K=3
+channels against a B-wide one-hot, three useful rows of an MXU pass. This
+kernel uses a *radix factorization*:
 
     bin = hi * LO + lo          (LO = 8, HI = ceil(B / 8))
 
     hist[f, hi*LO + lo, k] = sum_i 1[hi_i = hi] * v[i, k] * 1[lo_i = lo]
-                           = (onehot_hi (x) values)^T-ish matmul:
-      LHS [HI*K, C]: row (h, k) carries onehot_hi[h, i] * values[k, i]
-      RHS [C,  LO]: onehot_lo
-      OUT [HI*K, LO] accumulated in f32, reshaped to [B, K] outside.
 
-With K=3 channels and B=256 bins this packs M = 3*ceil(256/8) = 96 rows into
-the 128-row MXU pass (vs 3), an ~11x improvement in streamed-row utilization,
-while the RHS one-hot shrinks from [C, 256] to [C, 8] (fewer weight tiles).
-The one-hot build is exact in any dtype (0/1 entries); ``dtype=bfloat16``
-additionally rounds the grad/hess operand to bf16 before the MXU (accumulation
-stays f32 via preferred_element_type) — the same single-precision-accumulator
-trade the reference's GPU path makes and validates for AUC parity
-(/root/reference/docs/GPU-Performance.rst:131-145); pass float32 to match the
-XLA fallback bit-for-bit more closely.
+The routed kernel (``_kernel_fb``, feature-batched) feeds that to the MXU
+with the rows on the lanes of both operands:
 
-Grid: (F, N/C). The output block index map pins each feature's accumulator to
-the same VMEM block across all row chunks, so partial histograms never round-
-trip through HBM (pallas revisiting semantics). Inputs stream: bins [1, C]
-int8 and the shared values [K, C] f32 per step.
+      oh_hi [HI, C]       bf16: 1 where row i's high digit is hi
+      vlo   [P*K*LO, C]   bf16: row (p, k, lo) = piece p of v[i, k] where
+                                row i's low digit is lo, else 0
+      OUT   [HI, P*K*LO]  = oh_hi . vlo^T over the rows, accumulated in f32;
+                          the wrapper adds the P pieces and reshapes to
+                          [B, K].
+
+Exactness comes from the split of the values, not from a matmul precision:
+a float32 operand (``dtype=float32``) is cut once a grid step into P = 3
+bfloat16 pieces that sum back to it bit for bit (``split_bf16``: 24
+significand bits = 8 + 8 + 8), the one-hots are exact 0/1 in bfloat16, so
+ONE single-pass bf16 contraction forms the very products a
+``Precision.HIGHEST`` float32 dot would (piece x 0/1), and they are
+accumulated in float32. ``dtype=bfloat16`` is the one-piece case of the same
+body: it rounds the grad/hess operand to bf16 before the MXU (accumulation
+stays f32) — the single-precision-accumulator trade the reference's GPU path
+makes and validates for AUC parity
+(/root/reference/docs/GPU-Performance.rst:131-145).
+
+Why the rows sit on the lanes and the one-hot is the streamed operand (one
+v5e, 2000 features x 255 bins; PERF.md §6, PR 26): a ``[C, HI]`` one-hot
+with the rows on the sublanes uses 32 of 128 lanes and needs a
+lane-to-sublane relayout of every row's high digit; building it, not the
+MXU's passes, bound a ``Precision.HIGHEST`` body at 0.77 ns a row and
+feature (a single pass over the same operands won 8%). Lane-dense operands
+read 0.117 ns with the values streamed and 0.066 with the one-hot streamed.
+
+Grid: (F/FB, N/C). The output block index map pins each feature batch's
+accumulator to the same VMEM block across all row chunks, so partial
+histograms never round-trip through HBM (pallas revisiting semantics).
+Inputs stream: bins [FB, C] u8 and the shared values [K, C] f32 per step.
+The per-feature-grid v1 kernel (``histogram_pallas_v1``) keeps the older
+orientation and ``Precision.HIGHEST``; it is a differential oracle only.
 
 ISSUE 17 adds two wide-bin siblings, both feature-batched like the v2 radix
 kernel and registered as first-class routing contenders:
@@ -58,7 +76,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LO = 8  # low-radix width: RHS one-hot lanes
+LO = 8  # low-radix width: one sublane tile of the values operand
+LO_BITS = LO.bit_length() - 1  # bin = (hi << LO_BITS) | lo
 
 # Scoped-VMEM budget for one grid step: Mosaic's limit (16MiB by default on
 # a v5e, obs/costs.CHIP_PEAKS vmem_bytes) less this margin for its own stack.
@@ -87,8 +106,11 @@ def _vmem_budget() -> int:
 # needs MORE than f32 here) — so every kernel was refused at the trainer's
 # tpu_hist_chunk=16384. One figure serves both operand dtypes, the larger.
 _BYTES_PER_COL = {
-    # bf16 24.65M / 16384 = 1578; f32 16.49M / 13824 = 1251
-    "pallas": 1580,
+    # the lane-dense body of PR 26, read the same way on the chip (libtpu
+    # 0.0.34): f32 46.07M / 200192 = 241, bf16 27.53M / 200192 = 144; the
+    # compiler run for a described v5e with no chip says the same (f32
+    # 23.10M / 99840 = 243)
+    "pallas": 244,
     # bf16 17.68M / 12288 = 1509; f32 compiles at 6144, which this keeps
     "pallas_onehot": 1640,
     # f32, 16x16 split: 57.52M / 12800 = 4712 (and 18.82M / 4096); bf16 not
@@ -175,41 +197,68 @@ def _kernel(bins_ref, vt_ref, out_ref, *, hi_n: int, dtype):
     )
 
 
-def _kernel_fb(bins_ref, vt_ref, out_ref, *, hi_n: int, dtype):
+def split_bf16(v: jax.Array, pieces: int):
+    """``pieces`` bfloat16-representable float32 arrays that sum to ``v``.
+
+    One piece is ``bf16(v)``: the rounding the caller asked for with
+    ``dtype=bfloat16``. Three pieces are exact: ``p0 = bf16(v)``, ``p1 =
+    bf16(v - p0)``, ``p2 = v - p0 - p1`` with the subtractions in float32.
+    Each remainder is exact in float32, and the third fits bf16's 8
+    significand bits (24 = 8 + 8 + 8), so ``p0 + p1 + p2 == v`` bit for bit
+    wherever the smallest piece is a normal number: ``|v|`` from 1e-30 up to
+    bf16's largest finite value."""
+    out, r = [], v
+    for i in range(pieces):
+        p = r.astype(jnp.bfloat16).astype(jnp.float32)
+        out.append(p)
+        if i + 1 < pieces:
+            r = r - p
+    return out
+
+
+def _pieces_for(dtype) -> int:
+    """MXU passes' worth of bf16 pieces that carry an operand of ``dtype``."""
+    return 3 if jnp.dtype(dtype) == jnp.float32 else 1
+
+
+def _kernel_fb(bins_ref, vt_ref, out_ref, *, hi_n: int, pieces: int):
     """Feature-batched kernel body: one grid step consumes an [FB, C] bins
-    block + ONE [K, C] values block and unrolls the FB features in VMEM. The
-    v1 grid (F, chunks) re-streamed the values block once per feature — 9x
-    the HBM traffic at F=28 — and measured DMA-bound on silicon (bf16 == f32
-    time, 34.8ms for 1Mx28x255). The factor orientation also flips vs v1:
-    lhs = onehot_lo (x) values [LO*K, C] (24 rows of VPU build work per row
-    instead of 96), rhs = onehot_hi [C, HI]."""
+    block + ONE [K, C] values block and unrolls the FB features in VMEM.
+
+    The values are split once a step into ``pieces`` bf16 pieces (3 for
+    float32 operands, exact: :func:`split_bf16`; 1 for bfloat16); one
+    single-pass bf16 ``dot_general`` a feature contracts oh_hi [HI, C]
+    with vlo [P*K*LO, C] over their shared last axis (the transposed-RHS
+    form; operands as the module docstring lays them out) into
+    out[hi, (p, k, lo)], float32. The wrapper adds the pieces."""
     c = pl.program_id(1)
 
     @pl.when(c == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    vt = vt_ref[:].astype(dtype)  # [K, C]
-    k_n, C = vt.shape
+    pk = jnp.concatenate(split_bf16(vt_ref[:], pieces), axis=0)  # [P*K, C]
+    m_n, C = pk.shape
     b_all = bins_ref[:, :].astype(jnp.int32)  # [FB, C]
-    hi_all = b_all // LO
-    lo_all = b_all - hi_all * LO
+    hi_all = b_all >> LO_BITS
+    lo_all = b_all & (LO - 1)
     lo_iota = jax.lax.broadcasted_iota(jnp.int32, (LO, C), 0)
-    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (C, hi_n), 1)
-    prec = (
-        jax.lax.Precision.HIGHEST
-        if dtype == jnp.float32
-        else jax.lax.Precision.DEFAULT
-    )
+    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (hi_n, C), 0)
     for j in range(FB):  # static unroll: register slices, no dynamic u8 rows
-        oh_lo = (lo_all[j][None, :] == lo_iota).astype(dtype)  # [LO, C]
-        lhs = (oh_lo[:, None, :] * vt[None, :, :]).reshape(LO * k_n, C)
-        oh_hi = (hi_all[j][:, None] == hi_iota).astype(dtype)  # [C, HI]
+        lo_hit = lo_all[j][None, :] == lo_iota  # [LO, C]
+        # built in float32 ([P*K, LO, C] -> [P*K*LO, C] moves nothing: LO
+        # rows are one sublane tile) and cast once: the pieces are bf16
+        # values already
+        vlo = (
+            jnp.where(lo_hit[None, :, :], pk[:, None, :], 0.0)
+            .reshape(m_n * LO, C)
+            .astype(jnp.bfloat16)
+        )
+        oh_hi = (hi_all[j][None, :] == hi_iota).astype(jnp.bfloat16)
         out_ref[j] += jax.lax.dot_general(
-            lhs, oh_hi,
-            dimension_numbers=(((1,), (0,)), ((), ())),
+            oh_hi, vlo,
+            dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=prec,
         )
 
 
@@ -229,16 +278,17 @@ def _histogram_pallas_fb(
     K = values.shape[1]
     B = num_bins
     HI = _hi_for(B)
-    dtype = jnp.dtype(dtype_name)
+    P = _pieces_for(dtype_name)
 
-    C = min(max(chunk, 512), max(512, N), _max_chunk_for("pallas"))
-    C = max(512, (C // 512) * 512)
-    if N % C != 0:
-        pad = (-N) % C
+    # equal chunks under the cap: the grower's lattice sizes (2^k, 3*2^k)
+    # then pad by nothing, where a fixed C pads 8192 rows to 12288
+    n_chunks = -(-N // min(max(chunk, 512), _max_chunk_for("pallas")))
+    C = -(-N // (n_chunks * 512)) * 512
+    if N != n_chunks * C:
+        pad = n_chunks * C - N
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         values = jnp.pad(values, ((0, pad), (0, 0)))
         N += pad
-    n_chunks = N // C
     Fp = -(-F // FB) * FB
     if Fp != F:
         # padded feature rows histogram the padded bins (all zero) against
@@ -246,7 +296,7 @@ def _histogram_pallas_fb(
         bins = jnp.pad(bins, ((0, Fp - F), (0, 0)))
 
     vt = values.T  # [K, N]
-    kernel = functools.partial(_kernel_fb, hi_n=HI, dtype=dtype)
+    kernel = functools.partial(_kernel_fb, hi_n=HI, pieces=P)
     out = pl.pallas_call(
         kernel,
         name="hist_pallas_fb",
@@ -256,18 +306,20 @@ def _histogram_pallas_fb(
             pl.BlockSpec((K, C), lambda f8, c: (0, c), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(
-            (FB, LO * K, HI), lambda f8, c: (f8, 0, 0), memory_space=pltpu.VMEM
+            (FB, HI, P * K * LO), lambda f8, c: (f8, 0, 0),
+            memory_space=pltpu.VMEM,
         ),
-        out_shape=jax.ShapeDtypeStruct((Fp, LO * K, HI), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Fp, HI, P * K * LO), jnp.float32),
         interpret=interpret,
     )(bins, vt)
 
-    # out[f, lo*K + k, hi] -> hist[f, hi*LO + lo, k]
-    hist = (
-        out.reshape(Fp, LO, K, HI)
-        .transpose(0, 3, 1, 2)
-        .reshape(Fp, HI * LO, K)
-    )
+    # out[f, hi, (p*K + k)*LO + lo] -> hist[f, hi*LO + lo, k]; the pieces
+    # are added largest first, in an order no fusion can change
+    parts = out.reshape(Fp, HI, P, K, LO)
+    total = parts[:, :, 0]
+    for p in range(1, P):
+        total = total + parts[:, :, p]
+    hist = total.transpose(0, 1, 3, 2).reshape(Fp, HI * LO, K)
     return hist[:F, :B, :]
 
 

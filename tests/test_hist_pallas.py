@@ -62,18 +62,181 @@ def test_pallas_masked_rows_contribute_nothing(rng):
     np.testing.assert_allclose(out[:, :, 2].sum(axis=1), mask.sum(), rtol=1e-6)
 
 
-def test_feature_batched_matches_v1(rng):
+def _pallas_call_eqn(n, chunk, dtype_name, F=8, num_bins=255):
+    """The ``pallas_call`` equation of the routed kernel's wrapper at
+    ``[F, n]`` bins (traced on shapes; nothing runs)."""
+    import functools
+
+    import jax
+
+    from lightgbm_tpu.ops.hist_pallas import _histogram_pallas_fb
+
+    fn = functools.partial(
+        _histogram_pallas_fb.__wrapped__, num_bins=num_bins, chunk=chunk,
+        dtype_name=dtype_name, interpret=True,
+    )
+    jaxpr = jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct((F, n), jnp.uint8),
+        jax.ShapeDtypeStruct((n, 3), jnp.float32),
+    )
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return eqn
+
+
+def _block_shape(eqn, i):
+    block = eqn.params["grid_mapping"].block_mappings[i].block_shape
+    return tuple(int(getattr(d, "block_size", d)) for d in block)
+
+
+def _split_cases():
+    r = np.random.RandomState(7)
+    wide = (
+        r.uniform(1.0, 2.0, 200000) * 10.0 ** r.uniform(-30, 30, 200000)
+    ).astype(np.float32)
+    # every one of the 24 significand bits set, at many exponents
+    full = np.float32(2.0 - 2.0 ** -23) * np.float32(2.0) ** np.arange(-90, 90)
+    return {
+        "positive_1e-30_to_1e30": wide,
+        "negative_1e-30_to_1e30": -wide,
+        "zeros_and_signed_zero": np.array([0.0, -0.0, 0.0], np.float32),
+        "all_24_bits_set": np.concatenate([full, -full]).astype(np.float32),
+        "halfway_to_the_next_bf16": (
+            np.float32(1.0 + 2.0 ** -8) * np.float32(2.0) ** np.arange(-60, 60)
+        ).astype(np.float32),
+        "gradients": r.randn(100000).astype(np.float32),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_split_cases()))
+def test_three_piece_split_is_exact(case):
+    """The float32 operand contract: three bf16 pieces that sum back to the
+    float32 value bit for bit, each exactly representable in bfloat16."""
+    from lightgbm_tpu.ops.hist_pallas import split_bf16
+
+    v = _split_cases()[case]
+    pieces = [np.asarray(p) for p in split_bf16(jnp.asarray(v), 3)]
+    assert len(pieces) == 3
+    for p in pieces:
+        assert p.dtype == np.float32
+        np.testing.assert_array_equal(
+            np.asarray(jnp.asarray(p).astype(jnp.bfloat16).astype(jnp.float32)), p
+        )
+    np.testing.assert_array_equal((pieces[0] + pieces[1]) + pieces[2], v)
+    # one piece is the rounding the caller asked for, nothing else
+    (one,) = split_bf16(jnp.asarray(v), 1)
+    np.testing.assert_array_equal(
+        np.asarray(one), np.asarray(jnp.asarray(v).astype(jnp.bfloat16).astype(jnp.float32))
+    )
+
+
+@pytest.mark.parametrize("num_bins", [63, 255])
+@pytest.mark.parametrize("values", ["gradients_1e-6_to_1e3", "integers"])
+def test_f32_kernel_against_float64_oracle(rng, num_bins, values):
+    """Rows no multiple of the chunk, F no multiple of FB. Gradients over
+    nine decades at the tolerance the kernel has always been held to;
+    integer values whose sums fit 24 bits come out exactly."""
+    F, n = 11, 9001  # three chunks of 3072 under histogram_pallas' 4096
+    bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
+    if values == "integers":
+        vals = rng.randint(-300, 300, (n, 3)).astype(np.float32)
+    else:
+        vals = (
+            rng.randn(n, 3) * 10.0 ** rng.uniform(-6, 3, (n, 3))
+        ).astype(np.float32)
+    ref = histogram_reference(bins, vals, num_bins)
+    out = np.asarray(
+        histogram_pallas(
+            jnp.asarray(bins), jnp.asarray(vals), num_bins,
+            chunk=4096, dtype_name="float32", interpret=True,
+        )
+    )
+    if values == "integers":
+        np.testing.assert_array_equal(out, ref)
+        return
+    # today's tolerance (rtol=1e-5, atol=1e-4) where it can hold at all;
+    # float32 accumulation itself errs by a few ulps of a bin's sum of |v|
+    # (1.5e-4 where thousands cancel), so the bound everywhere is 1e-6 of
+    # that sum, the tighter of the two wherever both apply: a dropped
+    # third piece would read 8e-6 of it, a dropped second 2e-3
+    mass = histogram_reference(bins, np.abs(vals), num_bins)
+    err = np.abs(out - ref)
+    assert (err <= 1e-6 * mass).all()
+    assert (err <= np.maximum(1e-5 * np.abs(ref) + 1e-4, 1e-6 * mass)).all()
+
+
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_vmapped_lanes_equal_single_calls_bitwise(rng, dtype_name):
+    """The contract the grower's ``lanes`` form rests on (ops/grow.py,
+    _ENV_SPEC_HIST): a vmapped lane runs the kernel verbatim."""
+    import jax
+
+    W, F, n, B = 8, 10, 5000, 255  # two chunks of 2560
+    bins = rng.randint(0, B, (W, F, n)).astype(np.uint8)
+    vals = (rng.randn(W, n, 3) * 10.0 ** rng.uniform(-3, 2, (W, n, 3))).astype(
+        np.float32
+    )
+    one = lambda b, v: histogram_pallas(
+        b, v, B, chunk=4096, dtype_name=dtype_name, interpret=True
+    )
+    lanes = np.asarray(jax.vmap(one)(jnp.asarray(bins), jnp.asarray(vals)))
+    for w in range(W):
+        single = np.asarray(one(jnp.asarray(bins[w]), jnp.asarray(vals[w])))
+        np.testing.assert_array_equal(lanes[w], single)
+
+
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_feature_batched_matches_v1(rng, dtype_name):
     """The default (feature-batched) kernel against the per-feature-grid v1
-    at the same chunking — same radix math, different grid/factor layout."""
+    at the same chunking — same radix math, different grid/factor layout.
+    bfloat16 is the one-piece case of the same body: the same rounded
+    operands as v1's, so the same tolerance."""
     from lightgbm_tpu.ops.hist_pallas import histogram_pallas_v1
 
     F, n, B = 5, 4096, 255
     bins = rng.randint(0, B, (F, n)).astype(np.uint8)
     vals = rng.randn(n, 3).astype(np.float32)
-    kw = dict(chunk=1024, dtype_name="float32", interpret=True)
+    kw = dict(chunk=1024, dtype_name=dtype_name, interpret=True)
     h2 = np.asarray(histogram_pallas(jnp.asarray(bins), jnp.asarray(vals), B, **kw))
     h1 = np.asarray(histogram_pallas_v1(jnp.asarray(bins), jnp.asarray(vals), B, **kw))
     np.testing.assert_allclose(h1, h2, rtol=1e-6, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "dtype_name,pieces", [("float32", 3), ("bfloat16", 1)]
+)
+def test_accumulator_block_follows_the_operand_dtype(dtype_name, pieces):
+    """One body, one parameter it observes: the accumulator block is
+    [FB, HI, pieces*K*LO], so bfloat16 keeps the 24 columns it always had
+    and float32 carries its three pieces side by side."""
+    from lightgbm_tpu.ops.hist_pallas import FB, LO, _pieces_for
+
+    assert _pieces_for(dtype_name) == pieces
+    eqn = _pallas_call_eqn(2048, 1024, dtype_name, F=5)
+    assert _block_shape(eqn, -1) == (FB, 32, pieces * 3 * LO)
+    assert eqn.outvars[0].aval.dtype == jnp.float32
+    # operands reach the MXU as bfloat16 only through the split: the
+    # kernel's inputs are the u8 bins and the float32 values as passed
+    assert [str(v.aval.dtype) for v in eqn.invars] == ["uint8", "float32"]
+
+
+@pytest.mark.parametrize(
+    "n,chunk,want",
+    [
+        (8192, 6144, (2, 4096)),      # a fixed C=6144 padded this to 12288
+        (24576, 16384, (2, 12288)),
+        (98304, 16384, (6, 16384)),
+        (200000, 16384, (13, 15872)),
+        (9001, 4096, (3, 3072)),
+        (1000, 512, (2, 512)),
+        (300, 4096, (1, 512)),
+    ],
+)
+def test_equal_chunks_under_the_cap(n, chunk, want):
+    """Rows are cut into equal chunks of at most ``chunk``, so the grower's
+    lattice sizes are never padded by a chunk's remainder."""
+    eqn = _pallas_call_eqn(n, chunk, "float32")
+    grid = eqn.params["grid_mapping"].grid
+    assert (int(grid[1]), _block_shape(eqn, 0)[1]) == want
 
 
 def test_feature_batched_many_features(rng):
