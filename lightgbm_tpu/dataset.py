@@ -428,7 +428,7 @@ def construct_dataset(
 
     mappers: List[BinMapper] = []
     used: List[int] = []
-    with trace_mod.span("dataset.find_bins", cat="setup", columns=num_cols):
+    with trace_mod.span("dataset.find_bins", cat="setup", columns=num_cols) as sp:
         for j in range(num_cols):
             col = np.asarray(sample[:, j], dtype=np.float64)
             # keep NaN and non-zero values; zeros are counted implicitly
@@ -448,6 +448,7 @@ def construct_dataset(
             if not m.is_trivial:
                 mappers.append(m)
                 used.append(j)
+        sp.note(nan_features=sum(m.missing_type == MISSING_NAN for m in mappers))
     if not used:
         log.warning("There are no meaningful features, as all feature values are constant.")
     bins = _bin_matrix(data, mappers, used)

@@ -692,7 +692,7 @@ def _build_kernels(gbdt):
         member = best_b[best_leaf, 1:]
         pbegin = lb[best_leaf]
         pphys = lp[best_leaf]
-        order2, left_cnt = kern.partition_batch(
+        order2, left_cnt, _ = kern.partition_batch(
             order, pbegin[None], pphys[None], f[None], thr[None],
             dleft[None], member[None],
         )

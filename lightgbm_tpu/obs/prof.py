@@ -301,7 +301,7 @@ def _build_kernels(gbdt):
         member = best_b[best_leaf, 1:]
         pbegin = leaf_begin[best_leaf]
         pphys = leaf_phys[best_leaf]
-        order2, left_cnt = kern.partition_batch(
+        order2, left_cnt, _ = kern.partition_batch(
             order, pbegin[None], pphys[None], f[None], thr[None],
             dleft[None], member[None],
         )

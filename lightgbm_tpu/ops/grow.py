@@ -58,6 +58,7 @@ from .histogram import (
 )
 from .split import (
     MISSING_NAN,
+    MISSING_NONE,
     MISSING_ZERO,
     CegbParams,
     SplitParams,
@@ -181,10 +182,15 @@ class TreeArrays(NamedTuple):
 #: bucket the lattice switch chose, x KB for the speculative lanes; the flat
 #: form's concatenated length; N for the root and in masked mode);
 #: ``hist_rows_needed``: the same calls' live rows. ``part_rows_*``: the same
-#: pair for the partition. Under shard_map the largest shard's counts.
+#: pair for the partition; ``part_rows_missing``: of ``part_rows_needed``, the
+#: rows whose bin is the split feature's missing bin, which go where the
+#: split's default direction says; ``splits_default_left``: of ``splits``,
+#: those that send missing to the left on a feature that has a missing type.
+#: Under shard_map the largest shard's counts.
 COUNTER_NAMES = (
     "steps", "slots_computed", "splits", "hist_rows_streamed",
     "hist_rows_needed", "part_rows_streamed", "part_rows_needed",
+    "part_rows_missing", "splits_default_left",
 )
 
 
@@ -318,13 +324,21 @@ def _decision_go_left(col, threshold, default_left, missing_type, default_bin, n
     vmapped lane, or per flat row); categorical decisions are that pure
     bitset lookup — no default-direction logic (tree.h:275).
     """
-    go_left = col <= threshold
-    is_zero_missing = missing_type == MISSING_ZERO
-    is_nan_missing = missing_type == MISSING_NAN
-    go_left = jnp.where(is_zero_missing & (col == default_bin), default_left, go_left)
-    go_left = jnp.where(is_nan_missing & (col == nan_bin), default_left, go_left)
-    go_left = jnp.where(is_cat, member_val, go_left)
-    return go_left
+    go_left = jnp.where(
+        _in_missing_bin(col, missing_type, default_bin, nan_bin, is_cat),
+        default_left, col <= threshold,
+    )
+    return jnp.where(is_cat, member_val, go_left)
+
+
+def _in_missing_bin(col, missing_type, default_bin, nan_bin, is_cat):
+    """Rows that ``_decision_go_left`` sends by the default direction: their
+    bin is the feature's missing bin (the zero bin under missing=Zero, the
+    NaN bin under missing=NaN)."""
+    return ~is_cat & (
+        ((missing_type == MISSING_ZERO) & (col == default_bin))
+        | ((missing_type == MISSING_NAN) & (col == nan_bin))
+    )
 
 
 def _ceil_log2(n: int) -> int:
@@ -468,6 +482,7 @@ def make_bucket_kernels(
     # bucket_sizes is also the autotuner's sweep distribution (obs/tune.py).
     SIZES = list(bucket_sizes(N))
     sizes_arr = jnp.asarray(SIZES, jnp.int32)
+    _half_bucket = min(S for S in SIZES if 2 * S >= N)
 
     # flat-partition branch lattice over 256-row units, up to the worst
     # case (every row plus per-slot 256-alignment)
@@ -482,7 +497,8 @@ def make_bucket_kernels(
 
     def partition_batch(order, begin, pcnt, feat, thr, dleft, member):
         """Stably partition W disjoint leaf segments in ONE flat segmented
-        pass; returns (new order, left physical counts [W]). The W axis is
+        pass; returns (new order, left physical counts [W], the rows that
+        went by their split's default direction, over all W). The W axis is
         the leading axis of every operand; W=1 is the sequential grower's
         per-split partition, W=KB a speculative batch — one implementation,
         so the two modes cannot drift, and arithmetic is proportional to the
@@ -501,7 +517,14 @@ def make_bucket_kernels(
         nanb = num_bin_arr[feat] - 1
         iscat = is_cat_arr[feat]
         rows_of = (gid_arr[feat] if bundled else feat).astype(jnp.int32)
-        Frows = bins.shape[0]
+        # the W split features' columns, laid flat OUTSIDE the lattice
+        # switch: a branch that flattens the whole [F, N] matrix for its
+        # gather rebuilds that copy at every call (on a v5e 58 ms a call at
+        # 968 x 750K, 72% of an iteration there; PERF.md, PR 28)
+        cols = (
+            jnp.take(bins_nf, rows_of, axis=1).T if bins_nf is not None
+            else jnp.take(bins, rows_of, axis=0)
+        ).reshape(-1)  # [W * N]
 
         padded = _part_padded(pcnt)  # [W]
         ends = jnp.cumsum(padded)
@@ -509,7 +532,7 @@ def make_bucket_kernels(
         L = ends[-1]
 
         def make_branch(Lb):
-            def branch(order, begin, pcnt, offs, ends, rows_of, feat, thr,
+            def branch(order, begin, pcnt, offs, ends, cols, feat, thr,
                        dleft, miss, dbin, nanb, iscat, member):
                 t = jnp.arange(Lb, dtype=jnp.int32)
                 j = jnp.minimum(
@@ -524,13 +547,8 @@ def make_bucket_kernels(
                 )
                 rows = order[src]
                 # per-row feature column through ONE flat gather (each row's
-                # slot picks its own split feature)
-                flat_idx = rows_of[j] * N + rows
-                colraw = (
-                    jnp.take(bins_nf.reshape(-1), rows * Frows + rows_of[j])
-                    if bins_nf is not None
-                    else jnp.take(bins.reshape(-1), flat_idx)
-                ).astype(jnp.int32)
+                # slot picks its own split feature's column)
+                colraw = jnp.take(cols, j * N + rows).astype(jnp.int32)
                 colv = decode_col(colraw, feat[j]) if bundled else colraw
                 gl = _decision_go_left(
                     colv, thr[j], dleft[j], miss[j], dbin[j], nanb[j],
@@ -538,6 +556,9 @@ def make_bucket_kernels(
                 )
                 is_left = valid & gl
                 is_right = valid & ~gl
+                by_default = jnp.sum(valid & _in_missing_bin(
+                    colv, miss[j], dbin[j], nanb[j], iscat[j]
+                ), dtype=jnp.int32)
                 # segmented inclusive count of lefts (resets at slot starts);
                 # int adds are reassociation-exact
                 seg_start = t == offs[j]
@@ -564,14 +585,14 @@ def make_bucket_kernels(
                 write = is_left | is_right
                 gt = jnp.where(write, begin[j] + tgt_local, N + t)
                 order2 = order.at[gt].set(rows, unique_indices=True)
-                return order2, left_cnt
+                return order2, left_cnt, by_default
 
             return branch
 
         return jax.lax.switch(
             _lattice_index(_part_sizes_arr, L),
             [make_branch(Lb) for Lb in _part_sizes],
-            order, begin, pcnt, offs, ends, rows_of, feat, thr, dleft, miss,
+            order, begin, pcnt, offs, ends, cols, feat, thr, dleft, miss,
             dbin, nanb, iscat, member,
         )
 
@@ -587,11 +608,12 @@ def make_bucket_kernels(
         (grad*bag, hess*bag, bag) instead of three masked takes — bag/valid
         are exact {0,1} multipliers so the product order cannot change f32
         results."""
-        W = begin.shape[0]
         Frows = bins.shape[0]
 
         def make_branch(S):
-            def branch(vals_all, order, begin, cnt):
+            def lanes(vals_all, order, begin, cnt):
+                W = begin.shape[0]
+
                 def geo(begin_j, cnt_j):
                     # zero-based (NOT the clamped _segment_slice window):
                     # real rows sit at positions [0, cnt) so chunk
@@ -619,6 +641,19 @@ def make_bucket_kernels(
                         feature_sharded=feature_sharded, route=hist_route,
                     )
                 )(b_seg, vals)
+
+            def branch(vals_all, order, begin, cnt):
+                if begin.shape[0] == 1 or S <= _half_bucket:
+                    return lanes(vals_all, order, begin, cnt)
+                # a bucket over half the rows: the lanes one after another,
+                # each the W=1 pass. The W segments gathered at once are
+                # W x S x F bytes, 7.7 GB at 8 x 1M x 968, more than the
+                # chip grants one program, and a smaller child is this large
+                # only under row sampling or an uneven shard (PERF.md, PR 28)
+                return jax.lax.map(
+                    lambda bc: lanes(vals_all, order, bc[0][None], bc[1][None])[0],
+                    (begin, cnt),
+                )
 
             return branch
 
@@ -904,11 +939,11 @@ def grow_tree(
 
         def partition_segment(order, begin, pcnt, f, threshold, default_left, member):
             """One split's partition — the W=1 case of partition_batch."""
-            order2, left_cnt = partition_batch(
+            order2, left_cnt, by_default = partition_batch(
                 order, begin[None], pcnt[None], f[None], threshold[None],
                 default_left[None], member[None],
             )
-            return order2, left_cnt[0]
+            return order2, left_cnt[0], by_default
 
         def segment_histogram(order, begin, cnt):
             """One segment's histogram — the W=1 case of the batch launch."""
@@ -1349,7 +1384,7 @@ def grow_tree(
             leaf_id = s.leaf_id  # dummy; reconstructed from order at the end
             pbegin = s.leaf_begin[best_leaf]
             pphys = s.leaf_phys[best_leaf]
-            order, left_phys = partition_segment(
+            order, left_phys, by_default = partition_segment(
                 s.order, pbegin, pphys, f, rec.threshold, rec.default_left,
                 rec.cat_bitset,
             )
@@ -1358,7 +1393,7 @@ def grow_tree(
             leaf_phys = (
                 s.leaf_phys.at[best_leaf].set(left_phys).at[new_leaf].set(right_phys)
             )
-            part_rows = (part_extent(pphys[None]), pphys)
+            part_rows = (part_extent(pphys[None]), pphys, by_default)
         else:
             row = gid_arr[f] if bundled else f
             col = jax.lax.dynamic_slice(bins, (row, 0), (1, N))[0].astype(jnp.int32)
@@ -1377,7 +1412,10 @@ def grow_tree(
             in_leaf = s.leaf_id == best_leaf
             leaf_id = jnp.where(in_leaf & ~go_left, new_leaf, s.leaf_id)
             order, leaf_begin, leaf_phys = s.order, s.leaf_begin, s.leaf_phys
-            part_rows = (N, jnp.sum(in_leaf))
+            part_rows = (N, jnp.sum(in_leaf), jnp.sum(in_leaf & _in_missing_bin(
+                col, missing_arr[f], default_bin_arr[f], num_bin_arr[f] - 1,
+                is_cat_arr[f],
+            )))
 
         # ---- wire the tree (5 scatters, PackedTree) ----------------------
         t = s.tree
@@ -1659,6 +1697,8 @@ def grow_tree(
             hist_rows_streamed=small_rows[0] + direct * large_rows[0],
             hist_rows_needed=small_rows[1] + direct * large_rows[1],
             part_rows_streamed=part_rows[0], part_rows_needed=part_rows[1],
+            part_rows_missing=part_rows[2],
+            splits_default_left=rec.default_left & (missing_arr[f] != MISSING_NONE),
         ))
         return GrowState(
             it=s.it + 1,
@@ -1762,7 +1802,7 @@ def grow_tree(
         compute = (~cached) & (g_top > 0.0)
 
         pphys_c = jnp.where(compute, pphys, 0)
-        order2, left_phys_c = partition_batch(
+        order2, left_phys_c, by_default = partition_batch(
             s.order, pbegin, pphys_c, feat, thr, dleft, member
         )
         left_phys = jnp.where(cached, s.spec_lphys[b_top], left_phys_c)
@@ -1901,6 +1941,10 @@ def grow_tree(
                 hist_rows_needed=jnp.sum(small_cnt),
                 part_rows_streamed=part_extent(pphys_c),
                 part_rows_needed=jnp.sum(pphys_c),
+                part_rows_missing=by_default,
+                splits_default_left=jnp.sum(
+                    applied & dleft & (missing_arr[feat] != MISSING_NONE)
+                ),
             ),
             num_leaves=nl0 + p,
             node_f=t.node_f.at[nrow].set(
