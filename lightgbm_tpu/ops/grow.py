@@ -202,6 +202,16 @@ def _counted(counters: jax.Array, **delta) -> jax.Array:
     ])
 
 
+def _carry_rows(buf: jax.Array, idx: jax.Array) -> jax.Array:
+    """``buf[idx]`` for a few rows of a large loop carry, read row by row."""
+    # not buf[idx]: the TPU compiler cuts a gather this large into column
+    # pieces and copies the whole carry each step; not a stacked unroll: that
+    # relayouts it (tests/test_hist_pallas_tpu_compile.py holds the HLO to this)
+    return jax.lax.map(
+        lambda r: jax.lax.dynamic_index_in_dim(buf, r, 0, keepdims=False), idx
+    )
+
+
 class PackedBest(NamedTuple):
     """Per-leaf best-split candidates, packed so each split's refresh is 3
     scatters instead of 28 chained single-field updates (the dominant fixed
@@ -1371,7 +1381,7 @@ def grow_tree(
     )
 
     # scopes: what is not inside partition / hist_build / split_find below is
-    # the carry writes, and reads as apply_split alone
+    # the carry's row reads and writes, and reads as apply_split alone
     @jax.named_scope("apply_split")
     def apply_split(s: GrowState, best_leaf, rec: SplitResult) -> GrowState:
         """Apply one split of ``best_leaf`` by ``rec`` (Split,
@@ -1832,7 +1842,7 @@ def grow_tree(
         # for a cached slot, hist row b_j already holds the LEFT child's
         # histogram (committed at cache time) and the right child's parks in
         # spec_rhist; for computing slots it still holds the parent's
-        parent_hist = s.hist[b_top]
+        parent_hist = _carry_rows(s.hist, b_top)
         large_hist = parent_hist - small_hist
         ls4 = left_smaller[:, None, None, None]
         c4 = cached[:, None, None, None]
@@ -1840,7 +1850,8 @@ def grow_tree(
             c4, parent_hist, jnp.where(ls4, small_hist, large_hist)
         )
         rhist = jnp.where(
-            c4, s.spec_rhist[b_top], jnp.where(ls4, large_hist, small_hist)
+            c4, _carry_rows(s.spec_rhist, b_top),
+            jnp.where(ls4, large_hist, small_hist),
         )
 
         # ---- children: aux, monotone windows, one batched scan ----------

@@ -1,16 +1,21 @@
 """The routed Pallas histogram kernel compiled for a v5e that is described,
 not attached: Mosaic accepts the lane-dense body at the largest chunk the
-``_BYTES_PER_COL`` table allows (what interpret mode cannot show). Nothing
+``_BYTES_PER_COL`` table allows (what interpret mode cannot show); and the
+speculative grower's step, which must copy neither histogram carry. Nothing
 runs, so nothing here is a device number. The topology is described inside
 a fixture: one worker loads the TPU compiler, and only when it is given
 this file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+import lightgbm_tpu.ops.grow as grow_mod
+import lightgbm_tpu.ops.histogram as hist_mod
 from lightgbm_tpu.ops import hist_pallas
+from lightgbm_tpu.ops.split import SplitParams
 
 
 @pytest.fixture(scope="module")
@@ -47,3 +52,57 @@ def test_mosaic_accepts_the_largest_chunk(one_chip, dtype_name, num_bins, lanes)
     fn = jax.vmap(one) if lanes else one
     compiled = jax.jit(fn).lower(bins, vals).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def spec_pallas(monkeypatch):
+    """The cells' grower on a process whose default backend is the CPU: both
+    choices are read at import, so they are steered here, in the test."""
+    monkeypatch.setattr(grow_mod, "_ENV_GROW", "spec")
+    monkeypatch.setattr(hist_mod, "_ENV_IMPL", "pallas")
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("F", [64, 968])
+def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
+    """``grow_tree`` lowered and compiled at 255 bins and 255 leaves, the two
+    ``[255, F, 255, 3]`` carries donated. A speculative batch reads 8 rows of
+    each (``grow._carry_rows``). Read by ``buf[idx]``, the TPU compiler cut a
+    gather whose result outgrew its fast memory into column pieces
+    (``mini-gather-slice``: at 968 columns, none at 64) and materialised every
+    piece, a copy of the whole carry a step; read by a stacked unroll of
+    dynamic slices, it relaid the whole carry out instead (a ``copy`` to
+    ``{1,0,3,2}``). Neither may come back, at a narrow table or a wide one.
+    The loop body does not depend on the rows, so 4096 do. Nothing runs, and
+    nothing here is a device number."""
+    N, B, M = 4096, 255, 255
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = {
+        "num_bin": arg((F,), jnp.int32),
+        "missing_type": arg((F,), jnp.int32),
+        "default_bin": arg((F,), jnp.int32),
+        "monotone": arg((F,), jnp.int8),
+    }
+    compiled = grow_mod.grow_tree.lower(
+        arg((F, N), jnp.uint8), arg((N,), jnp.float32), arg((N,), jnp.float32),
+        arg((N,), jnp.float32), arg((F,), jnp.bool_), meta,
+        num_leaves=M, max_depth=-1, num_bins=B,
+        params=SplitParams(0.0, 0.0, 0.0, 1, 100.0, 0.0), chunk=16384,
+        hist_buf=arg((M, F, B, 3), jnp.float32),
+        spec_buf=arg((M, F, B, 3), jnp.float32),
+    ).compile()
+    assert grow_mod._LAST_GROW_MODE == "spec"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the cells' histogram, not the one-hot
+    assert text.count("mini-gather-slice") == 0
+    carry = re.escape(f"f32[{M},{F},{B},3]")
+    assert not re.search(rf"= {carry}\S* copy\(", text)
+    if F == 968:
+        # 704.6 MB with the gather, 189.0 MB without (compiled here, PR 29)
+        carry_bytes = M * F * B * 3 * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < carry_bytes // 2
