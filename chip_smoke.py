@@ -38,7 +38,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-# the one measured configuration (bench.py's cell, BASELINE.md's Higgs row)
+# the one measured configuration (BASELINE.md's Higgs row)
 FULL = dict(rows=1_000_000, features=28, leaves=255, max_bin=255,
             kernel_rows=(4096, 1_000_000), serve_batches=(1, 256, 4096),
             equality_rounds=5)

@@ -1,9 +1,4 @@
-"""Shared synthetic-workload generators for bench/profiling harnesses.
-
-One definition of the Higgs-shaped dataset (was duplicated between bench.py
-and helpers/prof_grow.py, with silently different feature distributions —
-their numbers were not comparable). bench.py re-exports
-:func:`make_higgs_like`; chip_smoke.py imports it from here.
+"""The Higgs-shaped synthetic dataset that ``chip_smoke.py`` trains on.
 
 Stdlib + numpy only.
 """

@@ -22,10 +22,6 @@
 #                               # smoke: flight-recorded train (JSONL schema),
 #                               # drift-monitored serve (shifted traffic must
 #                               # alert, in-dist must not), HTML run report
-#   helpers/check.sh --prof     # lint gate, then the performance-attribution
-#                               # smoke: segment-profiled mini-train —
-#                               # breakdown structure + fused-vs-segmented
-#                               # bitwise identity + cost-analysis cross-check
 #   helpers/check.sh --multichip
 #                               # lint gate, then the multichip smoke: the
 #                               # composed data-parallel sharded-chunk path
@@ -33,16 +29,6 @@
 #                               # sharded-chunk model strings must match
 #                               # bit for bit, one train_chunk compile,
 #                               # serial-learner structural cross-check
-#   helpers/check.sh --dist-obs # lint gate, then the distributed-obs smoke:
-#                               # segmented sharded chunk bitwise-identical
-#                               # to the fused one (model strings + score
-#                               # carries) on 8 forced CPU devices, merged
-#                               # pod registry exposition (counters == the
-#                               # per-process sums), merged Perfetto trace
-#                               # with disjoint pids, MULTICHIP record with
-#                               # comms_fraction + scaling_efficiency, and
-#                               # the HTML Multichip report page — from ONE
-#                               # invocation (docs/Observability.md)
 #   helpers/check.sh --san      # lint gate (JX011-JX013 engaged), then the
 #                               # runtime sanitizer: unit tests (seeded
 #                               # transfer/NaN/lock-inversion violations all
@@ -73,15 +59,6 @@
 #                               # bit-transparent (default-pinned table ==
 #                               # untuned bytes; same-table reruns and
 #                               # chunk=1-vs-4 byte-identical)
-#   helpers/check.sh --devprof  # lint gate, then the device-timeline
-#                               # smoke: capture a scoped jax.profiler
-#                               # window around real boosting iterations,
-#                               # parse the emitted Chrome trace with the
-#                               # stdlib devprof parser, assert a
-#                               # non-empty attributed timeline + a
-#                               # host/device/transfer-bound verdict +
-#                               # the device_timeline report section —
-#                               # ONE invocation (obs/devprof.py)
 #   helpers/check.sh --elastic  # lint gate, then the elastic preemption-
 #                               # tolerance smoke: ONE invocation at forced-
 #                               # 8-CPU-device shapes — SIGKILL mid-run ->
@@ -122,15 +99,6 @@
 #                               # program-fingerprint contract
 #                               # (docs/StaticAnalysis.md §Program-level
 #                               # audit)
-#   helpers/check.sh --bench-diff [CUR BASE]
-#                               # the bench regression gate: golden-fixture
-#                               # self-test (synthetic regression must FAIL,
-#                               # improvement must PASS) + informational
-#                               # BENCH_r* series diff; with CUR and BASE
-#                               # paths it hard-gates that pair instead.
-#                               # Part of the pre-merge flow for any PR that
-#                               # claims (or risks) a perf change
-#                               # (docs/Observability.md).
 #
 # ruff/mypy are optional: the container may not ship them (no network
 # installs); when absent they are skipped with a notice — graftlint and
@@ -140,16 +108,16 @@ cd "$(dirname "$0")/.."
 
 MODE="${1:-full}"
 case "$MODE" in
-    full|--quick|--lint|--serve|--obs|--resil|--prof|--drift|--multichip|--dist-obs|--san|--loop|--tune|--devprof|--elastic|--podwatch|--flex|--ir|--bench-diff) ;;
+    full|--quick|--lint|--serve|--obs|--resil|--drift|--multichip|--san|--loop|--tune|--elastic|--podwatch|--flex|--ir) ;;
     *)
-        echo "check.sh: unknown mode '$MODE' (expected --quick, --lint, --serve, --obs, --resil, --prof, --drift, --multichip, --dist-obs, --san, --loop, --tune, --devprof, --elastic, --podwatch, --flex, --ir or --bench-diff)" >&2
+        echo "check.sh: unknown mode '$MODE' (expected --quick, --lint, --serve, --obs, --resil, --drift, --multichip, --san, --loop, --tune, --elastic, --podwatch, --flex or --ir)" >&2
         exit 2
         ;;
 esac
 fail=0
 
-echo "== graftlint (lightgbm_tpu/ + helpers/ + bench.py against baseline) =="
-python -m tools.graftlint lightgbm_tpu/ helpers/ bench.py || fail=1
+echo "== graftlint (lightgbm_tpu/ + helpers/ against baseline) =="
+python -m tools.graftlint lightgbm_tpu/ helpers/ || fail=1
 
 echo "== graftlint (tools/, no baseline) =="
 python -m tools.graftlint --no-baseline tools/ || fail=1
@@ -193,11 +161,6 @@ if [ "$MODE" = "--resil" ]; then
     exec env JAX_PLATFORMS=cpu python helpers/resil_smoke.py
 fi
 
-if [ "$MODE" = "--prof" ]; then
-    echo "== prof smoke (segment breakdown + bitwise identity + cost analysis) =="
-    exec env JAX_PLATFORMS=cpu python helpers/obs_smoke.py --prof
-fi
-
 if [ "$MODE" = "--drift" ]; then
     echo "== drift smoke (flight JSONL + PSI separation + HTML report) =="
     exec env JAX_PLATFORMS=cpu python helpers/obs_smoke.py --drift
@@ -206,11 +169,6 @@ fi
 if [ "$MODE" = "--multichip" ]; then
     echo "== multichip smoke (8 forced CPU devices, sharded-chunk bit-identity) =="
     exec python helpers/multichip_smoke.py
-fi
-
-if [ "$MODE" = "--dist-obs" ]; then
-    echo "== dist-obs smoke (segmented sharded chunk + merged registry/trace/report) =="
-    exec env JAX_PLATFORMS=cpu python helpers/dist_obs_smoke.py
 fi
 
 if [ "$MODE" = "--san" ]; then
@@ -231,11 +189,6 @@ if [ "$MODE" = "--tune" ]; then
     exec env JAX_PLATFORMS=cpu python helpers/tune_smoke.py
 fi
 
-if [ "$MODE" = "--devprof" ]; then
-    echo "== devprof smoke (capture -> parse -> verdict + report section) =="
-    exec env JAX_PLATFORMS=cpu python helpers/devprof_smoke.py
-fi
-
 if [ "$MODE" = "--elastic" ]; then
     echo "== elastic smoke (SIGKILL/SIGTERM -> resume byte-identity + 8->2 reshard) =="
     exec python helpers/elastic_smoke.py
@@ -254,18 +207,6 @@ fi
 if [ "$MODE" = "--ir" ]; then
     echo "== irscan smoke (seeded IR violations caught + real-tree scan vs baseline/contract) =="
     exec python helpers/irscan_smoke.py
-fi
-
-if [ "$MODE" = "--bench-diff" ]; then
-    if [ $# -ge 3 ]; then
-        echo "== bench-diff gate ($2 vs $3) =="
-        exec python helpers/bench_diff.py "$2" "$3"
-    fi
-    echo "== bench-diff self-test (golden fixtures) =="
-    python helpers/bench_diff.py --self-test || exit 1
-    echo "== bench-diff series (informational) =="
-    python helpers/bench_diff.py --series 'BENCH_r*.json' || true
-    exit 0
 fi
 
 if [ "$MODE" = "--quick" ]; then

@@ -13,13 +13,6 @@ What `helpers/check.sh --obs` runs. In-process, on CPU:
      device-memory gauge to be present;
   4. checks memwatch shape math against the actual donated hist buffer.
 
-``--prof`` (what `helpers/check.sh --prof` runs) instead validates the
-performance-attribution tier: a segment-profiled mini-train whose breakdown
-must carry every core segment, whose segmented model must be BITWISE
-identical to the fused grower's, whose run_report must carry the
-``growth_segments_s`` + ``cost_analysis`` sections, and whose cost-analysis
-byte counts must agree with memwatch's shape math for the same tensors.
-
 ``--drift`` (what `helpers/check.sh --drift` runs) validates the MODEL/data
 observability tier (docs/Observability.md §Model & data observability):
 a flight-recorded train whose JSONL schema must parse (manifest + one
@@ -124,78 +117,6 @@ def main() -> int:
     print("obs smoke OK: %d trace events, phases=%s" % (
         len(events), sorted(phases),
     ))
-    return 0
-
-
-def prof_main() -> int:
-    """Segment-profiler smoke (check.sh --prof): breakdown structure,
-    fused-vs-segmented bitwise identity, report sections, cost-analysis
-    bytes vs memwatch shape math."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["LIGHTGBM_TPU_COSTS"] = "1"
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    import lightgbm_tpu as lgb
-    from lightgbm_tpu.obs import REGISTRY, memwatch
-    from lightgbm_tpu.obs import costs as costs_mod
-    from lightgbm_tpu.obs import prof as prof_mod
-    from lightgbm_tpu.ops.histogram import leaf_histogram
-
-    rng = np.random.RandomState(0)
-    N, F = 5000, 6
-    X = rng.randn(N, F).astype(np.float32)
-    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + rng.randn(N) * 0.3 > 0).astype(
-        np.float32
-    )
-    bst = lgb.train(
-        {"objective": "binary", "num_leaves": 31, "verbosity": -1},
-        lgb.Dataset(X, label=y), num_boost_round=2,
-    )
-    reason = prof_mod.unsupported_reason(bst._gbdt)
-    assert reason is None, "profiler unexpectedly unsupported: %s" % reason
-    rec = prof_mod.profile_growth(bst, iters=2)
-
-    # --- breakdown structure + the bitwise-identity proof ------------------
-    segs = rec["segments_per_tree_s"]
-    missing = [s for s in prof_mod.CORE_SEGMENTS if s not in segs]
-    assert not missing, "segments missing from breakdown: %s" % missing
-    assert all(v >= 0 for v in segs.values()), segs
-    assert rec["bitwise_identical"] is True, (
-        "segmented model diverged from the fused grower's"
-    )
-    assert rec["segment_sum_s_per_tree"] > 0
-    assert rec["splits_per_tree"] > 0
-
-    # --- report sections ---------------------------------------------------
-    report = REGISTRY.run_report()
-    assert "growth_segments_s" in report, sorted(report)
-    assert set(prof_mod.CORE_SEGMENTS) <= set(report["growth_segments_s"])
-    assert "cost_analysis" in report, sorted(report)
-    grow_cost = report["cost_analysis"].get("ops.grow_tree")
-    assert grow_cost and grow_cost.get("flops", 0) > 0, grow_cost
-    prom = REGISTRY.prometheus_text()
-    assert "lgbtpu_growth_segment_seconds_total" in prom
-    assert "lgbtpu_xla_cost_flops" in prom
-    assert 'lgbtpu_jit_traces{name="ops.grow_tree"}' in prom
-
-    # --- cost-analysis bytes vs memwatch shape math ------------------------
-    bins = jnp.zeros((F, 512), jnp.uint8)
-    vals = jnp.zeros((512, 3), jnp.float32)
-    hrec = costs_mod.COSTS.harvest(
-        "smoke.leaf_histogram", leaf_histogram, (bins, vals, 16)
-    )
-    assert hrec is not None
-    assert hrec["argument_bytes"] == bins.nbytes + vals.nbytes, hrec
-    assert hrec["output_bytes"] == memwatch.hist_carry_bytes(1, F, 16), hrec
-
-    print(
-        "prof smoke OK: bitwise identical over %d trees, segments=%s, "
-        "sum/fused ratio=%.3f" % (
-            rec["trees"], sorted(segs), rec["segment_sum_ratio"],
-        )
-    )
     return 0
 
 
@@ -309,8 +230,6 @@ def drift_main() -> int:
 
 
 if __name__ == "__main__":
-    if "--prof" in sys.argv[1:]:
-        sys.exit(prof_main())
     if "--drift" in sys.argv[1:]:
         sys.exit(drift_main())
     sys.exit(main())

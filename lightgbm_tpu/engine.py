@@ -394,7 +394,7 @@ def train(
         if preempt_watcher is not None:
             preempt_watcher.uninstall()
         # a crashed/interrupted run (anywhere — the loop, the deferred stop
-        # readback, the profiler, the harvest) still closes its flight log:
+        # readback, the harvest) still closes its flight log:
         # the records up to the failure are exactly the evidence wanted,
         # and a leaked _ACTIVE recorder would silently disable recording
         # for every later train() in the process
@@ -418,31 +418,8 @@ def _finish_train(booster, evaluation_result_list, flight_rec, model_stats):
     booster._gbdt._consume_pending_stop()
     booster._gbdt.timers.report()
     # same numbers, machine-readable: phase totals land in the metrics
-    # registry so /metrics, bench JSON and bringup reports all agree
+    # registry so /metrics and bringup reports agree
     booster._gbdt.timers.publish()
-
-    # env-gated segment profiler (LIGHTGBM_TPU_PROF_SEGMENTS=N): after
-    # training, run N profiling iterations of fenced sub-step tree growth —
-    # breakdown lands in run_report()/gauges/trace spans; the trainer's
-    # state is NOT advanced (obs/prof.py). Unsupported configs log and skip.
-    from .obs import prof as prof_mod
-
-    if prof_mod.segments_enabled():
-        reason = prof_mod.unsupported_reason(booster._gbdt)
-        if reason is not None:
-            log.warning("segment profiler skipped: %s" % reason)
-        else:
-            try:
-                rec = prof_mod.profile_growth(
-                    booster, iters=prof_mod.segments_iters()
-                )
-                log.info(
-                    "growth segments (s/tree): %s | sum/fused=%.3f bitwise=%s"
-                    % (rec["segments_per_tree_s"], rec["segment_sum_ratio"],
-                       rec["bitwise_identical"])
-                )
-            except Exception as e:  # profiling must never fail training
-                log.warning("segment profiler failed: %r" % e)
 
     booster.best_score = collections.defaultdict(collections.OrderedDict)
     for (dname, ename, v, _) in evaluation_result_list or []:

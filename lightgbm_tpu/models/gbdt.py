@@ -859,11 +859,6 @@ class GBDT:
         # per-chunk peak accounting (allocator stats only — no buffer walk
         # inside the training loop; gated on LIGHTGBM_TPU_MEMWATCH)
         memwatch.auto_snapshot("chunk", light=True)
-        # straggler detection (LIGHTGBM_TPU_DIST_PROF=1 only): fence each
-        # score shard in device order and publish per-device completion
-        # offsets — zero overhead and zero new traces when off
-        if dist_mod.wait_profiling_enabled():
-            dist_mod.note_dispatch_waits(self.scores)
         base = len(self._device_trees)
         for idx, ta in enumerate(trees_out):  # iteration-major, class-minor
             self._device_trees.append((ta, idx % K))

@@ -1,6 +1,6 @@
 """lightgbm_tpu.obs: the unified observability layer (docs/Observability.md).
 
-System tier (trace/retrace/memwatch/costs/prof/registry) plus the model/data
+System tier (trace/retrace/memwatch/costs/registry) plus the model/data
 tier — :mod:`~lightgbm_tpu.obs.flight` (training flight recorder),
 :mod:`~lightgbm_tpu.obs.modelstats` (importance evolution, bin occupancy,
 leaf shape) and :mod:`~lightgbm_tpu.obs.report` (the self-contained HTML run
@@ -21,14 +21,11 @@ report); the serve-side drift monitor lives in serve/drift.py. One spine:
    points + shape-math attribution of the known large carries.
  * :mod:`~lightgbm_tpu.obs.costs`    — measured XLA cost analysis per core
    executable (flops / bytes via ``lower().compile().cost_analysis()``,
-   env-gated ``LIGHTGBM_TPU_COSTS=1``) + the per-``device_kind`` roofline
-   peak table bench.py reads.
- * :mod:`~lightgbm_tpu.obs.prof`     — the segment profiler: tree growth as
-   separately-dispatched fenced sub-steps (``LIGHTGBM_TPU_PROF_SEGMENTS``),
-   proven bitwise-identical to the fused grower.
+   env-gated ``LIGHTGBM_TPU_COSTS=1``) + the per-``device_kind`` peak
+   table (``ops/hist_pallas.py`` sizes its blocks by its VMEM figures).
  * :mod:`~lightgbm_tpu.obs.registry` — the one metrics registry (counters /
    gauges / histograms / rates) behind the serve ``/metrics`` Prometheus
-   endpoint, the training callback, and the bench/bringup run reports.
+   endpoint, the training callback, and the run reports.
  * :mod:`~lightgbm_tpu.obs.sanitize` — the graftsan runtime sanitizer
    (``LIGHTGBM_TPU_SAN=transfer,nan,locks``): transfer guards at the jitted
    dispatch seams, NaN tripwires on the score carries, lock-order inversion
@@ -37,12 +34,10 @@ report); the serve-side drift monitor lives in serve/drift.py. One spine:
    (``python -m lightgbm_tpu.obs.tune``): measured per-shape kernel
    routing tables, atomically persisted, frozen per training run
    (docs/HistogramRouting.md). Imported lazily (it pulls ops/ on use).
- * :mod:`~lightgbm_tpu.obs.devprof`  — the device-timeline auditor
-   (``python -m lightgbm_tpu.obs.devprof``): parses the XLA profile a
-   ``LIGHTGBM_TPU_PROFILE`` capture emits, attributes device self-time to
-   the TraceAnnotation segment vocabulary, and classifies the run
-   host- / device- / transfer-bound (docs/Observability.md §Device
-   timeline). Stdlib-only parsing; imported lazily by its callers.
+ * :mod:`~lightgbm_tpu.obs.dist`     — what joins the per-process pieces
+   of a multi-process run: registry snapshots gathered and merged, the
+   byte allgather the checkpoint barrier rides, per-shard row counts.
+   jax-lazy; imported by its callers.
  * :mod:`~lightgbm_tpu.obs.podwatch` — the live fleet telemetry plane
    (``python -m lightgbm_tpu.obs.podwatch``): per-rank chunk-boundary
    time-series ring (``LIGHTGBM_TPU_TELEMETRY=<dir>``), the opt-in
@@ -59,13 +54,8 @@ from __future__ import annotations
 from . import costs, flight, memwatch, modelstats, registry, retrace, trace  # noqa: F401
 from .registry import REGISTRY, MetricsRegistry  # noqa: F401
 
-# NOTE: obs.prof and obs.dist (the mesh-aware distributed tier: sharded
-# compute-vs-collective attribution, pod-wide registry/trace merging,
-# shard-skew detection) are imported lazily by their callers (they pull
-# ops/ and parallel/ code paths this package promises to avoid at import
-# time — dist's merge helpers themselves stay jax-lazy). obs.report is
-# the run-report CLI (`python -m lightgbm_tpu.obs.report`) and is
-# imported on use; `python -m lightgbm_tpu.obs.trace merge` folds
+# NOTE: obs.report is the run-report CLI (`python -m lightgbm_tpu.obs.report`)
+# and is imported on use; `python -m lightgbm_tpu.obs.trace merge` folds
 # per-process trace files into one timeline.
 
 # cross-wiring: the default registry's watchdog/memory gauges pull live

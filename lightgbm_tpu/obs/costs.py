@@ -13,18 +13,13 @@ executables — keyed by the SAME names the retrace watchdog counts
  * every harvested record publishes ``xla_cost_*`` gauges (labeled by
    executable) on the default metrics registry, next to the watchdog's
    per-name ``jit_traces`` compile counts;
- * ``run_report()`` carries the whole book as a ``cost_analysis`` section
-   (bench.py embeds it in its artifact);
- * bench.py's roofline uses the measured flops/bytes when a harvest for
-   the headline executable exists, falling back to the analytic work model
-   — every report is stamped ``roofline_source: "measured" | "analytic"``
-   so BENCH_r*.json comparisons are never apples-to-oranges.
+ * ``run_report()`` carries the whole book as a ``cost_analysis`` section.
 
 Harvesting is env-gated (``LIGHTGBM_TPU_COSTS=1``): ``lower().compile()``
 is a SECOND XLA compile of the executable (the AOT path does not share the
 jit dispatch cache), which the persistent compilation cache makes cheap on
 re-runs but which plain training should not pay silently. Call sites
-(models/gbdt.py, serve/packed.py, obs/prof.py) check :func:`enabled` and
+(models/gbdt.py, serve/packed.py) check :func:`enabled` and
 dedupe per (name, arg-shape signature), so the steady-state overhead is a
 dict lookup.
 
@@ -49,7 +44,7 @@ ENV_COSTS = "LIGHTGBM_TPU_COSTS"
 
 
 def enabled() -> bool:
-    """Read per call, not at import: bench/bringup flip it in-process."""
+    """Read per call, not at import: a process may flip it."""
     return os.environ.get(ENV_COSTS, "") not in ("", "0")
 
 

@@ -247,9 +247,8 @@ def build_manifest(
         man["mesh"] = mesh
     # the frozen histogram tune route (ops/histogram.HistRoute, ISSUE 13):
     # the digest IS the run's routing identity — two flight logs with equal
-    # digests trained under byte-identical kernel routing, and bench_diff
-    # treats a digest change as "throughput rows reflect routing, not
-    # regression" (docs/HistogramRouting.md)
+    # digests trained under byte-identical kernel routing
+    # (docs/HistogramRouting.md)
     route = getattr(gbdt, "_hist_route", None)
     if route is not None:
         man["hist_route_digest"] = route.digest
@@ -277,21 +276,9 @@ def note_boundary(
         [str(d), str(m), float(v)]
         for (d, m, v, _b) in (evaluation_result_list or [])
     ]
-    extra: Dict[str, Any] = {}
-    try:
-        # collective seconds the sharded segment profiler measured since
-        # the previous boundary (obs/dist.py; 0.0 — and no field — unless
-        # distributed profiling ran inside this window)
-        from . import dist as dist_mod
-
-        comms = dist_mod.take_boundary_comms()
-        if comms > 0:
-            extra["comms_s"] = round(comms, 6)
-    except Exception as e:  # recording must never fail the boundary
-        log.debug("flight: comms probe failed: %r" % (e,))
     rec.record(
         "iteration", iteration=int(iteration), chunk=int(done),
-        dt_s=round(float(dt_s), 6), evals=evals, **extra,
+        dt_s=round(float(dt_s), 6), evals=evals,
     )
 
 

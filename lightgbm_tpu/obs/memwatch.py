@@ -8,9 +8,8 @@ makes their footprint visible per run instead of rediscovered by advisors:
  * :func:`snapshot` — record device ``memory_stats()`` (bytes_in_use /
    peak_bytes_in_use where the backend reports them; the CPU backend
    reports None) plus the live-buffer census from ``jax.live_arrays()``
-   at a named point. Training takes one post-bin (models/gbdt.py) and the
-   bench one post-run; serving exposes the device gauges on every /metrics
-   scrape. Automatic per-chunk snapshots are opt-in via
+   at a named point. Training takes one post-bin (models/gbdt.py);
+   serving exposes the device gauges on every /metrics scrape. Automatic per-chunk snapshots are opt-in via
    ``LIGHTGBM_TPU_MEMWATCH=1`` (``auto_snapshot``) — ``light=True`` skips
    the live-buffer walk so chunk boundaries stay cheap.
  * shape-math attribution — :func:`attribute_training` /

@@ -19,8 +19,8 @@ Three surfaces, all pull-based and disabled by default
 
 ``publish(booster)`` sets registry gauges (``model_feature_importance``,
 ``model_leaf_depth``, ``model_split_gain``, ``model_trees``) and registers a
-``model_stats`` run-report section so bench/bringup artifacts and /metrics
-carry the same numbers.
+``model_stats`` run-report section so run reports and /metrics carry the
+same numbers.
 """
 from __future__ import annotations
 

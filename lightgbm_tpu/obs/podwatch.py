@@ -2,8 +2,7 @@
 telemetry).
 
 Everything else in obs/ answers questions about a run after it happened
-(devprof parses a finished profile, flight stamps provenance, report renders
-a finished run); podwatch answers them WHILE the pod is training:
+(flight stamps provenance, report renders a finished run); podwatch answers them WHILE the pod is training:
 
  * **Per-rank time-series recorder** — env-gated by
    ``LIGHTGBM_TPU_TELEMETRY=<dir>``: at every chunk boundary the boost loop
@@ -29,7 +28,7 @@ a finished run); podwatch answers them WHILE the pod is training:
  * **Cross-rank aggregator + verdicts** — ``python -m
    lightgbm_tpu.obs.podwatch <dir>`` (and :func:`pod_summary` as a library)
    folds every rank's timeline shard and heartbeat into one pod view and
-   issues evidence-backed verdicts in the devprof style, each citing the
+   issues evidence-backed verdicts, each citing the
    module-constant threshold it tripped: *straggler* (a named rank whose
    mean chunk seconds exceed the pod median by ``STRAGGLER_FACTOR``, with
    the segment that diverges — the synthetic ``host_other`` bucket catches
@@ -38,8 +37,7 @@ a finished run); podwatch answers them WHILE the pod is training:
    (iteration spread across ranks beyond ``SKEW_ITERATIONS``) and *dead*
    (via resil/coord.stale_ranks, heartbeat evidence attached). Verdicts
    surface as ``podwatch_*`` gauges, a run_report() ``fleet_telemetry``
-   section (report.py renders it as §Fleet telemetry), bench stamps and
-   WARN-never-FAIL bench_diff rows.
+   section (report.py renders it as §Fleet telemetry).
 
 The aggregator half is stdlib-only and never imports jax — it must run on
 an operator's laptop against an NFS dir while the pod is still training.
@@ -552,8 +550,8 @@ def compute_verdicts(
     timelines: Dict[int, List[Dict]],
     stale: Optional[List] = None,
 ) -> List[Dict]:
-    """Evidence-backed verdict list (devprof style: ``verdict``/``why``/
-    ``evidence``, thresholds cited by value so the sentence stands alone).
+    """Evidence-backed verdict list (``verdict``/``why``/``evidence``,
+    thresholds cited by value so the sentence stands alone).
     Deterministic order: stragglers, stalls, skew, dead — each by rank."""
     verdicts: List[Dict] = []
     windows = {r: _window(s) for r, s in timelines.items()}

@@ -15,8 +15,7 @@ the single spine every lightgbm_tpu metric hangs off:
 
 ``MetricsRegistry`` hands out get-or-create instruments by name and renders
 them all as Prometheus text exposition (``prometheus_text``) or a JSON-able
-run report (``run_report`` — the block bench.py embeds in its output
-JSON). ``REGISTRY`` is the process-wide default: training
+run report (``run_report``). ``REGISTRY`` is the process-wide default: training
 (engine.py, utils/timer.py), the retrace watchdog and memwatch all publish
 here; each ServeApp keeps its own instance for isolation and the /metrics
 endpoint concatenates both (serve/server.py).
@@ -213,8 +212,8 @@ class MetricsRegistry:
         """Attach a pull section to ``run_report()``: ``fn()`` is called at
         report time and its JSON-able return lands under ``name`` (skipped
         when empty/None or raising — a section must never break a report).
-        The cost-analysis book (obs/costs.py) and the segment profiler
-        (obs/prof.py) publish their structured blocks this way."""
+        The cost-analysis book (obs/costs.py) and the model statistics
+        (obs/modelstats.py) publish their structured blocks this way."""
         with self._lock:
             self._sections[name] = fn
 
@@ -359,8 +358,8 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
     def run_report(self) -> Dict[str, object]:
-        """JSON-able block of every instrument's current state — the shared
-        structured run report bench.py embeds."""
+        """JSON-able block of every instrument's current state: the shared
+        structured run report."""
         counters: Dict[str, float] = {}
         gauges: Dict[str, float] = {}
         summaries: Dict[str, Dict[str, float]] = {}

@@ -1,10 +1,10 @@
 """Self-contained HTML run report: one file, zero dependencies, inline SVG.
 
 ``python -m lightgbm_tpu.obs.report`` renders a training flight log
-(obs/flight.py), a metrics/run-report snapshot (obs/registry.py), optional
-BENCH_*.json series and a drift snapshot into a single HTML file a browser
-opens offline — what a perf investigation passes around instead of
-four JSON files and a plotting environment.
+(obs/flight.py), a metrics/run-report snapshot (obs/registry.py) and a drift
+snapshot into a single HTML file a browser opens offline — what an
+investigation passes around instead of three JSON files and a plotting
+environment.
 
 Sections (each rendered only when its input is present):
 
@@ -12,30 +12,24 @@ Sections (each rendered only when its input is present):
   * learning curves — eval-history series per dataset/metric
   * per-tree gain + leaf count along the boosting sequence
   * cumulative gain-importance evolution of the top features
-  * growth segment breakdown (obs/prof.py, PR 6)
-  * device timeline audit (obs/devprof.py: busy/idle lanes, top-op table,
-    segment-grouped device self-time, transfers, bound-ness verdict)
   * serve drift table (serve/drift.py PSI per feature)
-  * bench series (headline value across BENCH_r*.json rounds)
   * counters/gauges digest
 
 Usage::
 
     python -m lightgbm_tpu.obs.report --flight run.jsonl \
-        --metrics metrics.json --bench 'BENCH_r*.json' -o report.html
+        --metrics metrics.json -o report.html
 
-``--metrics`` accepts either a bare ``run_report()`` block or a full bench
-record (the ``obs_report`` key is unwrapped). Stdlib-only: importing this
+``--metrics`` accepts either a bare ``run_report()`` block or a record that
+holds one under ``obs_report``. Stdlib-only: importing this
 module never touches a jax backend.
 """
 from __future__ import annotations
 
 import argparse
-import glob
 import html
 import json
 import math
-import os
 import sys
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -61,7 +55,6 @@ th { background: #f1f3f7; }
 .alert { color: #b91c1c; font-weight: 600; }
 .ok { color: #15803d; }
 svg { background: #fbfcfe; border: 1px solid #e3e6ee; margin: 6px 0; }
-.bar { fill: #2563eb; } .barlabel { font-size: 11px; fill: #1f2430; }
 """
 
 
@@ -152,100 +145,6 @@ def svg_line_chart(
             % (width - 190, mt + 4 + i * 15, color,
                width - 176, mt + 13 + i * 15, _esc(name[:26]))
         )
-    out.append("</svg>")
-    return "".join(out)
-
-
-def svg_bar_chart(
-    items: Sequence[Tuple[str, float]], title: str = "", width: int = 640,
-    unit: str = "",
-) -> str:
-    """Horizontal bars (segment breakdowns, share tables)."""
-    items = [(k, v) for k, v in items if v is not None]
-    if not items:
-        return ""
-    vmax = max(v for _, v in items) or 1.0
-    row_h, ml = 22, 170
-    height = 28 + row_h * len(items)
-    out = ['<svg width="%d" height="%d" role="img">' % (width, height)]
-    if title:
-        out.append(
-            '<text x="6" y="15" font-size="13" font-weight="600">%s</text>'
-            % _esc(title)
-        )
-    for i, (name, v) in enumerate(items):
-        y = 26 + i * row_h
-        w = max((width - ml - 130) * v / vmax, 1.0)
-        out.append(
-            '<text x="%d" y="%d" font-size="12" text-anchor="end">%s</text>'
-            % (ml - 6, y + 12, _esc(str(name)[:24]))
-        )
-        out.append(
-            '<rect class="bar" x="%d" y="%d" width="%.1f" height="14"/>'
-            % (ml, y, w)
-        )
-        out.append(
-            '<text class="barlabel" x="%.1f" y="%d">%s%s</text>'
-            % (ml + w + 5, y + 12, _fmt(v), _esc(unit))
-        )
-    out.append("</svg>")
-    return "".join(out)
-
-
-def svg_stacked_bars(
-    items: Sequence[Tuple[str, Sequence[Tuple[str, float, str]]]],
-    title: str = "", width: int = 640, unit: str = "",
-) -> str:
-    """Horizontal stacked bars: one row per item, each a list of
-    (segment_name, value, color) parts — the comms-vs-compute split of the
-    Multichip section. A legend is built from the distinct segment names."""
-    items = [(k, [(n, v, c) for n, v, c in parts if v and v > 0])
-             for k, parts in items]
-    items = [(k, parts) for k, parts in items if parts]
-    if not items:
-        return ""
-    vmax = max(sum(v for _, v, _ in parts) for _, parts in items) or 1.0
-    row_h, ml = 22, 170
-    legend: List[Tuple[str, str]] = []
-    for _, parts in items:
-        for n, _, c in parts:
-            if (n, c) not in legend:
-                legend.append((n, c))
-    height = 28 + row_h * len(items) + 18
-    out = ['<svg width="%d" height="%d" role="img">' % (width, height)]
-    if title:
-        out.append(
-            '<text x="6" y="15" font-size="13" font-weight="600">%s</text>'
-            % _esc(title)
-        )
-    for i, (name, parts) in enumerate(items):
-        y = 26 + i * row_h
-        out.append(
-            '<text x="%d" y="%d" font-size="12" text-anchor="end">%s</text>'
-            % (ml - 6, y + 12, _esc(str(name)[:24]))
-        )
-        x = float(ml)
-        total = sum(v for _, v, _ in parts)
-        for _, v, color in parts:
-            w = max((width - ml - 130) * v / vmax, 1.0)
-            out.append(
-                '<rect x="%.1f" y="%d" width="%.1f" height="14" '
-                'fill="%s"/>' % (x, y, w, color)
-            )
-            x += w
-        out.append(
-            '<text class="barlabel" x="%.1f" y="%d">%s%s</text>'
-            % (x + 5, y + 12, _fmt(total), _esc(unit))
-        )
-    ly = 26 + row_h * len(items) + 4
-    lx = ml
-    for name, color in legend:
-        out.append(
-            '<rect x="%d" y="%d" width="10" height="10" fill="%s"/>'
-            '<text x="%d" y="%d" font-size="11">%s</text>'
-            % (lx, ly, color, lx + 14, ly + 9, _esc(name[:18]))
-        )
-        lx += 14 + 7 * min(len(name), 18) + 18
     out.append("</svg>")
     return "".join(out)
 
@@ -347,116 +246,12 @@ def _section_importance_evolution(flight: Dict, top: int = 6) -> str:
 
 
 def _metrics_block(metrics: Optional[Dict]) -> Dict:
-    """Accept a run_report() block or a full bench record (obs_report key)."""
+    """Accept a run_report() block, bare or under an ``obs_report`` key."""
     if not metrics:
         return {}
     if "obs_report" in metrics and isinstance(metrics["obs_report"], dict):
         return metrics["obs_report"]
     return metrics
-
-
-def _section_segments(metrics: Dict) -> str:
-    segs = metrics.get("growth_segments_s")
-    if not isinstance(segs, dict) or not segs:
-        return ""
-    items = sorted(segs.items(), key=lambda kv: -float(kv[1]))
-    return (
-        "<h2>Growth segment breakdown</h2>"
-        + svg_bar_chart(
-            [(k, float(v)) for k, v in items],
-            title="seconds per tree (obs/prof.py)", unit=" s",
-        )
-    )
-
-
-def _section_device_timeline(metrics: Dict) -> str:
-    """The device-timeline audit (obs/devprof.py): busy/idle per lane,
-    segment-grouped device self-time (``unattributed`` rendered like any
-    other — loudly), the top-op table with roofline placement, transfer
-    totals, and the bound-ness verdict with its evidence inline."""
-    rec = metrics.get("device_timeline")
-    if not isinstance(rec, dict) or not rec:
-        return ""
-    out = ["<h2>Device timeline</h2>"]
-    v = rec.get("verdict") or {}
-    if v.get("bound"):
-        cls = "ok" if v["bound"] == "device-bound" else "alert"
-        out.append(
-            '<div><span class="%s">verdict: %s</span> — '
-            '<span class="small">%s</span></div>'
-            % (cls, _esc(v["bound"]), _esc(v.get("why", "")))
-        )
-    if rec.get("lanes_source"):
-        out.append(
-            '<div class="small">lanes: %s · window %ss · '
-            "device_busy_fraction %s · attributed %s</div>"
-            % (
-                _esc(rec["lanes_source"]), _fmt(float(rec.get("window_s", 0))),
-                "-" if rec.get("device_busy_fraction") is None
-                else "%.3f" % rec["device_busy_fraction"],
-                "-" if rec.get("attributed_fraction") is None
-                else "%.0f%%" % (100 * rec["attributed_fraction"]),
-            )
-        )
-    lanes = rec.get("lanes") or []
-    if lanes:
-        out.append(svg_stacked_bars(
-            [
-                (
-                    str(ln.get("device", "?")),
-                    [
-                        ("busy", float(ln.get("busy_s", 0.0)), "#2563eb"),
-                        ("idle",
-                         max(float(rec.get("window_s", 0.0))
-                             - float(ln.get("busy_s", 0.0)), 0.0),
-                         "#d8dce4"),
-                    ],
-                )
-                for ln in lanes
-            ],
-            title="busy vs idle per device lane", unit=" s",
-        ))
-    segs = rec.get("segments") or {}
-    if segs:
-        out.append(svg_bar_chart(
-            [(k, float(s)) for k, s in segs.items()],
-            title="device self-time per segment (TraceAnnotation "
-                  "attribution)", unit=" s",
-        ))
-    tops = rec.get("top_ops") or []
-    if tops:
-        out.append(_table(
-            ("op", "segment", "self s", "count", "share", "peak FLOPs"),
-            [
-                (
-                    str(t.get("op", ""))[:60], t.get("segment", ""),
-                    _fmt(float(t.get("self_s", 0.0))), t.get("count", 0),
-                    "%.1f%%" % (100 * float(t.get("share", 0.0))),
-                    "-" if t.get("peak_flops_fraction") is None
-                    else "%.2f%%" % (100 * t["peak_flops_fraction"]),
-                )
-                for t in tops
-            ],
-        ))
-    tr = rec.get("transfers") or {}
-    if tr:
-        rows = []
-        for direction in ("h2d", "d2h"):
-            d = tr.get(direction) or {}
-            if d:
-                rows.append((direction, d.get("count", 0),
-                             _fmt(float(d.get("seconds", 0.0))),
-                             _fmt(float(d.get("bytes", 0)))))
-        if rows:
-            out.append(_table(("direction", "events", "seconds", "bytes"),
-                              rows))
-    gaps = rec.get("dispatch_gaps") or {}
-    if gaps.get("histogram"):
-        out.append(svg_bar_chart(
-            [(k, float(n)) for k, n in gaps["histogram"].items()],
-            title="dispatch-gap (device idle) histogram", unit=" gaps",
-        ))
-    return "".join(out)
 
 
 def _section_drift(metrics: Dict, drift: Optional[Dict]) -> str:
@@ -500,150 +295,6 @@ def _section_drift(metrics: Dict, drift: Optional[Dict]) -> str:
     return head + _table(
         ("model", "feature", "PSI", "state"), [r[1:] for r in rows]
     )
-
-
-def _multichip_efficiency(rec: Dict) -> List[Point]:
-    """(devices, scaling efficiency) points: measured iters/s at D devices
-    over the ideal D x (the sweep's n=1 measurement). Prefers the record's
-    own ``efficiency_by_devices`` (helpers/multichip_bench.py) and falls
-    back to recomputing from the scaling list."""
-    eff = rec.get("efficiency_by_devices")
-    if eff:
-        return [(float(d), float(e)) for d, e in eff]
-    pts = sorted(
-        (float(p["devices"]), float(p["iters_per_sec"]))
-        for p in rec.get("scaling") or []
-        if p.get("iters_per_sec")
-    )
-    base = next((v for d, v in pts if d == 1), None)
-    if not base:
-        return []
-    return [(d, v / (d * base)) for d, v in pts]
-
-
-def _section_multichip(records: List[Tuple[str, Dict]]) -> str:
-    """The Multichip page: devices-vs-iters/s scaling curves, measured-vs-
-    ideal scaling efficiency, the comms/compute split (obs/dist.py
-    attribution), and the latest round's per-device shard table — one
-    report answers 'how fast', 'how does it scale', and 'WHY it bends'."""
-    series: List[Tuple[str, List[Point]]] = []
-    eff_series: List[Tuple[str, List[Point]]] = []
-    stacked = []
-    rows = []
-    latest_devices = None
-    for name, rec in records:
-        pts = [
-            (float(p["devices"]), float(p["iters_per_sec"]))
-            for p in rec.get("scaling") or []
-            if p.get("iters_per_sec")
-        ]
-        if not pts:
-            continue
-        short = name.replace(".json", "")
-        series.append((short, sorted(pts)))
-        eff = _multichip_efficiency(rec)
-        if eff:
-            eff_series.append((short, eff))
-        cf = rec.get("comms_fraction")
-        if cf is not None:
-            cf = float(cf)
-            stacked.append((short, [
-                ("comms", cf * 100.0, "#dc2626"),
-                ("compute", (1.0 - cf) * 100.0, "#2563eb"),
-            ]))
-        if rec.get("per_device"):
-            latest_devices = (short, rec["per_device"])
-        rows.append((
-            name, rec.get("platform", "?"),
-            " / ".join("%g@%d" % (v, int(d)) for d, v in sorted(pts)),
-            "-" if rec.get("speedup_vs_1dev") is None
-            else "%.2fx" % rec["speedup_vs_1dev"],
-            "-" if rec.get("scaling_efficiency") is None
-            else "%.0f%%" % (float(rec["scaling_efficiency"]) * 100),
-            "-" if cf is None else "%.1f%%" % (cf * 100),
-        ))
-    if not series:
-        return ""
-    out = ["<h2>Multichip scaling</h2>"]
-    out.append(svg_line_chart(
-        series, title="devices vs iters/s (data-parallel sharded chunk)",
-        y_zero=True,
-    ))
-    if eff_series:
-        # ideal = 1.0 reference line spanning the measured device range
-        xs = [x for _, pts in eff_series for x, _ in pts]
-        eff_series = eff_series + [
-            ("ideal", [(min(xs), 1.0), (max(xs), 1.0)])
-        ]
-        out.append(svg_line_chart(
-            eff_series,
-            title="scaling efficiency (measured / ideal linear)",
-            y_zero=True,
-        ))
-    if stacked:
-        out.append(svg_stacked_bars(
-            stacked,
-            title="tree-growth time split: collective vs compute "
-                  "(obs/dist.py)",
-            unit="%",
-        ))
-    out.append(_table(
-        ("record", "platform", "iters/s @ devices", "speedup vs 1 dev",
-         "scaling eff", "comms"),
-        rows,
-    ))
-    if latest_devices:
-        short, per_dev = latest_devices
-        out.append(
-            '<div class="small">per-device shard table (%s)</div>' % short
-        )
-        out.append(_table(
-            ("device", "rows", "wait s"),
-            [
-                (d.get("device", "?"), d.get("rows", "-"),
-                 "-" if d.get("wait_s") is None else "%.4f" % d["wait_s"])
-                for d in per_dev
-            ],
-        ))
-    return "".join(out)
-
-
-def _section_bench(bench_records: List[Tuple[str, Dict]]) -> str:
-    if not bench_records:
-        return ""
-    bench_records = [
-        (n, r) for n, r in bench_records if not r.get("scaling")
-    ]
-    if not bench_records:
-        return ""
-    pts_v: List[Point] = []
-    pts_auc: List[Point] = []
-    rows = []
-    for i, (name, rec) in enumerate(bench_records):
-        v = rec.get("value")
-        if v is not None:
-            pts_v.append((float(i), float(v)))
-        auc = rec.get("train_auc")
-        if auc is not None:
-            pts_auc.append((float(i), float(auc)))
-        rows.append((
-            name, rec.get("platform", "?"),
-            "-" if v is None else _fmt(float(v)),
-            "-" if auc is None else "%.5f" % auc,
-            rec.get("roofline_source", "-"),
-        ))
-    out = ["<h2>Bench series</h2>"]
-    out.append(svg_line_chart(
-        [("iters/s", pts_v)], title="headline iters/s per round", y_zero=True,
-    ))
-    if pts_auc:
-        out.append(svg_line_chart(
-            [("train_auc", pts_auc)], title="train AUC per round",
-        ))
-    out.append(_table(
-        ("record", "platform", "iters/s", "train_auc", "roofline"), rows
-    ))
-    return "".join(out)
 
 
 def _section_fleet(metrics: Dict) -> str:
@@ -700,29 +351,9 @@ def _section_registry_digest(metrics: Dict, limit: int = 40) -> str:
 # assembly + CLI
 # ---------------------------------------------------------------------------
 
-def load_bench_records(pattern: str) -> List[Tuple[str, Dict]]:
-    """(basename, record) for every bench JSON matching ``pattern``: the
-    driver's BENCH_r*.json wrapper is unwrapped (record under "parsed"),
-    bare bench.py records pass through, anything without a "metric" key is
-    skipped."""
-    out: List[Tuple[str, Dict]] = []
-    for p in sorted(glob.glob(pattern)):
-        try:
-            with open(p, encoding="utf-8") as fh:
-                doc = json.load(fh)
-        except (OSError, ValueError):
-            continue
-        rec = doc.get("parsed") if isinstance(doc, dict) else None
-        rec = rec if isinstance(rec, dict) else doc
-        if isinstance(rec, dict) and "metric" in rec:
-            out.append((os.path.basename(p), rec))
-    return out
-
-
 def render(
     flight: Optional[Dict] = None,
     metrics: Optional[Dict] = None,
-    bench_records: Optional[List[Tuple[str, Dict]]] = None,
     drift: Optional[Dict] = None,
     title: str = "lightgbm_tpu run report",
 ) -> str:
@@ -738,12 +369,8 @@ def render(
         _section_learning_curves(flight),
         _section_trees(flight),
         _section_importance_evolution(flight),
-        _section_segments(mblock),
-        _section_device_timeline(mblock),
         _section_fleet(mblock),
         _section_drift(mblock, drift),
-        _section_bench(bench_records or []),
-        _section_multichip(bench_records or []),
         _section_registry_digest(mblock),
         "<div class='small'>generated by python -m lightgbm_tpu.obs.report"
         "</div></body></html>",
@@ -758,17 +385,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument("--flight", help="flight JSONL log (obs/flight.py)")
     ap.add_argument("--metrics",
-                    help="run_report JSON (or a bench record; obs_report "
-                         "is unwrapped)")
-    ap.add_argument("--bench", help="glob of bench JSON records "
-                                    "(e.g. 'BENCH_r*.json')")
+                    help="run_report JSON (bare, or under an obs_report "
+                         "key)")
     ap.add_argument("--drift", help="a /drift endpoint snapshot JSON")
     ap.add_argument("--title", default="lightgbm_tpu run report")
     ap.add_argument("-o", "--out", default="run_report.html")
     args = ap.parse_args(argv)
-    if not (args.flight or args.metrics or args.bench or args.drift):
-        ap.error("nothing to report: pass --flight, --metrics, --bench "
-                 "and/or --drift")
+    if not (args.flight or args.metrics or args.drift):
+        ap.error("nothing to report: pass --flight, --metrics and/or "
+                 "--drift")
 
     flight = None
     if args.flight:
@@ -783,9 +408,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.drift:
         with open(args.drift, encoding="utf-8") as fh:
             drift = json.load(fh)
-    bench_records = load_bench_records(args.bench) if args.bench else []
-    doc = render(flight=flight, metrics=metrics, bench_records=bench_records,
-                 drift=drift, title=args.title)
+    doc = render(flight=flight, metrics=metrics, drift=drift,
+                 title=args.title)
     with open(args.out, "w", encoding="utf-8") as fh:
         fh.write(doc)
     print("report: wrote %s (%d bytes)" % (args.out, len(doc)))

@@ -25,7 +25,7 @@ and, with ``LIGHTGBM_TPU_RETRACE=fail``, raises ``LightGBMError`` — turning a
 silent performance cliff into a loud failure. ``LIGHTGBM_TPU_RETRACE=warn``
 is the explicit spelling of the default. Counts feed the metrics registry as
 ``jit_traces_total`` / ``jit_retraces_after_warmup`` (obs/__init__.py wires
-the gauges), so /metrics and bench reports carry them per run.
+the gauges), so /metrics and run reports carry them per run.
 """
 from __future__ import annotations
 

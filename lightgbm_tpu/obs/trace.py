@@ -10,7 +10,7 @@ running process):
    materialised tree; never one per row, column, split or request) go to a
    ring of the last ``RING_EVENTS`` events, oldest dropped and counted.
    ``events()`` copies it out, ``reset()`` empties it. Spans of every other
-   category (``serve``, ``loop``, ``cli``, the profilers') are not recorded
+   category (``serve``, ``loop``, ``cli``, ``resil``) are not recorded
    and cost what they did before the ring: one lookup.
  * ``<path>``: **file mode**, as before: every ``span()`` of every category
    also records into a buffer capped at ``MAX_EVENTS`` that is written to
@@ -46,7 +46,7 @@ Span and counter sites (name [cat], where):
     tree (``ops/grow.COUNTER_NAMES``), emitted when the tree is materialised
     on the host (``GBDT._materialize``)
   * file mode only: ``serve.*`` [serve], ``loop.*`` [loop], ``cli.*`` [cli],
-    ``prof.*`` / ``dist.*``
+    ``resil.*`` [resil]
 
 Device correlation: when jax is already imported, a recorded ``span()`` also
 enters ``jax.profiler.TraceAnnotation(name)``, so the program's spans lie in
@@ -54,8 +54,8 @@ the host plane of every profile (``LIGHTGBM_TPU_PROFILE``, the benchmark's
 traced run) under their names, on the profiler's clock.
 
 One trace file per PROCESS: a subprocess inheriting the env var would clobber
-the parent's file at exit, so drivers that fan out children rewrite the path
-per child (helpers/multichip_bench.py appends ``.dev<N>``).
+the parent's file at exit, so a driver that fans out children rewrites the
+path per child.
 
 Thread-safe throughout.
 """
@@ -63,6 +63,8 @@ from __future__ import annotations
 
 import atexit
 import collections
+import glob as glob_mod
+import gzip
 import itertools
 import json
 import os
@@ -253,10 +255,9 @@ def rank_suffixed(target: str) -> str:
     """``<target>.rank<N>`` when a multi-process jax.distributed world is
     initialized (consults only an already-imported jax; never imports it).
     Shared clobber fix for every env-derived per-process artifact path:
-    the tracer's LIGHTGBM_TPU_TRACE file here, utils/timer.maybe_profile's
-    LIGHTGBM_TPU_PROFILE dir, and obs/devprof.capture's profile window —
-    devprof.find_trace_files folds the ``.rank<N>`` siblings back together
-    at parse time."""
+    the tracer's LIGHTGBM_TPU_TRACE file here and utils/timer.maybe_profile's
+    LIGHTGBM_TPU_PROFILE dir — :func:`find_trace_files` folds the
+    ``.rank<N>`` siblings back together for ``merge``."""
     if ".rank" in target:
         return target
     jx = sys.modules.get("jax")
@@ -543,9 +544,44 @@ def watch_compiles() -> None:
 # multi-file merge: fold per-process/per-rank traces into ONE timeline
 # ---------------------------------------------------------------------------
 
+def load_chrome_trace(path: str) -> Dict:
+    """One Chrome-trace document, transparently gunzipping ``*.gz``."""
+    opener = gzip.open if path.endswith(".gz") else open
+    with opener(path, "rt", encoding="utf-8") as fh:
+        return json.load(fh)
+
+
+def find_trace_files(profile_dir: str) -> List[str]:
+    """The Chrome-trace files of a profiler capture dir.
+
+    Looks under ``<dir>/plugins/profile/<session>/*.trace.json(.gz)``, the
+    newest session per dir, and — the multi-process story — folds sibling
+    ``<dir>.rank<N>`` dirs in, so one merge sees the whole pod. A direct
+    file path passes through untouched.
+    """
+    if os.path.isfile(profile_dir):
+        return [profile_dir]
+    dirs = [profile_dir] + sorted(
+        glob_mod.glob(glob_mod.escape(profile_dir) + ".rank*"))
+    out: List[str] = []
+    for d in dirs:
+        sessions = sorted(
+            s for s in glob_mod.glob(
+                os.path.join(glob_mod.escape(d), "plugins", "profile", "*"))
+            if os.path.isdir(s))
+        for s in sessions[-1:]:
+            out.extend(sorted(
+                glob_mod.glob(os.path.join(glob_mod.escape(s),
+                                           "*.trace.json.gz"))
+                + glob_mod.glob(os.path.join(glob_mod.escape(s),
+                                             "*.trace.json"))
+            ))
+    return out
+
+
 def merge_traces(out_path: str, in_paths) -> Dict:
-    """Fold several Chrome-trace files (a pod's per-rank ``.rank<N>`` files, a sweep's ``.dev<D>``
-    workers) into ONE Perfetto-loadable timeline. Every source (file, pid)
+    """Fold several Chrome-trace files (a pod's per-rank ``.rank<N>`` files)
+    into ONE Perfetto-loadable timeline. Every source (file, pid)
     pair is remapped to a fresh DISJOINT pid with a ``process_name``
     metadata row naming its origin, so same-pid events from different
     processes can never interleave; ``dropped_events`` markers are summed
@@ -553,8 +589,6 @@ def merge_traces(out_path: str, in_paths) -> Dict:
     export format) load transparently, so per-rank LIGHTGBM_TPU_PROFILE
     captures merge next to the host-span files.
     Returns {files, events, pids, dropped, path}."""
-    from . import devprof as devprof_mod  # one gz-transparent loader
-
     events: List[Dict] = []
     pid_map: Dict = {}
     dropped = 0
@@ -562,7 +596,7 @@ def merge_traces(out_path: str, in_paths) -> Dict:
     files = 0
     for i, p in enumerate(in_paths):
         try:
-            doc = devprof_mod.load_chrome_trace(str(p))
+            doc = load_chrome_trace(str(p))
         except (OSError, ValueError):
             continue  # a torn/absent child trace must not kill the merge
         files += 1
@@ -603,7 +637,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     """``python -m lightgbm_tpu.obs.trace merge -o out.json in1 in2 ...``
     (globs welcome) — the pod-wide timeline merge. Stdlib only."""
     import argparse
-    import glob as glob_mod
 
     ap = argparse.ArgumentParser(
         prog="python -m lightgbm_tpu.obs.trace",
@@ -626,11 +659,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         for hit in hits if hits else [item]:
             if os.path.isdir(hit):
                 # a profiler capture dir: fold its (and its .rank<N>
-                # siblings') Chrome traces in — obs/devprof.py owns the
-                # directory-layout knowledge, stdlib only like this module
-                from . import devprof as devprof_mod
-
-                paths.extend(devprof_mod.find_trace_files(hit))
+                # siblings') Chrome traces in
+                paths.extend(find_trace_files(hit))
             else:
                 paths.append(hit)
     # a dir and its .rank<N> sibling both matching the glob would fold the

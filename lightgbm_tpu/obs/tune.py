@@ -1,7 +1,7 @@
 """Shape-aware histogram autotuner (docs/HistogramRouting.md, ISSUE 13).
 
-``hist_build`` owns ~69% of tree-growth segment time (obs/prof.py at the 1M
-bench shape) — yet until this module the kernel that served it was picked by
+``hist_build`` owns most of tree-growth time (PERF.md section 5's table by
+scope) — yet until this module the kernel that served it was picked by
 ONE import-time env default. The bucketed grower actually emits histogram
 calls at a *distribution* of shapes (the {2^k} ∪ {3·2^(k-1)} bucket lattice,
 ops/grow.py ``bucket_sizes``), and the winner measurably differs per shape:
@@ -60,7 +60,7 @@ ENV_PATH = "LIGHTGBM_TPU_HIST_TUNE"
 
 def entries_digest(entries: Sequence[Dict]) -> str:
     """Content digest over the routing-relevant entry fields — the value
-    the flight manifest and bench records stamp, and the tamper check
+    the flight manifest stamps, and the tamper check
     ``load_table`` verifies."""
     import hashlib
 

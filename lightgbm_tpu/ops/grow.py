@@ -123,8 +123,9 @@ def spec_batch_slots(
     tune route) declines spec mode: the spec batch histograms candidates at
     the batch-max bucket size, so a route whose impl choice varies with the
     row bucket would let the same logical segment take different kernels in
-    the fused (spec) vs segmented (W=1) programs — breaking the profiler's
-    bitwise-identity proof (docs/HistogramRouting.md §Exactness).
+    the batch and in the W=1 pass — and the speculative and the sequential
+    grower could then grow different trees (docs/HistogramRouting.md
+    §Exactness).
     """
     bucketed = hist_mode == "bucketed" and not has_lazy_cegb and num_leaves > 1
     spec_ok = (
@@ -169,7 +170,7 @@ class TreeArrays(NamedTuple):
     leaf_depth: jax.Array  # [M] int32
     cat_member: jax.Array  # [M-1, B] bool: left-side bin membership bitsets
     # [len(COUNTER_NAMES)] f32: how the grower worked for this tree, not part
-    # of the model (None from the native host learner and the profilers)
+    # of the model (None from the native host learner)
     counters: Optional[jax.Array] = None
 
 
@@ -407,11 +408,9 @@ def _lattice_index(sizes_arr: jax.Array, n) -> jax.Array:
 
 
 class BucketKernels(NamedTuple):
-    """The bucketed grower's SEGMENT SEAMS: the per-split partition and
-    segment-histogram kernels, extracted from grow_tree so the fused
-    while_loop grower and the segmented profiler (obs/prof.py) trace the
-    exact same ops — the bitwise-identity guarantee between the two comes
-    from sharing THIS code, not from a tolerance."""
+    """The bucketed grower's per-split partition and segment-histogram
+    kernels for one dataset layout, with the extents its work counters
+    book (``make_bucket_kernels`` builds them, ``grow_tree`` calls them)."""
 
     #: (order, begin[W], pcnt[W], feat[W], thr[W], dleft[W], member[W, B])
     #: -> (new order, left physical counts [W])
@@ -440,21 +439,14 @@ def make_bucket_kernels(
 ) -> BucketKernels:
     """Build the bucketed partition / segment-histogram kernels for one
     dataset layout. ``kb`` is the speculative-batch width the caller will
-    trace with (it only widens the flat-partition branch lattice's cap);
-    the profilers pass 0. Bodies are the ones grow_tree always traced —
-    moved, not rewritten. Consumers: the fused while_loop grower here,
-    the sequential segment profiler (obs/prof.py), and the SHARDED
-    segment profiler (obs/dist.py), which traces these same kernels
-    per-shard inside shard_map bodies so its local-compute segments are
-    op-identical to the fused data-parallel program's.
+    trace with (it only widens the flat-partition branch lattice's cap; 0
+    for the sequential grower). One caller family: ``grow_tree``, on one
+    device or per shard inside the data-parallel grower's shard_map.
 
     ``hist_route`` is the run's frozen histogram tune route
-    (ops/histogram.HistRoute) — THIS is the one seam that hands the
-    measured per-shape routing to every consumer at once: each bucket
-    branch's leaf_histogram call resolves its impl from the route at trace
-    time, keyed on that branch's static segment size, so the fused grower,
-    both profilers and the sharded path can never disagree on which kernel
-    a shape class runs (docs/HistogramRouting.md)."""
+    (ops/histogram.HistRoute): each bucket branch's leaf_histogram call
+    resolves its impl from the route at trace time, keyed on that branch's
+    static segment size (docs/HistogramRouting.md)."""
     N = bins.shape[1]
     B = num_bins
     F = feature_meta["num_bin"].shape[0]
@@ -928,10 +920,8 @@ def grow_tree(
     else:
         is_cat_arr = is_cat_arr.astype(bool)
 
-    # Bucketed partition / segment-histogram kernels come from the shared
-    # seam factory (make_bucket_kernels above): one implementation serves
-    # the fused while_loop grower here AND the segmented profiler
-    # (obs/prof.py), so the two can never drift numerically.
+    # Bucketed partition / segment-histogram kernels (make_bucket_kernels
+    # above), shared by the sequential and the speculative body.
     if bucketed:
         _kern = make_bucket_kernels(
             bins, feature_meta, B, num_group_bins=num_group_bins,

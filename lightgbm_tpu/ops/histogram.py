@@ -365,18 +365,18 @@ def route_rows_variant(
 
     The spec-mode gate (ops/grow.py ``spec_batch_slots``): the speculative
     grower histograms a candidate batch at the batch-max bucket size while
-    the sequential/segmented (W=1) form uses each segment's own bucket — a
-    route whose impl choice VARIES across the run's reachable bucket
-    classes would let the SAME logical segment take different impls in the
-    two programs and break the profiler's fused-vs-segmented bitwise
-    identity (obs/prof.py). Such a route runs the sequential grower; a
-    route that resolves every reachable class to ONE impl (the default, or
-    uniformly any single kernel) is self-consistent and leaves spec mode
-    on. With the run geometry (``num_bins``/``hist_dtype``/``n_rows``) the
-    check is exact — entries for unreachable (B, dtype) groups cost
-    nothing; without it, conservatively shape-blind. With
-    LIGHTGBM_TPU_HIST_IMPL in force the route never engages (env
-    precedence), so it cannot introduce variance."""
+    the W=1 pass uses each segment's own bucket — a route whose impl choice
+    VARIES across the run's reachable bucket classes would let the SAME
+    logical segment take different impls in the two, so the speculative
+    and the sequential grower could grow different trees. Such a route
+    runs the sequential grower; a route that resolves every reachable
+    class to ONE impl (the default, or uniformly any single kernel) is
+    self-consistent and leaves spec mode on. With the run geometry
+    (``num_bins``/``hist_dtype``/``n_rows``) the check is exact — entries
+    for unreachable (B, dtype) groups cost nothing; without it,
+    conservatively shape-blind. With LIGHTGBM_TPU_HIST_IMPL in force the
+    route never engages (env precedence), so it cannot introduce
+    variance."""
     if route is None or _ENV_IMPL:
         return False
     if num_bins is None or hist_dtype is None or n_rows is None:

@@ -78,7 +78,7 @@ class BucketedDispatcher:
             self.bucket_counts[b] += 1
         if new_bucket:
             # the process-wide observability counter behind /metrics and the
-            # bench/bringup run reports (obs/registry.py) — the generalized
+            # run reports (obs/registry.py) — the generalized
             # form of the zero-retraces-after-warmup assertion this class
             # used to keep private
             obs_registry.REGISTRY.counter("bucket_retraces").inc()

@@ -33,7 +33,7 @@ Reference sources, in order of preference:
 Surfaces: ``serve_drift_psi{model=,feature=}`` gauges on /metrics, the
 ``/drift`` endpoint (per-feature PSI + alert state), a ``warn_once`` + the
 ``serve_drift_alerts_total{feature=}`` counter when a feature crosses the
-threshold, and a WARN row in the bench-diff gate (helpers/bench_diff.py).
+threshold.
 
 Categorical features are not tracked (their codes are raw category values,
 not lattice ranks — an unbounded domain PSI over a dense histogram cannot
@@ -287,9 +287,9 @@ class DriftMonitor:
 
     def _count_alert(self, name: str, value: float) -> None:
         """Record the crossing on the app registry AND the process-wide one:
-        the app registry backs /metrics, while bench/bringup artifacts embed
-        the GLOBAL registry's run_report — without the mirror the
-        bench_diff WARN row could never see an alert. The global PSI gauge
+        the app registry backs /metrics, while bringup artifacts embed the
+        GLOBAL registry's run_report — without the mirror a reader of that
+        report could never see an alert. The global PSI gauge
         holds the value AT crossing time (the app-registry gauges stay
         scrape-fresh via publish())."""
         counted = []

@@ -61,7 +61,7 @@ def place_compile_cache() -> str:
     cache key, so it must not depend on the working directory, a pid or a
     clock. The one place in the repo that sets ``jax_compilation_cache_dir``;
     called by the entry points that compile the grower (``chip_smoke.py``,
-    ``bench.py``, the CLI).
+    ``benchmarks/run.py``, the CLI).
     """
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:

@@ -153,8 +153,8 @@ class PhaseTimers:
         (labels carry the phase name): ``train_phase_seconds_total``,
         ``train_phase_dispatch_seconds_total``, ``train_phase_calls_total``.
         No-op when nothing was recorded; engine.train calls this once at
-        the end so /metrics, bench JSON and bringup reports all read the
-        same numbers (docs/Observability.md)."""
+        the end so /metrics and run reports read the same numbers
+        (docs/Observability.md)."""
         if not self.seconds:
             return
         from ..obs import registry as registry_mod
@@ -178,10 +178,10 @@ def maybe_profile():
     dir clobber each other's ``plugins/profile/<ts>`` session — so the
     env-derived dir gets the shared ``.rank<N>`` suffix (obs/trace.py
     ``rank_suffixed``, the same fix PR 9 gave LIGHTGBM_TPU_TRACE);
-    ``obs.devprof`` and ``obs.trace merge`` fold the per-rank dirs back
-    together at parse time. Parse the capture with
-    ``python -m lightgbm_tpu.obs.devprof parse <dir>``
-    (docs/Observability.md §Device timeline).
+    ``python -m lightgbm_tpu.obs.trace merge <dir>`` folds the per-rank
+    dirs' Chrome traces back together. The capture's ``.xplane.pb`` is read
+    by ``benchmarks/trace_reduce.py`` (device busy and idle, the operations)
+    and ``helpers/xplane_scopes.py`` (time by ``jax.named_scope``).
     """
     out_dir = os.environ.get(ENV_PROFILE, "")
     if not out_dir:

@@ -58,14 +58,14 @@ def rng():
 _QUICK_MODULES = {
     "test_api_surface", "test_binning",
     "test_binning_equiv", "test_chip_smoke", "test_device_chunk",
-    "test_devprof", "test_dist_obs", "test_elastic",
+    "test_dist_obs", "test_elastic",
     "test_errors", "test_feature_importance", "test_flex",
     "test_graftlint",
     "test_hist_modes", "test_irscan", "test_loop", "test_metric_alias",
     "test_micro_exact", "test_model_io", "test_model_obs", "test_native",
     "test_obs",
     "test_ops", "test_parallel_chunk", "test_param_docs", "test_podwatch",
-    "test_prof", "test_resil", "test_sanitize",
+    "test_resil", "test_sanitize",
     "test_serve_drift", "test_serve_packed",
     "test_serve_resil", "test_serve_server", "test_snapshot_timers",
     "test_tune", "test_vfile", "test_warmstart",
@@ -83,9 +83,9 @@ def pytest_configure(config):
         "slow: long-tail cases excluded from the tier-1 window "
         "(-m 'not slow'); run them with -m slow when touching their "
         "subsystem. Membership lives in tests/slow_tests.txt (applied at "
-        "collection) = measured duration x redundancy (ISSUE 14 "
-        "burn-down), NOT importance — every listed case has a quicker "
-        "sibling or a check.sh smoke covering the same seam.",
+        "collection) = measured duration x redundancy, NOT importance: "
+        "every listed case is over about 30 s or has a quicker sibling "
+        "or a check.sh smoke covering the same seam.",
     )
 
 
@@ -179,7 +179,7 @@ def _mp_collectives_supported():
 
 
 # ---------------------------------------------------------------------------
-# Tier-1 timeout burn-down (ISSUE 14): the slow marker's membership lives in
+# The slow marker's membership lives in
 # tests/slow_tests.txt (one node id per line, relative to tests/, with the
 # per-block redundancy justification). The tier-1 window runs -m 'not slow';
 # run the excluded long tail with -m slow when touching its subsystem.

@@ -1,18 +1,16 @@
-"""Regenerate the checked-in devprof golden captures (deterministic gzip).
+"""Regenerate the checked-in profiler capture fixtures (deterministic gzip).
 
 Two synthetic LIGHTGBM_TPU_PROFILE capture dirs in the XLA profiler's
-on-disk layout (``<dir>/plugins/profile/<session>/<host>.trace.json.gz``):
+on-disk layout (``<dir>/plugins/profile/<session>/<host>.trace.json.gz``),
+read by tests/test_obs.py's ``trace merge`` tests:
 
- * ``tpu_capture`` — one host lane with TraceAnnotation spans from the
-   real vocabulary (``prof.hist_build``, ``prof.split_scan``, the
-   ``tree growth`` phase, ``train.iteration``), one ``/device:TPU:0`` lane
-   with nested op events (some carrying flops/bytes args, one outside
-   every annotation -> ``unattributed``), and H2D/D2H transfer events
-   with byte counts. Every expected number in tests/test_devprof.py is
-   derived from the literals below.
+ * ``tpu_capture`` — one host lane with TraceAnnotation spans, one
+   ``/device:TPU:0`` lane with nested op events, and H2D/D2H transfer
+   events: what ``python -m lightgbm_tpu.obs.trace merge <dir>`` expands
+   and gunzips.
  * ``rank_capture.rank0`` / ``rank_capture.rank1`` — a two-rank
    ``maybe_profile`` capture (the base dir does not exist, exactly as the
-   rank-suffix fix leaves things) proving find_trace_files folds ranks.
+   rank suffix leaves things) proving find_trace_files folds ranks.
 
 Run from the repo root::
 

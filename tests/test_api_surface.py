@@ -239,3 +239,44 @@ class TestBoosterSurface:
         bst2 = lgb.train(PARAMS, lgb.Dataset(X, label=y), num_boost_round=1)
         bst2.model_from_string(s)
         np.testing.assert_allclose(bst2.predict(X), p, rtol=1e-12)
+
+
+#: obs/ modules that still import the training path: debts (ROADMAP.md,
+#: "Debts left by PR 30"). This list can only shrink.
+_OBS_IMPORTS_TRAINING = {"tune", "irscan", "memwatch"}
+
+
+def test_obs_does_not_import_the_training_path():
+    """The arrow points one way: ops/ and models/ publish into obs/, and no
+    module of obs/ but the listed debts imports them back — at module level
+    or inside a function — so no instrument can hold the grower's shape."""
+    import ast
+    import os
+
+    obs_dir = os.path.join(os.path.dirname(lgb.__file__), "obs")
+    offenders = {}
+    for fname in sorted(os.listdir(obs_dir)):
+        mod = fname[:-3]
+        if not fname.endswith(".py") or mod in _OBS_IMPORTS_TRAINING:
+            continue
+        with open(os.path.join(obs_dir, fname), encoding="utf-8") as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                # inside lightgbm_tpu/obs/, level 2 is the package root
+                if node.level == 2:
+                    base = node.module or ""
+                elif node.level == 0:
+                    base = (node.module or "").partition("lightgbm_tpu.")[2]
+                else:
+                    continue
+                names = [base] if base else [a.name for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name.partition("lightgbm_tpu.")[2]
+                         for a in node.names]
+            else:
+                continue
+            for name in names:
+                if name.split(".")[0] in ("ops", "models"):
+                    offenders.setdefault(mod, set()).add(name)
+    assert not offenders, offenders

@@ -1,20 +1,14 @@
-"""Distributed observability (obs/dist.py + trace merge + report Multichip).
+"""Distributed observability (obs/dist.py + trace merge).
 
-Runs on the conftest 8-virtual-CPU-device mesh. Three proof tiers:
+Runs on the conftest 8-virtual-CPU-device mesh:
 
- * the SHARDED segment profiler: fenced shard_map sub-steps (local
-   histogram build / _combine psum / root reduction / split scan) must be
-   bitwise-identical to the fused ``grow_tree_data_parallel`` program, and
-   ``segmented_train_chunk`` must reproduce the fused sharded chunk's
-   model strings AND score carries;
  * pod-wide aggregation: registry snapshot merge (counters == per-process
    sums, gauges keep ``process=`` provenance), the file-based fallback,
    and the Chrome-trace merge (disjoint pids, dropped-events marker
    preserved);
- * shard-skew surfaces: the N=1003-over-8 padding shape's known 7x126+121
-   row split in ``train_shard_rows{device=}``, dispatch-wait gauges under
-   ``LIGHTGBM_TPU_DIST_PROF=1``, and the report's Multichip section /
-   bench_diff's scaling-efficiency WARN row.
+ * shard skew: the N=1003-over-8 padding shape's known 7x126+121 row
+   split in ``train_shard_rows{device=}``;
+ * the flight manifest's mesh and process fields.
 """
 import json
 import os
@@ -22,7 +16,6 @@ import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 import jax
 
@@ -167,81 +160,7 @@ def test_trace_rank_suffix_under_distributed(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# sharded segment profiler
-# ---------------------------------------------------------------------------
-
-def test_profile_sharded_growth_bitwise_and_structure():
-    X, y = _data()
-    bst = _train({"device_chunk_size": 3, "bagging_freq": 2,
-                  "bagging_fraction": 0.8}, X, y, 4)
-    rec = dist.profile_sharded_growth(bst, iters=1)
-    assert rec["bitwise_identical"] is True
-    segs = rec["segments_per_tree_s"]
-    for name in ("root_init", "hist_build", "hist_combine", "root_reduce",
-                 "partition", "split_scan", "hist_subtract", "finalize"):
-        assert name in segs, name
-    assert set(rec["collective_segments"]) == {"hist_combine", "root_reduce"}
-    assert 0.0 < rec["comms_fraction"] < 1.0
-    assert rec["devices"] == 2
-    # collective payload: [F, B, 3] f32 — the HistogramSource seam's shape
-    # math must agree with the trainer's histogram dimensions
-    F = bst._gbdt.feature_meta["num_bin"].shape[0]
-    B = bst._gbdt.num_bins
-    assert rec["collective_bytes_per_split"] == F * B * 3 * 4
-    # per-tree collective bytes: one hist psum per split + the root's,
-    # plus the 3-scalar root reduction
-    per_tree = rec["segment_counts"]["hist_combine"] / rec["trees"]
-    assert rec["collective_bytes_per_tree"] == int(
-        per_tree * F * B * 3 * 4
-        + rec["segment_counts"]["root_reduce"] / rec["trees"] * 12
-    )
-    # gauges landed with the collective label, and sharded="true" keeps
-    # them disjoint from the serial profiler's same-named segments
-    g = registry_mod.REGISTRY.gauge("growth_segment_seconds_total").values()
-    assert (("collective", "true"), ("segment", "hist_combine"),
-            ("sharded", "true")) in g
-    assert dist.last_record()["comms_fraction"] == rec["comms_fraction"]
-
-
-def test_profile_sharded_growth_refuses_serial():
-    X, y = _data(n=200)
-    p = {"objective": "binary", "num_leaves": 6, "verbosity": -1}
-    bst = lgb.train(p, lgb.Dataset(X, label=y), 2)
-    with pytest.raises(Exception, match="data-parallel"):
-        dist.profile_sharded_growth(bst)
-
-
-def test_segmented_train_chunk_model_and_scores_identical():
-    X, y = _data(n=700, seed=11)
-    params = {"device_chunk_size": 4, "bagging_freq": 2,
-              "bagging_fraction": 0.8}
-    rounds = 9
-    fused = _train(params, X, y, rounds)
-    p = {"objective": "binary", "num_leaves": 8, "verbosity": -1,
-         "tree_learner": "data", "num_machines": 2, "min_data_in_leaf": 5}
-    p.update(params)
-    seg = lgb.Booster(params=p, train_set=lgb.Dataset(X, label=y))
-    seg.update()  # the sequential first iteration (as train_chunk runs it)
-    done = 1
-    while done < rounds:
-        d, stopped = dist.segmented_train_chunk(
-            seg._gbdt, min(4, rounds - done)
-        )
-        done += d
-        if stopped:
-            break
-    strip = lambda s: s.split("parameters:")[0]  # noqa: E731
-    assert strip(fused.model_to_string()) == strip(seg.model_to_string())
-    assert np.array_equal(
-        fused._gbdt.scores_canonical_np(), seg._gbdt.scores_canonical_np()
-    )
-    # the collective seconds accumulated for the flight boundary hook
-    assert dist.take_boundary_comms() > 0.0
-    assert dist.take_boundary_comms() == 0.0  # drained
-
-
-# ---------------------------------------------------------------------------
-# shard skew + straggler surfaces
+# shard skew
 # ---------------------------------------------------------------------------
 
 def test_shard_rows_gauge_reports_1003_over_8_split():
@@ -256,26 +175,8 @@ def test_shard_rows_gauge_reports_1003_over_8_split():
     assert dist.shard_valid_counts(1003, 8) == [126] * 7 + [121]
 
 
-def test_dispatch_wait_gauges_in_profiling_mode(monkeypatch):
-    monkeypatch.setenv(dist.ENV_DIST_PROF, "1")
-    X, y = _data(n=400, seed=9)
-    _train({"device_chunk_size": 3}, X, y, 4)
-    vals = registry_mod.REGISTRY.gauge("train_shard_wait_seconds").values()
-    devs = {dict(k).get("device") for k in vals}
-    assert len([d for d in devs if d]) >= 2
-
-
-def test_wait_profiling_disabled_by_default(monkeypatch):
-    monkeypatch.delenv(dist.ENV_DIST_PROF, raising=False)
-    assert not dist.wait_profiling_enabled()
-    monkeypatch.setenv(dist.ENV_DIST_PROF, "0")
-    assert not dist.wait_profiling_enabled()
-    monkeypatch.setenv(dist.ENV_DIST_PROF, "1")
-    assert dist.wait_profiling_enabled()
-
-
 # ---------------------------------------------------------------------------
-# flight manifest + report + bench_diff satellites
+# flight manifest
 # ---------------------------------------------------------------------------
 
 def test_flight_manifest_carries_mesh_and_process(tmp_path):
@@ -291,60 +192,6 @@ def test_flight_manifest_carries_mesh_and_process(tmp_path):
     man = rec["manifest"]
     assert man["process_index"] == 0 and man["process_count"] == 1
     assert man["mesh"] == {"learner": "data", "axes": {"data": 2}}
-
-
-def test_report_multichip_section_renders_new_fields():
-    from lightgbm_tpu.obs import report
-
-    summary = {
-        "metric": "higgs_multichip_iters_per_sec", "unit": "iters/s",
-        "value": 5.0, "platform": "cpu", "ok": True,
-        "scaling": [
-            {"devices": 1, "iters_per_sec": 3.0, "platform": "cpu"},
-            {"devices": 4, "iters_per_sec": 9.0, "platform": "cpu"},
-        ],
-        "speedup_vs_1dev": 3.0,
-        "efficiency_by_devices": [[1, 1.0], [4, 0.75]],
-        "scaling_efficiency": 0.75,
-        "comms_fraction": 0.22,
-        "dist_segments": {"hist_build": 0.01, "hist_combine": 0.002},
-        "per_device": [
-            {"device": "TFRT_CPU_0", "rows": 126, "wait_s": 0.001},
-            {"device": "TFRT_CPU_1", "rows": 121, "wait_s": 0.004},
-        ],
-    }
-    html = report.render(bench_records=[("MULTICHIP_r09.json", summary)])
-    assert "Multichip scaling" in html
-    assert "scaling efficiency" in html
-    assert "collective vs compute" in html
-    assert "per-device shard table" in html
-    assert "TFRT_CPU_1" in html and ">121<" in html
-    # efficiency falls back to recomputation when the field is absent
-    summary2 = dict(summary)
-    summary2.pop("efficiency_by_devices")
-    assert report._multichip_efficiency(summary2) == [(1.0, 1.0), (4.0, 0.75)]
-
-
-def test_bench_diff_scaling_efficiency_warns_never_fails():
-    sys.path.insert(0, os.path.join(REPO, "helpers"))
-    import bench_diff
-
-    base = {"metric": "m", "platform": "cpu", "scaling_efficiency": 0.9}
-    cur = {"metric": "m", "platform": "cpu", "scaling_efficiency": 0.6}
-    rows, failed = bench_diff.compare(cur, base)
-    row = next(r for r in rows if r["metric"] == "scaling_efficiency")
-    assert row["status"] == "WARN"
-    assert not failed, "scaling-efficiency drops must never hard-FAIL"
-    # same drop across platforms: not comparable -> SKIP
-    cur2 = dict(cur, platform="tpu")
-    rows2, _ = bench_diff.compare(cur2, base)
-    row2 = next(r for r in rows2 if r["metric"] == "scaling_efficiency")
-    assert row2["status"] == "SKIP"
-    # small wobble passes
-    cur3 = dict(cur, scaling_efficiency=0.85)
-    rows3, _ = bench_diff.compare(cur3, base)
-    row3 = next(r for r in rows3 if r["metric"] == "scaling_efficiency")
-    assert row3["status"] == "PASS"
 
 
 # ---------------------------------------------------------------------------

@@ -32,9 +32,9 @@ ALL_RULES = ("JX001", "JX002", "JX003", "JX004",
              "JX011", "JX012", "JX013")
 
 #: the default scan scope the check.sh gate and the baseline test share —
-#: lightgbm_tpu/ plus the orchestration surface (helpers/, bench.py) whose
+#: lightgbm_tpu/ plus the orchestration surface (helpers/) whose
 #: bugs burn bringup rounds just as surely (ISSUE 11 satellite)
-SCAN_SCOPE = ("lightgbm_tpu", "helpers", "bench.py")
+SCAN_SCOPE = ("lightgbm_tpu", "helpers")
 
 
 def _fixture(rule_id, kind):
@@ -109,7 +109,7 @@ def test_jx006_hot_path_factory(tmp_path):
 
 def test_jx009_scoped_to_ops_and_models(tmp_path):
     """JX009 polices only ops/ and models/ directories: the same file is
-    clean under helpers/ (bench scripts print their protocol lines) and
+    clean under helpers/ (smoke scripts print their protocol lines) and
     flagged under models/."""
     src = open(_fixture("JX009", "bad")).read()
     for dirname, expected in (("helpers", 0), ("models", 3)):

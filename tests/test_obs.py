@@ -11,6 +11,9 @@
     device bytes;
   * memwatch: shape-math attribution equals the actual donated buffer
     sizes (hist carry + spec_rhist) on CPU;
+  * profiler capture dirs: ``trace merge`` reads ``.gz`` traces and folds
+    rank-suffixed capture dirs (tests/golden/devprof/);
+  * the cost-analysis book and the chip peak table (obs/costs.py);
   * satellites: perf_counter-based phase timers, log.warn_once with ISO
     timestamps, spec_rhist donation reuse.
 """
@@ -27,8 +30,10 @@ import pytest
 
 import lightgbm_tpu as lgb
 import lightgbm_tpu.ops.grow as grow_mod
+from lightgbm_tpu.obs import costs as costs_mod
 from lightgbm_tpu.obs import memwatch, registry as registry_mod, retrace, trace
 from lightgbm_tpu.obs.registry import MetricsRegistry
+from lightgbm_tpu.ops.histogram import leaf_histogram
 from lightgbm_tpu.utils import log
 from lightgbm_tpu.utils.log import LightGBMError
 from lightgbm_tpu.utils.timer import PhaseTimers
@@ -442,6 +447,60 @@ def test_phase_spans_without_timetag(clean_obs, monkeypatch, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# profiler capture dirs: per-rank folding + trace merge
+# ---------------------------------------------------------------------------
+
+GOLD_CAPTURES = os.path.join(os.path.dirname(__file__), "golden", "devprof")
+TPU_CAP = os.path.join(GOLD_CAPTURES, "tpu_capture")
+RANK_CAP = os.path.join(GOLD_CAPTURES, "rank_capture")
+
+
+def test_rank_suffixed_dirs_fold_into_one_parse():
+    """maybe_profile's .rank<N> suffix leaves NO base dir behind —
+    find_trace_files must still find every rank's capture."""
+    files = trace.find_trace_files(RANK_CAP)
+    assert [os.path.basename(f) for f in files] == [
+        "rank0.trace.json.gz", "rank1.trace.json.gz"
+    ]
+    for rank, f in enumerate(files):
+        assert os.sep + "rank_capture.rank%d" % rank + os.sep in f
+        names = {e["name"] for e in trace.load_chrome_trace(f)["traceEvents"]}
+        assert "fusion.%d" % rank in names
+
+
+def test_trace_merge_reads_gz_and_expands_capture_dirs(tmp_path):
+    out = str(tmp_path / "merged.json")
+    rc = trace.main(["merge", "-o", out, TPU_CAP])
+    assert rc == 0
+    with open(out) as fh:
+        doc = json.load(fh)
+    names = {e.get("name") for e in doc["traceEvents"]}
+    assert "fusion.123" in names and "prof.hist_build" in names
+    # disjoint-pid remap still applies to profiler files
+    pids = {e["pid"] for e in doc["traceEvents"] if e.get("ph") == "X"}
+    assert pids
+
+
+def test_maybe_profile_rank_suffixes_env_dir(monkeypatch, tmp_path):
+    """Under an initialized multi-process world, two ranks sharing one
+    LIGHTGBM_TPU_PROFILE dir must diverge to .rank<N> captures (the
+    LIGHTGBM_TPU_TRACE fix, applied to the profiler dir too)."""
+    from lightgbm_tpu.utils import timer
+
+    seen = {}
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(jax, "process_index", lambda: 1)
+    monkeypatch.setattr(
+        jax.profiler, "start_trace", lambda d: seen.setdefault("dir", d))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    target = str(tmp_path / "prof_dir")
+    monkeypatch.setenv(timer.ENV_PROFILE, target)
+    with timer.maybe_profile():
+        pass
+    assert seen["dir"] == target + ".rank1"
+
+
+# ---------------------------------------------------------------------------
 # retrace watchdog
 # ---------------------------------------------------------------------------
 
@@ -794,6 +853,79 @@ def test_memwatch_snapshot_cpu(clean_obs):
     gauges = reg.run_report()["gauges"]
     assert "device_peak_bytes" in gauges
     assert memwatch.snapshots()[-1]["tag"] == "test_point"
+
+
+# ---------------------------------------------------------------------------
+# cost-analysis book + peak table (obs/costs.py)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def clean_cost_book():
+    costs_mod.COSTS.reset()
+    yield
+    costs_mod.COSTS.reset()
+
+
+def test_cost_bytes_match_memwatch_shape_math(clean_cost_book):
+    """The compiled executable's argument/output byte counts must equal the
+    shape math memwatch uses for the same tensors — the cross-check that
+    keeps the two attribution layers honest with each other."""
+    F, N, B = 4, 512, 16
+    bins = jnp.zeros((F, N), jnp.uint8)
+    vals = jnp.zeros((N, 3), jnp.float32)
+    rec = costs_mod.COSTS.harvest(
+        "test.leaf_histogram", leaf_histogram, (bins, vals, B)
+    )
+    assert rec is not None and rec["flops"] > 0
+    assert rec["argument_bytes"] == bins.nbytes + vals.nbytes
+    # [F, B, 3] f32 output == a 1-row histogram carry in memwatch's math
+    assert rec["output_bytes"] == memwatch.hist_carry_bytes(1, F, B)
+    # dedupe: the same signature returns the cached record, no re-compile
+    again = costs_mod.COSTS.harvest(
+        "test.leaf_histogram", leaf_histogram, (bins, vals, B)
+    )
+    assert again == rec
+
+
+def test_cost_harvest_during_training(clean_cost_book, monkeypatch):
+    monkeypatch.setenv(costs_mod.ENV_COSTS, "1")
+    _train_small(rounds=1, seed=11)
+    book = costs_mod.COSTS.report()
+    assert "ops.grow_tree" in book, sorted(book)
+    assert book["ops.grow_tree"].get("flops", 0) > 0
+    report = registry_mod.REGISTRY.run_report()
+    assert "cost_analysis" in report
+    prom = registry_mod.REGISTRY.prometheus_text()
+    assert 'lgbtpu_xla_cost_flops{executable="ops.grow_tree"}' in prom
+    # the satellite wiring: per-name compile counts ride next to the costs
+    assert 'lgbtpu_jit_traces{name="ops.grow_tree"}' in prom
+
+
+def test_costs_disabled_by_default(clean_cost_book, monkeypatch):
+    monkeypatch.delenv(costs_mod.ENV_COSTS, raising=False)
+    assert not costs_mod.enabled()
+    _train_small(rounds=1, seed=13)
+    assert "ops.grow_tree" not in costs_mod.COSTS.report()
+
+
+def test_chip_peak_table():
+    assert costs_mod.normalize_device_kind("TPU v4") == "v4"
+    assert costs_mod.normalize_device_kind("TPU v5e") == "v5e"
+    # "TPU v5 lite" is what a v5e reports (jax 0.9 / libtpu 0.0.34, PR 21)
+    assert costs_mod.normalize_device_kind("TPU v5 lite") == "v5e"
+    assert costs_mod.normalize_device_kind("TPU v5p") == "v5p"
+    assert costs_mod.normalize_device_kind("TPU v6e") == "v6e"
+    assert costs_mod.normalize_device_kind("TPU v6 lite") == "v6e"
+    assert costs_mod.normalize_device_kind("cpu") == "cpu"
+    assert costs_mod.normalize_device_kind("warp9") is None
+    for fam, rec in costs_mod.CHIP_PEAKS.items():
+        assert rec["peak_flops"] > 0 and rec["peak_bw"] > 0, fam
+    v5e = costs_mod.chip_peaks("TPU v5e", platform="tpu")
+    assert v5e["peak_flops"] == 197e12 and "v5e" in v5e["chip"]
+    # no default chip and no cpu row (tests/test_chip_smoke.py pins the rest)
+    for kind, plat in (("warp9", "tpu"), ("cpu", "cpu")):
+        with pytest.raises(LightGBMError):
+            costs_mod.chip_peaks(kind, platform=plat)
 
 
 # ---------------------------------------------------------------------------

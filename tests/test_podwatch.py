@@ -419,52 +419,6 @@ def test_read_heartbeats_skips_torn_files(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench_diff: fleet-telemetry rows are WARN, never FAIL (sick RANKS are a
-# host condition, not a code regression)
-# ---------------------------------------------------------------------------
-
-def _bench_rec(**kw):
-    rec = {"metric": "m", "platform": "cpu"}
-    rec.update(kw)
-    return rec
-
-
-def test_bench_diff_podwatch_verdicts_warn_never_fail():
-    import helpers.bench_diff as bench_diff
-
-    summary = podwatch.pod_summary(os.path.join(GOLDEN, "straggler"), now=NOW)
-    rows, failed = bench_diff.compare(
-        _bench_rec(podwatch=summary), _bench_rec())
-    row = next(r for r in rows if r["metric"] == "podwatch.verdicts")
-    assert row["status"] == bench_diff.WARN
-    assert "straggler rank 1" in row["note"]
-    assert not failed
-
-
-def test_bench_diff_podwatch_spread_growth_warns_stable_passes():
-    import helpers.bench_diff as bench_diff
-
-    rows, failed = bench_diff.compare(
-        _bench_rec(podwatch={"iteration_spread": 40, "verdicts": []}),
-        _bench_rec(podwatch={"iteration_spread": 8, "verdicts": []}),
-    )
-    row = next(r for r in rows
-               if r["metric"] == "podwatch.iteration_spread")
-    assert row["status"] == bench_diff.WARN and not failed
-
-    rows, failed = bench_diff.compare(
-        _bench_rec(podwatch={"iteration_spread": 8, "verdicts": []}),
-        _bench_rec(podwatch={"iteration_spread": 8, "verdicts": []}),
-    )
-    row = next(r for r in rows
-               if r["metric"] == "podwatch.iteration_spread")
-    assert row["status"] == bench_diff.PASS and not failed
-    # no podwatch block at all: no rows, no noise
-    rows, _ = bench_diff.compare(_bench_rec(), _bench_rec())
-    assert not [r for r in rows if r["metric"].startswith("podwatch")]
-
-
-# ---------------------------------------------------------------------------
 # the verdict→action plane flexctl consumes (ISSUE 20): heartbeat ages must
 # be judged by a cross-host-comparable clock, and dead verdicts map to
 # drain_survivors only when the age evidence is real

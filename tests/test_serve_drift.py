@@ -104,8 +104,8 @@ def test_drift_separates_shifted_from_in_distribution(tmp_path):
         counts = app.metrics.registry.counter("serve_drift_alerts").values()
         assert sum(counts.values()) == len(snap["alerts"])
         # alerts mirror into the PROCESS-WIDE registry too: that is the
-        # report bench/bringup artifacts embed, and what the bench_diff
-        # WARN row reads — without the mirror it could never fire
+        # report bringup artifacts embed — without the mirror a reader of
+        # it could never see an alert
         from lightgbm_tpu.obs import REGISTRY as global_reg
 
         gcounts = global_reg.counter("serve_drift_alerts").values()
