@@ -132,13 +132,16 @@ class GBDT:
             self.bins_dev_nf = None
         else:
             self.bins_dev = jnp.asarray(train_set.bins)
-            # CPU: keep a [N, F] transposed copy for the serial grower's
-            # segment gathers (contiguous rows; ~3x faster than [F, N]
-            # column gathers). TPU keeps only [F, N] — the lane-friendly
-            # layout.
+            # the serial grower holds the matrix in both layouts, the
+            # [N, F] one made on the device once a training: its segment
+            # gathers read whole rows ([N, F]; contiguous, ~3x faster on
+            # CPU caches) and its partition reads whole columns ([F, N]).
+            # One matrix for both is one layout in the grower loop's carry,
+            # and on a TPU the side it does not suit copies the whole of it
+            # to the other layout at every step (PERF.md, PR 31)
             self.bins_dev_nf = (
-                jnp.asarray(np.ascontiguousarray(train_set.bins.T))
-                if jax.default_backend() == "cpu"
+                jax.jit(jnp.transpose)(self.bins_dev)
+                if self._learner_kind() == "serial"
                 else None
             )
         meta_np = train_set.feature_meta_arrays()

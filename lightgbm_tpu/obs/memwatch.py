@@ -215,11 +215,13 @@ def attribute_training(gbdt) -> Dict[str, object]:
     K = int(getattr(gbdt, "num_tree_per_iteration", 1))
     N = int(getattr(gbdt, "num_data", 0))
     out["scores"] = {"shape": [K, N], "bytes": scores_bytes(K, N)}
-    bins = getattr(gbdt, "bins_dev", None)
-    if bins is not None:
-        out["bins"] = {
-            "shape": list(bins.shape), "bytes": int(bins.nbytes),
-        }
+    # the serial learner holds the matrix in both layouts (bins_dev_nf)
+    for key, attr in (("bins", "bins_dev"), ("bins_nf", "bins_dev_nf")):
+        bins = getattr(gbdt, attr, None)
+        if bins is not None:
+            out[key] = {
+                "shape": list(bins.shape), "bytes": int(bins.nbytes),
+            }
     out["total_bytes"] = sum(
         v["bytes"] for v in out.values() if isinstance(v, dict)
     )

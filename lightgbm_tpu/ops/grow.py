@@ -522,11 +522,12 @@ def make_bucket_kernels(
         # the W split features' columns, laid flat OUTSIDE the lattice
         # switch: a branch that flattens the whole [F, N] matrix for its
         # gather rebuilds that copy at every call (on a v5e 58 ms a call at
-        # 968 x 750K, 72% of an iteration there; PERF.md, PR 28)
-        cols = (
-            jnp.take(bins_nf, rows_of, axis=1).T if bins_nf is not None
-            else jnp.take(bins, rows_of, axis=0)
-        ).reshape(-1)  # [W * N]
+        # 968 x 750K, 72% of an iteration there; PERF.md, PR 28). Always
+        # from ``bins``, never ``bins_nf``: a matrix that both this take
+        # and the segment gathers read gets one layout in the loop's carry,
+        # and the side it does not suit relays the whole of it out at every
+        # step (PERF.md, PR 31)
+        cols = jnp.take(bins, rows_of, axis=0).reshape(-1)  # [W * N]
 
         padded = _part_padded(pcnt)  # [W]
         ends = jnp.cumsum(padded)
@@ -750,8 +751,11 @@ def grow_tree(
     everywhere — the differential oracle for the pool's miss path.
     ``bins_nf``: optional transposed copy of ``bins`` ([N, F]); when given,
     the bucketed segment gathers read it instead of ``bins`` — row gathers
-    are contiguous there, ~3x faster on CPU caches. TPU callers leave it
-    None ([F, N] is the lane-friendly layout the Pallas kernel wants).
+    are contiguous there, ~3x faster on CPU caches — while the partition
+    reads its split features' columns from ``bins`` always. The serial
+    learner gives it on every backend: on a TPU one matrix read both ways
+    is relaid out whole at every step (PERF.md, PR 31). The sharded grower
+    gives none.
     ``cegb``: static CegbParams; per-feature penalty vectors ride in
     ``feature_meta["cegb_coupled"/"cegb_lazy"]``. ``cegb_state`` is the
     (feature_used [F] bool, used_in_data [F, N] bool) pair carried across trees

@@ -1,10 +1,10 @@
 """The routed Pallas histogram kernel compiled for a v5e that is described,
 not attached: Mosaic accepts the lane-dense body at the largest chunk the
 ``_BYTES_PER_COL`` table allows (what interpret mode cannot show); and the
-speculative grower's step, which must copy neither histogram carry. Nothing
-runs, so nothing here is a device number. The topology is described inside
-a fixture: one worker loads the TPU compiler, and only when it is given
-this file."""
+speculative grower's step, which must copy neither histogram carry nor the
+bin matrix. Nothing runs, so nothing here is a device number. The topology
+is described inside a fixture: one worker loads the TPU compiler, and only
+when it is given this file."""
 import os
 import re
 
@@ -65,19 +65,12 @@ def spec_pallas(monkeypatch):
     jax.clear_caches()
 
 
-@pytest.mark.parametrize("F", [64, 968])
-def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
-    """``grow_tree`` lowered and compiled at 255 bins and 255 leaves, the two
-    ``[255, F, 255, 3]`` carries donated. A speculative batch reads 8 rows of
-    each (``grow._carry_rows``). Read by ``buf[idx]``, the TPU compiler cut a
-    gather whose result outgrew its fast memory into column pieces
-    (``mini-gather-slice``: at 968 columns, none at 64) and materialised every
-    piece, a copy of the whole carry a step; read by a stacked unroll of
-    dynamic slices, it relaid the whole carry out instead (a ``copy`` to
-    ``{1,0,3,2}``). Neither may come back, at a narrow table or a wide one.
-    The loop body does not depend on the rows, so 4096 do. Nothing runs, and
-    nothing here is a device number."""
-    N, B, M = 4096, 255, 255
+B = M = 255  # the cells' bins and leaves
+
+
+def _compile_grower(one_chip, F, N, with_bins_nf=False):
+    """``grow_tree`` lowered and compiled for the described chip at 255 bins
+    and 255 leaves, the two ``[255, F, 255, 3]`` carries donated."""
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -95,10 +88,26 @@ def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
         params=SplitParams(0.0, 0.0, 0.0, 1, 100.0, 0.0), chunk=16384,
         hist_buf=arg((M, F, B, 3), jnp.float32),
         spec_buf=arg((M, F, B, 3), jnp.float32),
+        bins_nf=arg((N, F), jnp.uint8) if with_bins_nf else None,
     ).compile()
     assert grow_mod._LAST_GROW_MODE == "spec"
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the cells' histogram, not the one-hot
+    return compiled, text
+
+
+@pytest.mark.parametrize("F", [64, 968])
+def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
+    """A speculative batch reads 8 rows of each carry
+    (``grow._carry_rows``). Read by ``buf[idx]``, the TPU compiler cut a
+    gather whose result outgrew its fast memory into column pieces
+    (``mini-gather-slice``: at 968 columns, none at 64) and materialised every
+    piece, a copy of the whole carry a step; read by a stacked unroll of
+    dynamic slices, it relaid the whole carry out instead (a ``copy`` to
+    ``{1,0,3,2}``). Neither may come back, at a narrow table or a wide one.
+    The loop body does not depend on the rows, so 4096 do. Nothing runs, and
+    nothing here is a device number."""
+    compiled, text = _compile_grower(one_chip, F, 4096)
     assert text.count("mini-gather-slice") == 0
     carry = re.escape(f"f32[{M},{F},{B},3]")
     assert not re.search(rf"= {carry}\S* copy\(", text)
@@ -106,3 +115,77 @@ def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
         # 704.6 MB with the gather, 189.0 MB without (compiled here, PR 29)
         carry_bytes = M * F * B * 3 * 4
         assert compiled.memory_analysis().temp_size_in_bytes < carry_bytes // 2
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(\S+) = .*? ([a-z][a-z0-9-]*)\((?:%([^\s,)]+))?"
+)
+
+
+def _loop_copies_of_a_handed_in_matrix(text, shape_a, shape_b):
+    """The ``copy`` instructions of an optimised HLO module that (1) lie in a
+    computation some ``while`` runs (its body or condition, and whatever
+    those call: branches, fusions, inner loops), (2) give a ``u8`` array of
+    either shape, and (3) read, through ``get-tuple-element`` and ``bitcast``
+    alone, a parameter of their computation: a value the loop carries or is
+    handed, not one a step computes (a gathered segment of as many rows as
+    the table has the matrix's shape too, and its relayout is the
+    histogram's own work)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line)
+    in_loop = set(re.findall(r"(?:body|condition)=%([^\s,)}]+)", text))
+    todo = list(in_loop)
+    while todo:
+        for ref in re.findall(r"%([^\s,)}]+)", "\n".join(comps[todo.pop()])):
+            if ref in comps and ref not in in_loop:
+                in_loop.add(ref)
+                todo.append(ref)
+    shapes = "|".join(re.escape("u8[%d,%d]" % s) for s in (shape_a, shape_b))
+    whole = re.compile(rf"= (?:{shapes})\S* copy\(")
+    found = []
+    for name in in_loop:
+        defs = {}
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if m:
+                defs[m.group(1)] = (m.group(2), m.group(3), line)
+        for op, src, line in defs.values():
+            if op != "copy" or not whole.search(line):
+                continue
+            while src in defs and defs[src][0] in ("get-tuple-element", "bitcast"):
+                src = defs[src][1]
+            if src in defs and defs[src][0] == "parameter":
+                found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("F", [64, 968])
+@pytest.mark.parametrize("both_layouts", [True, False])
+def test_a_grower_step_copies_no_bin_matrix(one_chip, spec_pallas, F, both_layouts):
+    """The grower's loop carries the bin matrix, and a carried value has one
+    layout. The histogram's segment gathers want it rows major, the
+    partition's take of the 8 split features' columns wants it columns major,
+    and the compiler sinks that take into every branch of the partition's
+    lattice switch: with one matrix for both, every grower step copies the
+    whole of it to the other layout (on a v5e 2.5 ms a step at 968 x 750K,
+    a ninth of an iteration there; PERF.md, PR 31). Handed both layouts as
+    two values, as the serial learner hands them, the loop copies neither;
+    handed one, it shows the copy once a partition branch, so this test is
+    known to see what it guards. 5000 rows, so that most segment buckets
+    have another shape than the table. Nothing runs, and nothing here is a
+    device number."""
+    N = 5000
+    _, text = _compile_grower(one_chip, F, N, with_bins_nf=both_layouts)
+    found = _loop_copies_of_a_handed_in_matrix(text, (F, N), (N, F))
+    if both_layouts:
+        assert not found, found
+    else:
+        branches = grow_mod._branch_steps(-(-N // 256) + grow_mod._ENV_SPEC_K)
+        assert len(found) == len(branches), found
