@@ -13,7 +13,7 @@ import collections
 import json
 import sys
 
-SCOPES = ("hist_build", "partition", "split_find", "apply_split")
+SCOPES = ("hist_build", "partition", "split_find", "apply_split", "oob_leaf")
 
 
 def innermost(text):

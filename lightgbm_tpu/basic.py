@@ -807,6 +807,13 @@ class Booster:
     def num_model_per_iteration(self) -> int:
         return self._gbdt.num_tree_per_iteration
 
+    def sample_draws(self) -> List[Dict]:
+        """The row draws of this booster's training (GOSS, bagging, rf), one
+        dict a drawn iteration: ``iteration``, ``in_bag`` and ``amplified``
+        ([N] bool) and ``multiplier`` (``GBDT.sample_draws``; a bounded record,
+        oldest first). Empty where no rows were drawn."""
+        return self._gbdt.sample_draws()
+
     def num_feature(self) -> int:
         return self._gbdt.max_feature_idx + 1
 

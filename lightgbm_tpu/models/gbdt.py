@@ -9,13 +9,19 @@ leaves (gbdt.cpp:308-413) — while the mechanics are TPU-shaped:
  * scores live on device as ``[num_class, N]`` f32; the tree learner returns the
    per-row leaf assignment so the score update is a gather (no re-traversal),
    matching ScoreUpdater::AddScore-with-learner-partition (score_updater.hpp:80).
- * bagging is a per-row {0,1} mask (exactly floor(bagging_fraction*N) rows chosen)
-   instead of index compaction — keeps shapes static for XLA (gbdt.cpp:179-240).
+ * a row sample (bagging, GOSS, rf: exactly floor(bagging_fraction*N) rows, or
+   GOSS's top_k + other_k) is drawn on the device and handed to the learner as
+   a per-row mask; the serial grower roots the tree at the in-bag rows
+   (ops/grow.py), as gbdt.cpp:179-240 hands its learner the in-bag indices,
+   and scores the rows out of the bag by the finished tree. What stays on
+   the host per iteration: whether and when to draw, and the record of each
+   draw (``sample_draws``).
  * trees stay as device TreeArrays during training and convert to host model Trees
    lazily (for save/predict); validation scores update by on-device traversal.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -53,6 +59,18 @@ def _device_bag_mask(key, n: int, bag_cnt: int) -> jax.Array:
     """Exactly bag_cnt rows in-bag, drawn on device (gbdt.cpp:179-240)."""
     perm = jax.random.permutation(key, n)
     return jnp.zeros((n,), jnp.float32).at[perm[:bag_cnt]].set(1.0)
+
+
+@jax.jit
+def _packed_rows(mask: jax.Array) -> jax.Array:
+    """[1, ceil(N / 8)] uint8: the in-bag rows of a mask, packed to bits."""
+    return jnp.packbits(mask > 0)[None, :]
+
+
+#: the record of row draws (``GBDT.sample_draws``) holds at most this many
+#: bytes of packed bits, the oldest iteration dropped first: 2 x N / 8 bytes a
+#: GOSS iteration, so some 670 iterations at 200K rows and 12 at 10.5M
+DRAW_STORE_BYTES = 32 << 20
 
 
 def _leaf_output_np(sum_grad, sum_hess, l1: float, l2: float, max_delta_step: float):
@@ -204,6 +222,7 @@ class GBDT:
         self._feat_rng = np.random.RandomState(cfg.feature_fraction_seed & 0x7FFFFFFF)
         self._bag_mask = jnp.ones((self.num_data,), jnp.float32)
         self._bagging_active = False
+        self._draws = collections.deque()  # (iteration, multiplier, bits): sample_draws
         self._finish_fns = {}  # jitted renew+shrink+score-update steps per class
         self._pending_stop = None  # last iteration's device num_leaves scalars
         self._pending_chunk = None  # last chunk's stacked [n, K] num_leaves
@@ -467,7 +486,8 @@ class GBDT:
         """Hook for boosting variants (DART's normalization)."""
 
     def _bagging(self, iter_: int, grad, hess) -> Tuple[jax.Array, jax.Array]:
-        """Row-mask bagging (gbdt.cpp:179-240 expressed as a mask).
+        """The iteration's row sample (gbdt.cpp:179-240), left in
+        ``_bag_mask`` for the learner.
 
         The mask is drawn on device (jax.random.permutation) — no per-iteration
         host RNG + transfer of an N-sized array. Returns possibly-modified
@@ -476,11 +496,54 @@ class GBDT:
         if cfg.bagging_freq <= 0 or cfg.bagging_fraction >= 1.0:
             return grad, hess
         self._bagging_active = True
-        if iter_ % cfg.bagging_freq == 0:
+        with trace_mod.span("train.sample", cat="train"):
             bag_cnt = int(cfg.bagging_fraction * self.num_data)
-            key = jax.random.fold_in(self._bag_key, iter_)
-            self._bag_mask = _device_bag_mask(key, self.num_data, bag_cnt)
+            bits = None
+            if iter_ % cfg.bagging_freq == 0:
+                key = jax.random.fold_in(self._bag_key, iter_)
+                self._bag_mask = _device_bag_mask(key, self.num_data, bag_cnt)
+                bits = _packed_rows(self._bag_mask)
+            self._note_sample(iter_, bag_cnt, 0, 1.0, bits)
         return grad, hess
+
+    def _note_sample(self, iter_: int, top_k: int, other_k: int,
+                     multiplier: float, bits=None) -> None:
+        """One ``sample.counters`` event for the iteration's sample (``top_k``
+        rows at weight 1, ``other_k`` at ``multiplier``) and, where rows were
+        drawn, the draw's packed ``bits`` ([1 or 2, ceil(N / 8)] uint8 on the
+        device: the in-bag rows and, for GOSS, those that carry the
+        multiplier) into the record ``sample_draws`` reads. No host sync: the
+        bits stay device arrays until they are asked for."""
+        trace_mod.counters(
+            "sample.counters", cat="train", iteration=iter_,
+            rows=self.num_data, in_bag=top_k + other_k, top_k=top_k,
+            other_k=other_k, multiplier=multiplier)
+        if bits is None:
+            return
+        draws = self._draws
+        while draws and draws[-1][0] >= iter_:  # rolled back and drawn again
+            draws.pop()
+        draws.append((iter_, multiplier, bits))
+        while len(draws) > 1 and sum(d[2].nbytes for d in draws) > DRAW_STORE_BYTES:
+            draws.popleft()
+
+    def sample_draws(self) -> List[Dict]:
+        """The row draws this training made, oldest first, one dict a drawn
+        iteration: ``iteration``, ``in_bag`` ([N] bool: the rows the tree was
+        grown on), ``amplified`` ([N] bool: of those, the rows whose gradient
+        and hessian were multiplied; none under plain bagging) and
+        ``multiplier``. An iteration that grew on every row (no sampling
+        configured, GOSS's first 1/learning_rate iterations) or on the
+        previous draw (``bagging_freq`` > 1) has no entry, nor has one of the
+        fused path (``train_chunk``), which draws inside its scan. Bounded by
+        ``DRAW_STORE_BYTES``: the oldest draws of a long training are gone."""
+        out = []
+        for it, multiplier, bits in getattr(self, "_draws", ()):
+            rows = np.unpackbits(np.asarray(bits), axis=1)[:, : self.num_data] > 0
+            out.append({"iteration": it, "in_bag": rows[0],
+                        "amplified": rows[1] if len(rows) > 1 else np.zeros_like(rows[0]),
+                        "multiplier": float(multiplier)})
+        return out
 
     def _sample_features(self) -> jax.Array:
         cfg = self.config
@@ -722,7 +785,13 @@ class GBDT:
         chunked lax.scan can engage). Every condition names per-iteration
         HOST state the scan body cannot carry; the chunk=1 path stays the
         reference semantics and the two are bit-exact where both apply
-        (tests/test_device_chunk.py)."""
+        (tests/test_device_chunk.py). What the boosters that override the
+        per-iteration hooks keep on the host: GOSS the test of the iteration
+        against its unsampled lead-in, the dispatch of its draw and the
+        draw's record (``_note_sample``: the rows themselves are drawn, kept
+        and grown on the device); rf its constant-score gradients and the
+        init bias folded into every tree; DART its drop set and per-tree
+        weights, which rescale past trees."""
         cfg = self.config
         if cfg.device_chunk_size <= 1:
             return "device_chunk_size <= 1"

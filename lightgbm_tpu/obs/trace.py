@@ -40,6 +40,10 @@ Span and counter sites (name [cat], where):
     phases [train.phase] (utils/timer.py) and ``train.wait_prev_tree``
     [train], the host's blocking read of the previous tree's ``num_leaves``
     (models/gbdt.py), as children
+  * ``train.sample`` [train] and ``sample.counters`` [train], ``ph: "C"``: a
+    booster that draws rows (GOSS, bagging, rf): the span around its
+    ``_bagging``, and what it sampled this iteration (``rows``, ``in_bag``,
+    ``top_k``, ``other_k``, ``multiplier``; models/gbdt.py ``_note_sample``)
   * ``train.boundary`` [train]: from ``update``'s return to the next
     ``train.iteration``, child ``train.callbacks`` (engine._boost_loop)
   * ``grow.counters`` [grow], ``ph: "C"``: the grower's work counters of one

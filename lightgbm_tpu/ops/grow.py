@@ -9,7 +9,10 @@ Differences from the reference are architectural, not semantic:
    each split stably partitions the leaf's contiguous segment inside a
    gathered bucket from a {2^k} + {3*2^k} size lattice (``lax.switch`` over
    sizes), so per-split histogram cost tracks leaf size like the
-   reference's ordered-index kernels.
+   reference's ordered-index kernels. A row sample (bagging, GOSS, rf) is
+   work saved there: the root segment is the in-bag rows, as the
+   reference's learner is handed the in-bag indices (SetBaggingData), and the
+   rows out of the bag get their leaf from the finished tree.
    The ``masked`` mode is the simple oracle — a per-row ``leaf_id`` vector
    updated with ``where`` and full-N masked histogram passes — kept for
    differential testing (tests/test_hist_modes.py) and for lazy-CEGB, which
@@ -187,11 +190,14 @@ class TreeArrays(NamedTuple):
 #: rows whose bin is the split feature's missing bin, which go where the
 #: split's default direction says; ``splits_default_left``: of ``splits``,
 #: those that send missing to the left on a feature that has a missing type.
+#: ``root_rows``: the rows of the root segment, which every later pass is a
+#: part of: the in-bag rows where the grower is rooted at the sample, N where
+#: the sample is a mask (masked mode, the sharded growers).
 #: Under shard_map the largest shard's counts.
 COUNTER_NAMES = (
     "steps", "slots_computed", "splits", "hist_rows_streamed",
     "hist_rows_needed", "part_rows_streamed", "part_rows_needed",
-    "part_rows_missing", "splits_default_left",
+    "part_rows_missing", "splits_default_left", "root_rows",
 )
 
 
@@ -415,11 +421,16 @@ class BucketKernels(NamedTuple):
     #: (order, begin[W], pcnt[W], feat[W], thr[W], dleft[W], member[W, B])
     #: -> (new order, left physical counts [W])
     partition_batch: Callable
-    #: (vals_all [N, 3], order, begin[W], cnt[W]) -> [W, F, B_hist, 3]
+    #: (vals_all [N, 3], order, begin[W], cnt[W], sizes=sizes)
+    #: -> [W, F, B_hist, 3]
     segment_histogram_batch: Callable
     sizes: Tuple[int, ...]  # gathered-segment bucket lattice
+    #: the few of ``sizes`` a sampled tree's root segment is passed at: one
+    #: pass a tree, and a switch branch is a second or so of the build
+    root_sizes: Tuple[int, ...]
     part_sizes: Tuple[int, ...]  # flat-partition branch lattice
-    #: cnt[W] -> rows EACH of the W lanes of segment_histogram_batch passes
+    #: (cnt[W], sizes=sizes) -> rows EACH of the W lanes of
+    #: segment_histogram_batch passes
     hist_extent: Callable
     #: pcnt[W] -> rows the one flat pass of partition_batch runs over
     part_extent: Callable
@@ -482,8 +493,12 @@ def make_bucket_kernels(
     # histogram over-work for lax.switch branch count and therefore
     # first-contact compile time (20-40s+ per branch class on TPU).
     # bucket_sizes is also the autotuner's sweep distribution (obs/tune.py).
-    SIZES = list(bucket_sizes(N))
-    sizes_arr = jnp.asarray(SIZES, jnp.int32)
+    SIZES = bucket_sizes(N)
+    # powers of 4 from 4096 up, and N
+    ROOT_SIZES = tuple(
+        S for S in SIZES if S == N or (S >= 4096 and S.bit_count() == 1
+                                       and S.bit_length() % 2)
+    )
     _half_bucket = min(S for S in SIZES if 2 * S >= N)
 
     # flat-partition branch lattice over 256-row units, up to the worst
@@ -599,9 +614,10 @@ def make_bucket_kernels(
             dbin, nanb, iscat, member,
         )
 
-    def segment_histogram_batch(vals_all, order, begin, cnt):
+    def segment_histogram_batch(vals_all, order, begin, cnt, sizes=SIZES):
         """[W, F, B, 3] histograms of W disjoint segments via ONE lattice-
-        switch launch: one fused gather for all segments, then a vmapped
+        switch launch (over ``sizes``, a part of the lattice that ends in
+        N): one fused gather for all segments, then a vmapped
         chunked pass. W=1 is the sequential per-split histogram, W=KB a
         speculative batch — the launch amortization that attacks the
         per-split fixed cost dominating the r4 on-silicon breakdown.
@@ -661,17 +677,21 @@ def make_bucket_kernels(
             return branch
 
         return jax.lax.switch(
-            _lattice_index(sizes_arr, jnp.max(cnt)),
-            [make_branch(S) for S in SIZES], vals_all, order, begin, cnt
+            _lattice_index(jnp.asarray(sizes, jnp.int32), jnp.max(cnt)),
+            [make_branch(S) for S in sizes], vals_all, order, begin, cnt
         )
+
+    def hist_extent(cnt, sizes=SIZES):
+        sizes = jnp.asarray(sizes, jnp.int32)
+        return sizes[_lattice_index(sizes, jnp.max(cnt))]
 
     return BucketKernels(
         partition_batch=partition_batch,
         segment_histogram_batch=segment_histogram_batch,
-        sizes=tuple(SIZES),
+        sizes=SIZES,
+        root_sizes=ROOT_SIZES,
         part_sizes=tuple(_part_sizes),
-        hist_extent=lambda cnt: sizes_arr[
-            _lattice_index(sizes_arr, jnp.max(cnt))],
+        hist_extent=hist_extent,
         part_extent=lambda pcnt: _part_sizes_arr[
             _lattice_index(_part_sizes_arr, jnp.sum(_part_padded(pcnt)))],
     )
@@ -694,9 +714,9 @@ _NODE_I_COLS = np.array([0, 1, 2, 3, 2, 3], np.int32)
 )
 def grow_tree(
     bins: jax.Array,  # [F, N] uint8/int32
-    grad: jax.Array,  # [N] f32 (already zeroed outside the bag)
+    grad: jax.Array,  # [N] f32 (GOSS: amplified on the drawn rows)
     hess: jax.Array,  # [N] f32
-    bag_mask: jax.Array,  # [N] f32 (1.0 = in bag)
+    bag_mask: jax.Array,  # [N] f32 (> 0 = in bag; all ones without a sample)
     feature_mask: jax.Array,  # [F] bool (feature_fraction sample)
     feature_meta: Dict[str, jax.Array],
     num_leaves: int,
@@ -724,6 +744,19 @@ def grow_tree(
     hist_route=None,
 ):
     """Grow one tree; returns (TreeArrays, leaf_id [N]).
+
+    ``bag_mask`` is the row sample of this tree (bagging, GOSS, rf). The
+    bucketed grower is rooted at it: ``order`` starts as the stable partition
+    of the rows by ``bag_mask > 0`` and the root segment is the in-bag rows
+    alone, a traced length like every other segment's, so the root
+    histogram, every partition and every bucket choice pass over the sample
+    and one compiled program serves sampled and unsampled trees. The rows out
+    of the bag take no part in the growth and get their ``leaf_id`` from the
+    finished tree (``leaves_by_tree``). A mask of ones is the identity order
+    and the whole-table root pass. The masked mode and the sharded growers
+    (``axis_name``, ``feature_sharded``: a sort of the rows or a take of the
+    split columns would gather the sharded matrix) keep the sample as a mask
+    of zeros over all N rows.
 
     ``split_fn(hist, sum_g, sum_h, num_data, min_c, max_c, feature_meta,
     feature_mask, params) -> SplitResult`` overrides the best-split search —
@@ -936,10 +969,11 @@ def grow_tree(
         hist_extent, part_extent = _kern.hist_extent, _kern.part_extent
 
         @jax.named_scope("hist_build")
-        def segment_histogram_batch(order, begin, cnt):
+        def segment_histogram_batch(order, begin, cnt, sizes=_kern.sizes):
             # vals_all (the per-tree [N, 3] accumulands) binds below, before
             # the first call
-            return _kern.segment_histogram_batch(vals_all, order, begin, cnt)
+            return _kern.segment_histogram_batch(
+                vals_all, order, begin, cnt, sizes)
 
         def partition_segment(order, begin, pcnt, f, threshold, default_left, member):
             """One split's partition — the W=1 case of partition_batch."""
@@ -1205,12 +1239,43 @@ def grow_tree(
     # (vals_all); masked_values(ones) would rebuild the identical array
     # (ones * bag_mask == bag_mask) — ~6ms/tree on TPU at 1M
     root_vals = vals_all if bucketed else masked_values(jnp.ones((N,), f32))
-    with jax.named_scope("hist_build"):
-        root_hist = leaf_histogram(
+
+    @jax.named_scope("hist_build")
+    def root_whole():
+        return leaf_histogram(
             bins, root_vals, B_hist, chunk=chunk, axis_name=hist_axis,
             hist_dtype=hist_dtype, feature_sharded=feature_sharded,
             route=hist_route,
         )
+
+    # the bucketed grower on one device, its matrix whole, is rooted at the
+    # sample: the root segment is the in-bag rows, first in ``order`` and in
+    # their own order
+    rooted = bucketed and axis_name is None and not feature_sharded
+    if rooted:
+        in_bag = bag_mask > 0
+        n_root = jnp.sum(in_bag, dtype=jnp.int32)
+        sampled = n_root < N
+
+        def root_sample():
+            order = jnp.argsort(~in_bag, stable=True).astype(jnp.int32)
+            hist = segment_histogram_batch(
+                order, jnp.zeros((1,), jnp.int32), n_root[None],
+                _kern.root_sizes)[0]
+            return order, hist, hist_extent(n_root[None], _kern.root_sizes)
+
+        # every row in the bag: the identity order and one pass over the
+        # table as it lies, with no gather of it
+        order0, root_hist, root_streamed = jax.lax.cond(
+            sampled, root_sample,
+            lambda: (jnp.arange(N, dtype=jnp.int32), root_whole(),
+                     jnp.int32(N)),
+        )
+    else:
+        n_root = root_streamed = N
+        root_hist = root_whole()
+        if bucketed:
+            order0 = jnp.arange(N, dtype=jnp.int32)
     # Root totals from the histogram of feature 0 would miss rows in padded bins;
     # sum the mask directly instead (psum'd under shard_map like GBDT's root sync,
     # serial_tree_learner.cpp:271 BeforeTrain).
@@ -1275,10 +1340,11 @@ def grow_tree(
             [jnp.full((M, 1), -1, jnp.int32), jnp.zeros((M, 1), jnp.int32)],
             axis=1,
         ),
-        # the root's histogram pass reads every row once
+        # the root's histogram pass reads every row of its segment once
         counters=_counted(
             jnp.zeros((len(COUNTER_NAMES),), f32),
-            hist_rows_streamed=N, hist_rows_needed=N,
+            hist_rows_streamed=root_streamed, hist_rows_needed=n_root,
+            root_rows=n_root,
         ),
     )
 
@@ -1352,10 +1418,10 @@ def grow_tree(
         feature_used=feature_used0,
         unused_cnt=unused0,
         used_in_data=used_in_data0,
-        order=jnp.arange(N, dtype=jnp.int32) if bucketed else jnp.zeros((1,), jnp.int32),
+        order=order0 if bucketed else jnp.zeros((1,), jnp.int32),
         leaf_begin=jnp.zeros((M,) if bucketed else (1,), jnp.int32),
         leaf_phys=(
-            jnp.zeros((M,), jnp.int32).at[0].set(N)
+            jnp.zeros((M,), jnp.int32).at[0].set(n_root)
             if bucketed
             else jnp.zeros((1,), jnp.int32)
         ),
@@ -2023,6 +2089,81 @@ def grow_tree(
             spec_rhist=spec_rhist,
         )
 
+    @jax.named_scope("oob_leaf")
+    def leaves_by_tree(t: PackedTree) -> jax.Array:
+        """[N] int32: the leaf every row of the table falls in by the
+        finished tree's own splits (AddPredictionToScore over the
+        out-of-bag indices, gbdt.cpp:484), with no walk down the tree: a
+        row is in the leaf all of whose ancestors' decisions it shares, so
+        one [leaves, nodes] x [nodes, rows] product of +-1 matrices counts a
+        row's agreements with each leaf's path, and the leaf whose count is
+        its depth holds the row. Reads the tree's M - 1 split columns once,
+        in blocks of rows."""
+        nodes = M - 1
+        feat, thr = t.node_i[:nodes, 0], t.node_i[:nodes, 1]
+        live = jnp.arange(nodes, dtype=jnp.int32) < t.num_leaves - 1
+        # node i is item i, leaf l item nodes + l (a child < 0 is leaf -(c+1))
+        kids = t.node_i[:nodes, 2:4]
+        kids = jnp.where(kids >= 0, kids, nodes - (kids + 1))
+        item = jnp.arange(2 * M - 1, dtype=jnp.int32)[:, None]
+        # [items, nodes]: +1 on a node's left child, -1 on its right child
+        side = jnp.where(
+            live[None, :],
+            (item == kids[None, :, 0]).astype(f32)
+            - (item == kids[None, :, 1]).astype(f32),
+            0.0,
+        )
+        # item a reaches item b: b is a or one of a's ancestors; a path has
+        # under M steps, and squaring doubles the steps covered
+        reach = jnp.eye(2 * M - 1, dtype=f32) + jnp.pad(
+            jnp.abs(side), ((0, 0), (0, M)))
+        for _ in range(_ceil_log2(M)):
+            reach = jnp.minimum(reach @ reach, 1.0)
+        # [M, nodes]: +1 where the leaf lies under the node's left child, -1
+        # under its right; a row of zeros for a leaf the tree has not grown
+        path = (reach @ side)[nodes:]
+        depth = jnp.sum(jnp.abs(path), axis=1)
+        grown = jnp.arange(M, dtype=jnp.int32) < t.num_leaves
+
+        block_rows = min(N, 1 << 15)
+        blocks = -(-N // block_rows)
+        cols = jnp.take(
+            bins, (gid_arr[feat] if bundled else feat).astype(jnp.int32), axis=0
+        )
+        cols = jnp.pad(cols, ((0, 0), (0, blocks * block_rows - N)))
+
+        def of_node(per_feature):  # [nodes, 1]: down a column of the block
+            return per_feature[feat][:, None]
+
+        def block(col):  # [nodes, block_rows] bins of each node's own feature
+            colv = col.astype(jnp.int32)
+            if bundled:
+                colv = decode_col(colv, feat[:, None])
+            member = (
+                jnp.take_along_axis(
+                    t.node_b[:nodes, 1:], jnp.clip(colv, 0, B - 1), axis=1)
+                if "is_categorical" in feature_meta
+                else False
+            )
+            go_left = _decision_go_left(
+                colv, thr[:, None], t.node_b[:nodes, :1], of_node(missing_arr),
+                of_node(default_bin_arr), of_node(num_bin_arr) - 1,
+                of_node(is_cat_arr), member,
+            )
+            # +-1 and 0 are exact in bfloat16, their sums in float32
+            agree = jnp.dot(
+                path.astype(jnp.bfloat16),
+                jnp.where(go_left, 1.0, -1.0).astype(jnp.bfloat16),
+                preferred_element_type=f32,
+            )
+            return jnp.argmax(
+                (agree == depth[:, None]) & grown[:, None], axis=0
+            ).astype(jnp.int32)
+
+        return jax.lax.map(
+            block, cols.reshape(nodes, blocks, block_rows).transpose(1, 0, 2)
+        ).reshape(-1)[:N]
+
     if M > 1:
         final = jax.lax.while_loop(cond, body_spec if KB else body, state)
     else:
@@ -2041,6 +2182,13 @@ def grow_tree(
         slot = jnp.searchsorted(key[ordl], jnp.arange(N, dtype=jnp.int32), side="right") - 1
         pos_leaf = ordl[jnp.clip(slot, 0, M - 1)].astype(jnp.int32)
         out_leaf_id = jnp.zeros((N,), jnp.int32).at[final.order].set(pos_leaf)
+        if rooted:
+            # the rows past the root segment are in no leaf's segment
+            out_leaf_id = jax.lax.cond(
+                sampled,
+                lambda: jnp.where(in_bag, out_leaf_id, leaves_by_tree(final.tree)),
+                lambda: out_leaf_id,
+            )
     else:
         out_leaf_id = final.leaf_id
 

@@ -43,16 +43,24 @@ def _grow_both(X, y, bag=None, max_bin=63, leaves=31, mono=None):
     return tm, lm, tb, lb
 
 
-def _assert_trees_equal(tm, tb):
+def _assert_trees_equal(tm, tb, in_bag=None):
     for name in tm._fields:
         a, b = np.asarray(getattr(tm, name)), np.asarray(getattr(tb, name))
         if name == "counters":
             # how the tree was grown, not what it is: the two modes need the
-            # same rows and the masked one passes over all N for each split
+            # same rows and the masked one passes over all N for each split;
+            # under a row sample the masked one needs every row of a leaf, in
+            # the bag or not, and the bucketed one, rooted at the sample, the
+            # in-bag rows alone
             cm, cb = dict(zip(COUNTER_NAMES, a)), dict(zip(COUNTER_NAMES, b))
-            for same in ("steps", "splits", "hist_rows_needed",
-                         "part_rows_needed"):
+            for same in ("steps", "splits") + (
+                    () if in_bag else ("hist_rows_needed", "part_rows_needed",
+                                       "root_rows")):
                 assert cm[same] == cb[same], same
+            if in_bag:
+                assert cb["root_rows"] == in_bag < cm["root_rows"]
+                assert cb["hist_rows_needed"] < cm["hist_rows_needed"]
+                assert cb["part_rows_needed"] < cm["part_rows_needed"]
             assert cm["hist_rows_streamed"] >= cb["hist_rows_streamed"]
             continue
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6, err_msg=name)
@@ -76,7 +84,7 @@ def test_bucketed_matches_masked_with_bagging():
     y = (X[:, 0] > 0).astype(np.float64)
     bag = (rng.rand(2500) > 0.4).astype(np.float32)
     tm, lm, tb, lb = _grow_both(X, y, bag=bag)
-    _assert_trees_equal(tm, tb)
+    _assert_trees_equal(tm, tb, in_bag=int(bag.sum()))
     np.testing.assert_array_equal(np.asarray(lm), np.asarray(lb))
 
 

@@ -123,15 +123,10 @@ _INSTRUCTION = re.compile(
 )
 
 
-def _loop_copies_of_a_handed_in_matrix(text, shape_a, shape_b):
-    """The ``copy`` instructions of an optimised HLO module that (1) lie in a
-    computation some ``while`` runs (its body or condition, and whatever
-    those call: branches, fusions, inner loops), (2) give a ``u8`` array of
-    either shape, and (3) read, through ``get-tuple-element`` and ``bitcast``
-    alone, a parameter of their computation: a value the loop carries or is
-    handed, not one a step computes (a gathered segment of as many rows as
-    the table has the matrix's shape too, and its relayout is the
-    histogram's own work)."""
+def _loop_computations(text):
+    """The computations of an optimised HLO module by name, each a list of
+    its lines, and the names of those some ``while`` runs: its body or
+    condition, and whatever those call (branches, fusions, inner loops)."""
     comps, name = {}, None
     for line in text.splitlines():
         m = _COMPUTATION.match(line)
@@ -147,6 +142,18 @@ def _loop_copies_of_a_handed_in_matrix(text, shape_a, shape_b):
             if ref in comps and ref not in in_loop:
                 in_loop.add(ref)
                 todo.append(ref)
+    return comps, in_loop
+
+
+def _loop_copies_of_a_handed_in_matrix(text, shape_a, shape_b):
+    """The ``copy`` instructions of an optimised HLO module that (1) lie in a
+    computation some ``while`` runs, (2) give a ``u8`` array of
+    either shape, and (3) read, through ``get-tuple-element`` and ``bitcast``
+    alone, a parameter of their computation: a value the loop carries or is
+    handed, not one a step computes (a gathered segment of as many rows as
+    the table has the matrix's shape too, and its relayout is the
+    histogram's own work)."""
+    comps, in_loop = _loop_computations(text)
     shapes = "|".join(re.escape("u8[%d,%d]" % s) for s in (shape_a, shape_b))
     whole = re.compile(rf"= (?:{shapes})\S* copy\(")
     found = []
@@ -189,3 +196,31 @@ def test_a_grower_step_copies_no_bin_matrix(one_chip, spec_pallas, F, both_layou
     else:
         branches = grow_mod._branch_steps(-(-N // 256) + grow_mod._ENV_SPEC_K)
         assert len(found) == len(branches), found
+
+
+def test_one_grower_serves_sampled_and_unsampled_trees(one_chip, spec_pallas):
+    """The row sample is an operand (``bag_mask``), the root segment's length
+    a traced value: the one program compiled for the chip holds the sampled
+    tree's own work (the stable partition of the rows by the mask, the root's
+    pass over its segment at the few sizes of ``root_sizes``, the
+    out-of-bag rows' leaves by the finished tree, scope ``oob_leaf``) under
+    conditionals beside the whole-table root pass, none of it in the grower's
+    loop, and the loop copies no bin matrix (PR 31's guard, kept). The CPU
+    witness that one executable runs both kinds of tree:
+    tests/test_grow_rooted_at_sample.py. Nothing runs, and nothing here is a
+    device number."""
+    F, N = 64, 5000
+    compiled, text = _compile_grower(one_chip, F, N, with_bins_nf=True)
+    assert not _loop_copies_of_a_handed_in_matrix(text, (F, N), (N, F))
+    comps, in_loop = _loop_computations(text)
+    outside = "\n".join(l for name, lines in comps.items() if name not in in_loop
+                        for l in lines)
+    inside = "\n".join(l for name in in_loop for l in comps[name])
+    assert "oob_leaf" in outside and "oob_leaf" not in inside
+    assert re.search(r" sort\(", outside)           # the stable partition by the mask
+    assert " conditional(" in outside
+    kern = grow_mod.make_bucket_kernels(
+        jnp.zeros((F, N), jnp.uint8), {"num_bin": jnp.zeros((F,), jnp.int32),
+                                       "missing_type": jnp.zeros((F,), jnp.int32),
+                                       "default_bin": jnp.zeros((F,), jnp.int32)}, B)
+    assert kern.root_sizes == (4096, N) and set(kern.root_sizes) <= set(kern.sizes)
