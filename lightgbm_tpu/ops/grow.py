@@ -55,6 +55,7 @@ from ..utils.platform import env_choice, env_int
 from .histogram import (
     _default_backend,
     histogram_source,
+    impl_supported,
     leaf_histogram,
     leaf_values,
     route_effective_impls,
@@ -95,14 +96,15 @@ _ENV_SPLIT_IMPL = env_choice("LIGHTGBM_TPU_SPLIT_IMPL", ("pallas",))
 _ENV_GROW = env_choice("LIGHTGBM_TPU_GROW", ("spec", "seq"))
 _ENV_SPEC_K = env_int("LIGHTGBM_TPU_SPEC_K", 8, lo=2, hi=64)
 
-# Spec-mode batched-histogram form: "flat" (one concatenated chunk-aligned
-# pass — arithmetic ∝ total segment rows) vs "lanes" (vmapped common-max
-# lanes — arithmetic ∝ KB x max segment, ~3.4x the sequential row work in
-# the r5 batch study). Default: flat whenever the effective histogram impl
-# is the XLA one-hot (the r5 TPU default), because flat's fixed chunk
-# boundaries then make it BITWISE equal to the per-slot path; under the
-# scatter/pallas impls the groupings differ, so lanes (which reuse the
-# impl verbatim per lane) keep exactness.
+# Spec-mode batched-histogram form: "flat" (one concatenated pass, each slot
+# padded to whole chunks: work follows the segments' TOTAL rows) or "lanes"
+# (vmapped lanes, every one at the largest computing slot's bucket: work
+# follows KB x the largest segment). Default: flat where the effective
+# histogram impl has a flat kernel whose sums of a segment do not depend on
+# the batch: the Pallas radix kernel (hist_pallas.histogram_pallas_slots:
+# a fixed unit of summation at segment-relative offsets) and the XLA one-hot
+# (fixed chunk boundaries); lanes, which run the impl verbatim a lane, for
+# the rest (the CPU's scatter). The variable is the tests' override.
 _ENV_SPEC_HIST = env_choice("LIGHTGBM_TPU_SPEC_HIST", ("flat", "lanes"))
 
 def spec_batch_slots(
@@ -413,6 +415,52 @@ def _lattice_index(sizes_arr: jax.Array, n) -> jax.Array:
     )
 
 
+#: rows of histogram work that cost the flat Pallas pass what one more grid
+#: step costs (one v5e: 0.24 us a step against 0.51 ns a row of 8 columns;
+#: PERF.md section 6, PR 34)
+FLAT_STEP_ROWS = 480
+
+
+def flat_chunk(rows: int, slots: int) -> int:
+    """Rows a grid step of the flat Pallas pass takes in the switch branch
+    that holds ``rows`` over ``slots`` slots: the multiple of 512 nearest
+    ``sqrt(2 * FLAT_STEP_ROWS * rows / slots)``, between 512 and the
+    kernel's VMEM cap; past one unrolled group of the kernel's loop, a
+    whole number of groups, so that no branch's kernel has a tail to
+    lower.
+
+    From what the code can observe (the branch's static row count, the
+    batch's width, the cap), not from a table: a branch of ``rows`` in
+    chunks of C takes ``rows / C`` grid steps, each worth FLAT_STEP_ROWS
+    rows of work whatever it holds, and pads every slot by C / 2 rows on
+    average; the sum is least at the C above. The table's width F
+    multiplies both sides alike and drops out."""
+    from . import hist_pallas
+
+    c = (2 * FLAT_STEP_ROWS * rows / slots) ** 0.5
+    group = hist_pallas._UNROLL * hist_pallas.SUB
+    unit = group if c > group else hist_pallas.SUB
+    cap = hist_pallas._max_chunk_for("pallas") // unit * unit
+    return min(max(int(c / unit + 0.5) * unit, hist_pallas.SUB), cap)
+
+
+def flat_branches(n_rows: int, slots: int) -> Tuple[Tuple[int, int], ...]:
+    """The flat Pallas pass's switch branches for segments of ``n_rows``
+    rows in all over ``slots`` slots: ``(rows, chunk)`` pairs, ``rows`` a
+    whole number of ``chunk``s, on the bucket lattice's family over
+    512-row units (so LIGHTGBM_TPU_LATTICE cuts them like the rest). A
+    branch serves a batch whose slots, each padded to its chunk, fit its
+    rows; the last fits any batch: every row and a chunk a slot."""
+    n512 = -(-n_rows // 512) * 512
+    # past every row a branch only holds more padding: the chunk stays
+    top = n512 + slots * flat_chunk(n512, slots)
+    out = {}
+    for units in _branch_steps(top // 512):
+        c = flat_chunk(min(units * 512, n512), slots)
+        out[-(-units * 512 // c) * c] = c
+    return tuple(sorted(out.items()))
+
+
 class BucketKernels(NamedTuple):
     """The bucketed grower's per-split partition and segment-histogram
     kernels for one dataset layout, with the extents its work counters
@@ -617,10 +665,12 @@ def make_bucket_kernels(
     def segment_histogram_batch(vals_all, order, begin, cnt, sizes=SIZES):
         """[W, F, B, 3] histograms of W disjoint segments via ONE lattice-
         switch launch (over ``sizes``, a part of the lattice that ends in
-        N): one fused gather for all segments, then a vmapped
-        chunked pass. W=1 is the sequential per-split histogram, W=KB a
-        speculative batch — the launch amortization that attacks the
-        per-split fixed cost dominating the r4 on-silicon breakdown.
+        N): one fused gather for all segments, then a vmapped chunked pass
+        in which every lane runs the routed impl verbatim at the largest
+        segment's bucket. W=1 is the sequential per-split histogram and a
+        sampled tree's root pass; W=KB is the speculative batch's ``lanes``
+        form, which serves the impls that have no flat kernel
+        (``segment_histogram_flat`` in ``grow_tree`` serves the rest).
 
         Cost tracks leaf size like the reference's ordered-index histograms
         (dense_bin.hpp:71); one gather from the precomputed [N, 3]
@@ -885,19 +935,23 @@ def grow_tree(
         custom_split=split_fn is not find_best_split,
         route_rows_variant=len(_route_impls) > 1,
     )
+    from .histogram import _ENV_IMPL as _hist_env
+
+    # the effective impl: env override first, else the route's uniform impl
+    # (which is the backend default when no route is active)
+    eff_impl = _hist_env or (
+        next(iter(_route_impls)) if len(_route_impls) == 1 else ""
+    )
+    # the flat form's arithmetic: the Pallas kernel's slot-grouped entry
+    # where leaf_histogram would run that kernel, else the XLA one-hot scan
+    # (leaf_histogram's own fallback at a width the kernel does not serve)
+    flat_pallas = eff_impl == "pallas" and impl_supported(
+        "pallas", B_hist, ignore_backend=True
+    )
     if _ENV_SPEC_HIST:
         use_flat = _ENV_SPEC_HIST == "flat"
     else:
-        from .histogram import _ENV_IMPL as _hist_env
-
-        # flat spec histograms share onehot_chunk_partial (xla arithmetic),
-        # so they are only bitwise-consistent when the effective impl IS
-        # xla: env override first, else the route's uniform impl (which is
-        # the backend default when no route is active)
-        eff_impl = _hist_env or (
-            next(iter(_route_impls)) if len(_route_impls) == 1 else ""
-        )
-        use_flat = eff_impl == "xla"
+        use_flat = eff_impl in ("pallas", "xla")
     global _LAST_GROW_MODE, _LAST_SPEC_HIST  # trace-time test introspection
     _LAST_GROW_MODE = "spec" if KB else "seq"
     _LAST_SPEC_HIST = ("flat" if use_flat else "lanes") if KB else None
@@ -988,54 +1042,67 @@ def grow_tree(
             return segment_histogram_batch(order, begin[None], cnt[None])[0]
 
     if KB:
+        from . import hist_pallas
         from .histogram import _pick_chunk, onehot_chunk_partial
 
-        # flat-chunk batching constants: every slot is padded to a multiple
-        # of the SAME chunk the per-slot path would use (the F/B budget cap,
-        # un-shrunk by segment size), so chunk boundaries — and therefore
-        # f32 accumulation grouping — coincide with the sequential path's,
-        # and zero-valued pad lanes are fp-exact no-ops (x + 0 == x): the
-        # batched histogram is BITWISE equal to per-slot histograms.
+        # the flat form's switch branches, (rows, chunk) each. Every slot is
+        # padded to whole chunks of its branch, so a chunk lies inside one
+        # slot and zero-valued pad rows are fp-exact no-ops (x + 0 == x).
+        # Pallas: the chunk follows the branch's rows (flat_chunk); the
+        # kernel adds a segment's rows in 512-row units at segment-relative
+        # offsets whatever the chunk, so the batched histogram is BITWISE
+        # the per-slot one. XLA one-hot: ONE chunk for all branches, the one
+        # the per-slot path would use (the F/B budget cap, un-shrunk by
+        # segment size), so chunk boundaries coincide with that path's.
         _Frows = bins.shape[0]
-        C_FLAT = _pick_chunk(_Frows, B_hist, chunk, 1 << 60)
-        # branch lattice over the flat buffer's CHUNK COUNT (so every branch
-        # length is an exact C_FLAT multiple) up to the cap (L = N rows +
-        # per-slot alignment), honoring LIGHTGBM_TPU_LATTICE like the rest
-        _flat_sizes = [
-            n * C_FLAT for n in _branch_steps(-(-N // C_FLAT) + KB)
-        ]
-        _flat_sizes_arr = jnp.asarray(_flat_sizes, jnp.int32)
+        if flat_pallas:
+            _flat_branches = flat_branches(N, KB)
+        else:
+            C_FLAT = _pick_chunk(_Frows, B_hist, chunk, 1 << 60)
+            _flat_branches = tuple(
+                (n * C_FLAT, C_FLAT)
+                for n in _branch_steps(-(-N // C_FLAT) + KB)
+            )
+        _flat_rows = jnp.asarray([b[0] for b in _flat_branches], jnp.int32)
+        _flat_chunks = jnp.asarray([b[1] for b in _flat_branches], jnp.int32)
 
-        def _flat_padded(cnt):
-            return ((cnt + C_FLAT - 1) // C_FLAT) * C_FLAT
+        def _flat_plan(cnt):
+            """The branch the flat switch takes for these segments (the
+            first whose rows hold every slot padded to its chunk; the last
+            holds any), and the rows its pass streams: the chunks that hold
+            a slot's rows for the kernel, which skips the branch's round-up,
+            and the whole branch for the one-hot scan, which does not (the
+            gather that feeds either pays the branch's rows)."""
+            c = _flat_chunks[:, None]
+            need = jnp.sum((cnt[None, :] + c - 1) // c * c, axis=1)
+            idx = jnp.argmax(need <= _flat_rows)
+            return idx, (need if flat_pallas else _flat_rows)[idx]
 
         def flat_extent(cnt):
-            """Rows the one concatenated pass of segment_histogram_flat runs
-            over: the branch its lattice switch takes."""
-            return _flat_sizes_arr[
-                _lattice_index(_flat_sizes_arr, jnp.sum(_flat_padded(cnt)))]
+            return _flat_plan(cnt)[1]
 
         @jax.named_scope("hist_build")
         def segment_histogram_flat(order, begin, cnt):
             """[KB, F, B, 3] histograms of KB disjoint segments via ONE flat
-            concatenated pass — unlike the vmapped-lane form, arithmetic is
-            proportional to the segments' TOTAL padded rows, not
-            KB x max(segment): the r5 batch-structure study measured the
-            lane form at ~3.4x the sequential row work and this at ~1.06x.
+            concatenated pass: arithmetic follows the segments' TOTAL padded
+            rows, where the vmapped-lane form pays KB x the largest
+            segment's bucket, idle lanes too.
 
-            Layout: slot j owns flat rows [off_j, off_j + ceil_C(cnt_j));
-            each C_FLAT-chunk lies inside exactly one slot, so a chunked
-            one-hot scan attributes each partial to its slot row with one
-            dynamic-index add."""
-            padded = _flat_padded(cnt)  # [KB]
-            ends = jnp.cumsum(padded)  # [KB]
-            offs = ends - padded
-            L = ends[-1]
+            Layout: slot j owns flat rows [off_j, off_j + ceil_C(cnt_j)),
+            C the branch's chunk; each chunk lies inside exactly one slot,
+            so a chunk's partial goes to its slot's row: through the out
+            block's index map in the Pallas kernel
+            (hist_pallas.histogram_pallas_slots), with one dynamic-index
+            add in the one-hot scan. One gather through ``order`` and one
+            switch serve both; only the arithmetic is the impl's."""
 
-            def make_branch(Lb):
-                nsteps = Lb // C_FLAT
+            def make_branch(Lb, C):
+                nsteps = Lb // C
 
-                def branch(order, begin, cnt, offs, ends):
+                def branch(order, begin, cnt):
+                    padded = (cnt + C - 1) // C * C  # [KB]
+                    ends = jnp.cumsum(padded)
+                    offs = ends - padded
                     t = jnp.arange(Lb, dtype=jnp.int32)
                     j = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
                     j = jnp.minimum(j, KB - 1)
@@ -1049,13 +1116,18 @@ def grow_tree(
                         if bins_nf is not None
                         else jnp.take(bins, rows, axis=1)
                     )  # [Frows, Lb]
+                    if flat_pallas:
+                        return hist_pallas.histogram_pallas_slots(
+                            b_seg, vals, ends, B_hist, chunk=C,
+                            dtype_name=hist_dtype,
+                        )
                     slot_of_chunk = jnp.searchsorted(
-                        ends, jnp.arange(nsteps, dtype=jnp.int32) * C_FLAT,
+                        ends, jnp.arange(nsteps, dtype=jnp.int32) * C,
                         side="right",
                     ).astype(jnp.int32)
                     slot_of_chunk = jnp.minimum(slot_of_chunk, KB - 1)
-                    bins_c = b_seg.reshape(_Frows, nsteps, C_FLAT).transpose(1, 0, 2)
-                    vals_c = vals.reshape(nsteps, C_FLAT, 3)
+                    bins_c = b_seg.reshape(_Frows, nsteps, C).transpose(1, 0, 2)
+                    vals_c = vals.reshape(nsteps, C, 3)
                     op_dtype = (
                         jnp.bfloat16 if hist_dtype == "bfloat16" else jnp.float32
                     )
@@ -1074,9 +1146,9 @@ def grow_tree(
                 return branch
 
             return jax.lax.switch(
-                _lattice_index(_flat_sizes_arr, L),
-                [make_branch(Lb) for Lb in _flat_sizes],
-                order, begin, cnt, offs, ends,
+                _flat_plan(cnt)[0],
+                [make_branch(Lb, C) for Lb, C in _flat_branches],
+                order, begin, cnt,
             )
 
     coupled_arr = feature_meta.get("cegb_coupled")

@@ -49,7 +49,13 @@ read 0.117 ns with the values streamed and 0.066 with the one-hot streamed.
 Grid: (F/FB, N/C). The output block index map pins each feature batch's
 accumulator to the same VMEM block across all row chunks, so partial
 histograms never round-trip through HBM (pallas revisiting semantics).
-Inputs stream: bins [FB, C] u8 and the shared values [K, C] f32 per step.
+Inputs stream: bins [FB, C] u8 and the shared values [K, C] f32 per step;
+a step works through its block ``SUB`` = 512 rows at a time, one dot a
+feature and sub-block, so a segment's float32 additions are the same at
+every block size. The rows may be several segments laid end to end
+(``histogram_pallas_slots``, the speculative grower's batch): a per-chunk
+slot table, a scalar-prefetch operand, picks the accumulator block of each
+chunk, and the one-segment pass is the case of one slot.
 The per-feature-grid v1 kernel (``histogram_pallas_v1``) keeps the older
 orientation and ``Precision.HIGHEST``; it is a differential oracle only.
 
@@ -109,7 +115,10 @@ _BYTES_PER_COL = {
     # the lane-dense body of PR 26, read the same way on the chip (libtpu
     # 0.0.34): f32 46.07M / 200192 = 241, bf16 27.53M / 200192 = 144; the
     # compiler run for a described v5e with no chip says the same (f32
-    # 23.10M / 99840 = 243)
+    # 23.10M / 99840 = 243). Since PR 34 the body works through its block
+    # 512 rows at a time and holds no [*, C] intermediate, so the figure is
+    # an upper bound: kept, the cap (42,496 rows) binds nothing the grower
+    # asks for
     "pallas": 244,
     # bf16 17.68M / 12288 = 1509; f32 compiles at 6144, which this keeps
     "pallas_onehot": 1640,
@@ -221,45 +230,163 @@ def _pieces_for(dtype) -> int:
     return 3 if jnp.dtype(dtype) == jnp.float32 else 1
 
 
-def _kernel_fb(bins_ref, vt_ref, out_ref, *, hi_n: int, pieces: int):
+SUB = 512  # rows of one partial sum: the unit every pass adds its rows in
+_UNROLL = 4  # sub-blocks to an iteration of the body's loop
+
+
+def _kernel_fb(slot_ref, live_ref, bins_ref, vt_ref, out_ref, *, hi_n: int,
+               pieces: int):
     """Feature-batched kernel body: one grid step consumes an [FB, C] bins
-    block + ONE [K, C] values block and unrolls the FB features in VMEM.
+    block + ONE [K, C] values block, ``SUB`` rows at a time, and unrolls the
+    FB features in VMEM.
 
-    The values are split once a step into ``pieces`` bf16 pieces (3 for
-    float32 operands, exact: :func:`split_bf16`; 1 for bfloat16); one
-    single-pass bf16 ``dot_general`` a feature contracts oh_hi [HI, C]
-    with vlo [P*K*LO, C] over their shared last axis (the transposed-RHS
-    form; operands as the module docstring lays them out) into
-    out[hi, (p, k, lo)], float32. The wrapper adds the pieces."""
+    The values are split once into ``pieces`` bf16 pieces (3 for float32
+    operands, exact: :func:`split_bf16`; 1 for bfloat16); one single-pass
+    bf16 ``dot_general`` a feature contracts oh_hi [HI, SUB] with vlo
+    [P*K*LO, SUB] over their shared last axis (the transposed-RHS form;
+    operands as the module docstring lays them out) into out[hi, (p, k,
+    lo)], float32. The wrapper adds the pieces.
+
+    Chunk ``c`` belongs to slot ``slot_ref[c]`` (the out block's index map
+    reads the same table); a slot's chunks are consecutive and the chunk
+    axis is the inner one, so the block is zeroed at a slot's first chunk
+    and stays in VMEM to its last. Chunks from ``live_ref[0]`` on are the
+    lattice's round-up: no work, and their index maps repeat the last live
+    chunk's blocks, so nothing is fetched for them either.
+
+    **A segment's sums do not depend on the chunk.** Each dot runs over
+    ``SUB`` rows at a segment-relative offset (a slot starts at a chunk
+    boundary, a multiple of ``SUB``) and its result is added to the block
+    in row order, so the float32 additions of one segment are the same
+    ones at every block size, alone or among other slots; a sub-block of
+    zero pad rows adds an exact +0."""
     c = pl.program_id(1)
+    first = (c == 0) | (slot_ref[c] != slot_ref[jnp.maximum(c - 1, 0)])
+    live = c < live_ref[0]
 
-    @pl.when(c == 0)
+    @pl.when(live & first)
     def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    pk = jnp.concatenate(split_bf16(vt_ref[:], pieces), axis=0)  # [P*K, C]
-    m_n, C = pk.shape
-    b_all = bins_ref[:, :].astype(jnp.int32)  # [FB, C]
-    hi_all = b_all >> LO_BITS
-    lo_all = b_all & (LO - 1)
-    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (LO, C), 0)
-    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (hi_n, C), 0)
-    for j in range(FB):  # static unroll: register slices, no dynamic u8 rows
-        lo_hit = lo_all[j][None, :] == lo_iota  # [LO, C]
-        # built in float32 ([P*K, LO, C] -> [P*K*LO, C] moves nothing: LO
-        # rows are one sublane tile) and cast once: the pieces are bf16
-        # values already
-        vlo = (
-            jnp.where(lo_hit[None, :, :], pk[:, None, :], 0.0)
-            .reshape(m_n * LO, C)
-            .astype(jnp.bfloat16)
-        )
-        oh_hi = (hi_all[j][None, :] == hi_iota).astype(jnp.bfloat16)
-        out_ref[j] += jax.lax.dot_general(
-            oh_hi, vlo,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (LO, SUB), 0)
+    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (hi_n, SUB), 0)
+
+    def sub_block(i):
+        rows = pl.ds(pl.multiple_of(i * SUB, SUB), SUB)
+        pk = jnp.concatenate(split_bf16(vt_ref[:, rows], pieces), axis=0)
+        m_n = pk.shape[0]  # P*K
+        b_all = bins_ref[:, rows].astype(jnp.int32)  # [FB, SUB]
+        hi_all = b_all >> LO_BITS
+        lo_all = b_all & (LO - 1)
+        for j in range(FB):  # static unroll: register slices, no dynamic u8 rows
+            lo_hit = lo_all[j][None, :] == lo_iota  # [LO, SUB]
+            # built in float32 ([P*K, LO, SUB] -> [P*K*LO, SUB] moves
+            # nothing: LO rows are one sublane tile) and cast once: the
+            # pieces are bf16 values already
+            vlo = (
+                jnp.where(lo_hit[None, :, :], pk[:, None, :], 0.0)
+                .reshape(m_n * LO, SUB)
+                .astype(jnp.bfloat16)
+            )
+            oh_hi = (hi_all[j][None, :] == hi_iota).astype(jnp.bfloat16)
+            out_ref[j] += jax.lax.dot_general(
+                oh_hi, vlo,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+    @pl.when(live)
+    def _chunk():
+        # _UNROLL sub-blocks to a loop iteration, the rest after the loop
+        # (Mosaic unrolls a fori_loop wholly or not at all). On one v5e the
+        # root's 200K x 2000 reads 38.8 ms one by one, 27.5 four at a time,
+        # 25.6 eight at a time (the whole-chunk dots they replace: 26.0);
+        # four, because the grower traces and lowers a kernel a switch
+        # branch and what is unrolled is lowered again each time (PERF.md
+        # section 6, PR 34)
+        n_sub = bins_ref.shape[1] // SUB
+        unroll = min(_UNROLL, n_sub)
+
+        def run(first, n):
+            def one(i, carry):
+                sub_block(first + i)
+                return carry
+
+            if n:
+                jax.lax.fori_loop(0, n, one, 0, unroll=True)
+
+        def group(g, carry):
+            run(g * unroll, unroll)
+            return carry
+
+        if n_sub // unroll > 1:
+            jax.lax.fori_loop(0, n_sub // unroll, group, 0)
+        else:
+            run(0, unroll)
+        run(n_sub - n_sub % unroll, n_sub % unroll)
+
+
+def _fb_call(bins, values, slot_of_chunk, n_live, num_slots, num_bins, C,
+             dtype_name, interpret):
+    """The one ``pallas_call`` of the routed kernel: ``bins`` [F, n*C] and
+    ``values`` [n*C, K] cut into n chunks of C rows, chunk c summed into
+    slot ``slot_of_chunk[c]`` while c < ``n_live``. -> [W, F, B, K]; a
+    slot that owns no live chunk is never written (the caller knows which
+    those are)."""
+    F, L = bins.shape
+    K = values.shape[1]
+    B = num_bins
+    HI = _hi_for(B)
+    P = _pieces_for(dtype_name)
+    W = num_slots
+    n_chunks = L // C
+    Fp = -(-F // FB) * FB
+    if Fp != F:
+        # padded feature rows histogram the padded bins (all zero) against
+        # real values; their rows are sliced off below
+        bins = jnp.pad(bins, ((0, Fp - F), (0, 0)))
+
+    def src(c, live_ref):
+        return jnp.minimum(c, jnp.maximum(live_ref[0] - 1, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_kernel_fb, hi_n=HI, pieces=P),
+        name="hist_pallas_fb",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(Fp // FB, n_chunks),
+            in_specs=[
+                pl.BlockSpec(
+                    (FB, C), lambda f8, c, slot, live: (f8, src(c, live)),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (K, C), lambda f8, c, slot, live: (0, src(c, live)),
+                    memory_space=pltpu.VMEM,
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, FB, HI, P * K * LO),
+                lambda f8, c, slot, live: (slot[src(c, live)], f8, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((W, Fp, HI, P * K * LO), jnp.float32),
+        interpret=interpret,
+    )(
+        slot_of_chunk.astype(jnp.int32),
+        jnp.reshape(n_live, (1,)).astype(jnp.int32),
+        bins, values.T,
+    )
+
+    # out[w, f, hi, (p*K + k)*LO + lo] -> hist[w, f, hi*LO + lo, k]; the
+    # pieces are added largest first, in an order no fusion can change
+    parts = out.reshape(W, Fp, HI, P, K, LO)
+    total = parts[:, :, :, 0]
+    for p in range(1, P):
+        total = total + parts[:, :, :, p]
+    hist = total.transpose(0, 1, 2, 4, 3).reshape(W, Fp, HI * LO, K)
+    return hist[:, :F, :B, :]
 
 
 @functools.partial(
@@ -273,54 +400,66 @@ def _histogram_pallas_fb(
     dtype_name: str = "float32",
     interpret: bool = False,
 ) -> jax.Array:
-    """[F, B, K] f32 histogram via the feature-batched radix MXU kernel."""
-    F, N = bins.shape
-    K = values.shape[1]
-    B = num_bins
-    HI = _hi_for(B)
-    P = _pieces_for(dtype_name)
-
+    """[F, B, K] f32 histogram via the feature-batched radix MXU kernel:
+    the one-slot case of :func:`histogram_pallas_slots`' call."""
+    N = bins.shape[1]
     # equal chunks under the cap: the grower's lattice sizes (2^k, 3*2^k)
     # then pad by nothing, where a fixed C pads 8192 rows to 12288
-    n_chunks = -(-N // min(max(chunk, 512), _max_chunk_for("pallas")))
-    C = -(-N // (n_chunks * 512)) * 512
+    n_chunks = -(-N // min(max(chunk, SUB), _max_chunk_for("pallas")))
+    C = -(-N // (n_chunks * SUB)) * SUB
     if N != n_chunks * C:
         pad = n_chunks * C - N
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         values = jnp.pad(values, ((0, pad), (0, 0)))
-        N += pad
-    Fp = -(-F // FB) * FB
-    if Fp != F:
-        # padded feature rows histogram the padded bins (all zero) against
-        # real values; their rows are sliced off below
-        bins = jnp.pad(bins, ((0, Fp - F), (0, 0)))
+    return _fb_call(
+        bins, values, jnp.zeros((n_chunks,), jnp.int32), jnp.int32(n_chunks),
+        1, num_bins, C, dtype_name, interpret,
+    )[0]
 
-    vt = values.T  # [K, N]
-    kernel = functools.partial(_kernel_fb, hi_n=HI, pieces=P)
-    out = pl.pallas_call(
-        kernel,
-        name="hist_pallas_fb",
-        grid=(Fp // FB, n_chunks),
-        in_specs=[
-            pl.BlockSpec((FB, C), lambda f8, c: (f8, c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((K, C), lambda f8, c: (0, c), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (FB, HI, P * K * LO), lambda f8, c: (f8, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((Fp, HI, P * K * LO), jnp.float32),
-        interpret=interpret,
-    )(bins, vt)
 
-    # out[f, hi, (p*K + k)*LO + lo] -> hist[f, hi*LO + lo, k]; the pieces
-    # are added largest first, in an order no fusion can change
-    parts = out.reshape(Fp, HI, P, K, LO)
-    total = parts[:, :, 0]
-    for p in range(1, P):
-        total = total + parts[:, :, p]
-    hist = total.transpose(0, 1, 3, 2).reshape(Fp, HI * LO, K)
-    return hist[:F, :B, :]
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_bins", "chunk", "dtype_name", "interpret"),
+)
+def histogram_pallas_slots(
+    bins: jax.Array,  # [F, L]: W segments end to end
+    values: jax.Array,  # [L, K]; zeros on every pad row
+    ends: jax.Array,  # [W] int32: each segment's end, a multiple of chunk
+    num_bins: int,
+    chunk: int,
+    dtype_name: str = "float32",
+    interpret: bool = False,
+) -> jax.Array:
+    """[W, F, B, K] f32 histograms of W segments in ONE pass of the
+    feature-batched kernel over their concatenation.
+
+    Segment w holds rows ``[ends[w-1], ends[w])`` of the flat operands,
+    padded with zero values to a whole number of ``chunk`` rows (a
+    multiple of ``SUB`` under the kernel's cap, ``L`` a multiple of it);
+    a segment may be empty. The pass costs the chunks up to ``ends[-1]``:
+    the rest of ``L`` (a caller's static round-up) is neither fetched nor
+    summed. Each segment's histogram is bit for bit what
+    :func:`histogram_pallas` gives for it alone, whatever ``chunk`` and
+    whatever lies beside it (``_kernel_fb``)."""
+    W = ends.shape[0]
+    L = bins.shape[1]
+    if chunk % SUB or chunk > _max_chunk_for("pallas") or L % chunk:
+        raise ValueError(
+            "chunk %d must be a multiple of %d under the kernel's cap that "
+            "divides the %d flat rows" % (chunk, SUB, L)
+        )
+    ends = ends.astype(jnp.int32)
+    starts = jnp.arange(L // chunk, dtype=jnp.int32) * chunk
+    slot_of_chunk = jnp.minimum(
+        jnp.searchsorted(ends, starts, side="right").astype(jnp.int32), W - 1
+    )
+    hist = _fb_call(
+        bins, values, slot_of_chunk, ends[-1] // chunk, W, num_bins, chunk,
+        dtype_name, interpret,
+    )
+    # a slot with no chunk was never written: whatever the buffer held
+    owns_rows = jnp.diff(ends, prepend=0) > 0
+    return jnp.where(owns_rows[:, None, None, None], hist, 0.0)
 
 
 def histogram_pallas(

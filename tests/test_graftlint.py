@@ -454,6 +454,22 @@ def test_jx011_bitplane_fixture():
                  "JX011") == []
 
 
+def test_jx011_scalar_prefetch_fixture():
+    """A ``grid_spec=pltpu.PrefetchScalarGridSpec(...)`` carries the grid and
+    the specs (ops/hist_pallas.py's slot-grouped call since PR 34): the rule
+    reads them there, and each scalar-prefetch operand is one more index_map
+    argument and one more operand of the invocation."""
+    findings = _lint(os.path.join(LINT_DIR, "jx011_prefetch_bad.py"), "JX011")
+    details = sorted(f.detail for f in findings)
+    assert details == sorted([
+        "_kernel:program_id=2",         # axis 2 against the rank-2 grid
+        "in_specs[0]:index_map_arity",  # 2-arg lambda: rank 2 + 2 prefetch
+        "in_specs_count",               # one scalar operand short
+    ]), [f.format() for f in findings]
+    assert _lint(os.path.join(LINT_DIR, "jx011_prefetch_good.py"),
+                 "JX011") == []
+
+
 def test_jx011_real_pallas_seams_clean():
     """The shipped kernels must satisfy their own hygiene rule — the Pallas
     PR grows from these seams under JX011's gate (including the ISSUE 17

@@ -84,8 +84,10 @@ def _pallas_call_eqn(n, chunk, dtype_name, F=8, num_bins=255):
 
 
 def _block_shape(eqn, i):
+    """Block ``i``'s sizes, a squeezed axis (the out block's slot) left out."""
     block = eqn.params["grid_mapping"].block_mappings[i].block_shape
-    return tuple(int(getattr(d, "block_size", d)) for d in block)
+    sizes = [getattr(d, "block_size", d) for d in block]
+    return tuple(int(d) for d in sizes if isinstance(d, int))
 
 
 def _split_cases():
@@ -184,6 +186,90 @@ def test_vmapped_lanes_equal_single_calls_bitwise(rng, dtype_name):
         np.testing.assert_array_equal(lanes[w], single)
 
 
+def _flat_batch(rng, cnts, F, B, chunk, tail_chunks=2):
+    """``cnts`` segments laid end to end, each padded with zero values to
+    whole ``chunk``s, and ``tail_chunks`` more that belong to no segment
+    (a lattice's round-up). Pad and tail bins are 7s, not zeros, to show
+    that nobody's sums read them. The segments are drawn first, so one
+    seed lays the same segments out at any chunk."""
+    segments = [
+        (rng.randint(0, B, (F, c)).astype(np.uint8),
+         (rng.randn(c, 3) * 10.0 ** rng.uniform(-3, 2, (c, 3))).astype(np.float32))
+        for c in cnts
+    ]
+    padded = [-(-c // chunk) * chunk for c in cnts]
+    ends = np.cumsum(padded)
+    L = int(ends[-1]) + tail_chunks * chunk
+    bins = np.full((F, L), 7, np.uint8)
+    vals = np.zeros((L, 3), np.float32)
+    for end, pad, (b, v) in zip(ends, padded, segments):
+        at = end - pad
+        bins[:, at:at + len(v)] = b
+        vals[at:at + len(v)] = v
+    return bins, vals, ends.astype(np.int32), segments
+
+
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_slots_equal_single_calls_bitwise_at_any_chunk(dtype_name):
+    """The contract the grower's ``flat`` form rests on (ops/grow.py
+    ``segment_histogram_flat``): a segment's histogram does not depend on the
+    company it was computed in, nor on the block size. Eight segments, two
+    of them empty, one over several chunks, one of a single row; a slot
+    that owns no chunk is never written by the kernel and reads zeros."""
+    from lightgbm_tpu.ops.hist_pallas import histogram_pallas_slots
+
+    cnts = [1300, 0, 1, 5000, 0, 700, 512, 33]
+    F, B = 11, 255
+    out = {}
+    for chunk in (512, 1536):
+        bins, vals, ends, segments = _flat_batch(
+            np.random.RandomState(5), cnts, F, B, chunk)
+        out[chunk] = np.asarray(histogram_pallas_slots(
+            jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(ends), B,
+            chunk=chunk, dtype_name=dtype_name, interpret=True,
+        ))
+        assert np.isfinite(out[chunk]).all()
+        for w, (b, v) in enumerate(segments):
+            if not len(v):
+                assert (out[chunk][w] == 0).all()
+                continue
+            single = np.asarray(histogram_pallas(
+                jnp.asarray(b), jnp.asarray(v), B, chunk=4096,
+                dtype_name=dtype_name, interpret=True,
+            ))
+            np.testing.assert_array_equal(out[chunk][w], single)
+    # the same RandomState drew the same segments at both chunks
+    np.testing.assert_array_equal(out[512], out[1536])
+
+
+def test_slots_against_float64_oracle(rng):
+    """The flat batch under the file's tolerance for the float32 kernel."""
+    from lightgbm_tpu.ops.hist_pallas import histogram_pallas_slots
+
+    F, B = 11, 255
+    bins, vals, ends, segments = _flat_batch(rng, [3000, 0, 9, 1025], F, B, 1024)
+    out = np.asarray(histogram_pallas_slots(
+        jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(ends), B,
+        chunk=1024, dtype_name="float32", interpret=True,
+    ))
+    for w, (b, v) in enumerate(segments):
+        ref = histogram_reference(b, v, B)
+        mass = histogram_reference(b, np.abs(v), B)
+        err = np.abs(out[w] - ref)
+        assert (err <= 1e-6 * mass).all()
+        assert (err <= np.maximum(1e-5 * np.abs(ref) + 1e-4, 1e-6 * mass)).all()
+
+
+def test_slots_refuse_a_chunk_the_kernel_cannot_take():
+    from lightgbm_tpu.ops.hist_pallas import histogram_pallas_slots
+
+    bins, vals = jnp.zeros((8, 2048), jnp.uint8), jnp.zeros((2048, 3))
+    for chunk in (768, 1536):  # no multiple of 512; does not divide the rows
+        with pytest.raises(ValueError, match="chunk"):
+            histogram_pallas_slots(bins, vals, jnp.asarray([2048]), 255,
+                                   chunk=chunk, interpret=True)
+
+
 @pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
 def test_feature_batched_matches_v1(rng, dtype_name):
     """The default (feature-batched) kernel against the per-feature-grid v1
@@ -215,8 +301,10 @@ def test_accumulator_block_follows_the_operand_dtype(dtype_name, pieces):
     assert _block_shape(eqn, -1) == (FB, 32, pieces * 3 * LO)
     assert eqn.outvars[0].aval.dtype == jnp.float32
     # operands reach the MXU as bfloat16 only through the split: the
-    # kernel's inputs are the u8 bins and the float32 values as passed
-    assert [str(v.aval.dtype) for v in eqn.invars] == ["uint8", "float32"]
+    # kernel's inputs are the chunks' slot table and live count (scalar
+    # prefetch), the u8 bins and the float32 values as passed
+    assert [str(v.aval.dtype) for v in eqn.invars] == [
+        "int32", "int32", "uint8", "float32"]
 
 
 @pytest.mark.parametrize(

@@ -54,6 +54,25 @@ def test_mosaic_accepts_the_largest_chunk(one_chip, dtype_name, num_bins, lanes)
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("F", [64, 968])
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_mosaic_accepts_the_slots_at_the_largest_chunk(one_chip, F, dtype_name):
+    """The slot-grouped entry (scalar prefetch, the out block's slot from
+    the chunk table, the body's 512-row slices at a dynamic offset) at the
+    largest chunk the grower's rule can choose, narrow and wide."""
+    cap = grow_mod.flat_chunk(10 ** 9, 8)
+    group = hist_pallas._UNROLL * hist_pallas.SUB
+    assert cap == hist_pallas._max_chunk_for("pallas") // group * group
+    bins = jax.ShapeDtypeStruct((F, 3 * cap), jnp.uint8, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((3 * cap, 3), jnp.float32, sharding=one_chip)
+    ends = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda b, v, e: hist_pallas.histogram_pallas_slots(
+            b, v, e, 255, chunk=cap, dtype_name=dtype_name)
+    ).lower(bins, vals, ends).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.fixture
 def spec_pallas(monkeypatch):
     """The cells' grower on a process whose default backend is the CPU: both
@@ -198,6 +217,15 @@ def test_a_grower_step_copies_no_bin_matrix(one_chip, spec_pallas, F, both_layou
         assert len(found) == len(branches), found
 
 
+def _bucket_kernels(F, N):
+    """The grower's bucket kernels for an ``[F, N]`` table: the lattices
+    they were built over (``sizes``, ``root_sizes``)."""
+    zeros = jnp.zeros((F,), jnp.int32)
+    return grow_mod.make_bucket_kernels(
+        jnp.zeros((F, N), jnp.uint8),
+        {"num_bin": zeros, "missing_type": zeros, "default_bin": zeros}, B)
+
+
 def test_one_grower_serves_sampled_and_unsampled_trees(one_chip, spec_pallas):
     """The row sample is an operand (``bag_mask``), the root segment's length
     a traced value: the one program compiled for the chip holds the sampled
@@ -219,8 +247,53 @@ def test_one_grower_serves_sampled_and_unsampled_trees(one_chip, spec_pallas):
     assert "oob_leaf" in outside and "oob_leaf" not in inside
     assert re.search(r" sort\(", outside)           # the stable partition by the mask
     assert " conditional(" in outside
-    kern = grow_mod.make_bucket_kernels(
-        jnp.zeros((F, N), jnp.uint8), {"num_bin": jnp.zeros((F,), jnp.int32),
-                                       "missing_type": jnp.zeros((F,), jnp.int32),
-                                       "default_bin": jnp.zeros((F,), jnp.int32)}, B)
+    kern = _bucket_kernels(F, N)
     assert kern.root_sizes == (4096, N) and set(kern.root_sizes) <= set(kern.sizes)
+
+
+def _switches_over_the_kernel(text):
+    """The index conditionals of an optimised HLO module whose branches run
+    the histogram kernel (``hist_pallas_fb`` in a custom call's metadata,
+    in the branch or in what it calls): (in a loop?, number of branches)."""
+    comps, in_loop = _loop_computations(text)
+
+    def runs_kernel(name, seen=()):
+        body = "\n".join(comps[name])
+        if "tpu_custom_call" in body and "hist_pallas_fb" in body:
+            return True
+        return any(
+            runs_kernel(ref, seen + (name,))
+            for ref in set(re.findall(r"%([^\s,)}]+)", body))
+            if ref in comps and ref != name and ref not in seen
+        )
+
+    found = []
+    for name, lines in comps.items():
+        for line in lines:
+            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}", line)
+            if m:
+                branches = re.findall(r"%([^\s,]+)", m.group(1))
+                if any(runs_kernel(b) for b in branches):
+                    found.append((name in in_loop, len(branches)))
+    return found
+
+
+def test_the_histogram_switch_has_no_more_branches_than_the_lanes_form(
+        one_chip, spec_pallas):
+    """A switch branch is seconds of the cold build. Under ``pallas`` the
+    grower's loop holds ONE switch over the kernel, the flat form's, with as
+    many branches as ``flat_branches`` says and no more than the lanes form's
+    switch over the bucket lattice had (the parent's); outside the loop, the
+    sampled root's few sizes under the conditional that keeps the whole
+    table's pass for an unsampled tree. Nothing runs, and nothing here is a
+    device number."""
+    F, N = 64, 5000
+    _, text = _compile_grower(one_chip, F, N, with_bins_nf=True)
+    assert grow_mod._LAST_SPEC_HIST == "flat"
+    kern = _bucket_kernels(F, N)
+    found = _switches_over_the_kernel(text)
+    in_loop = [n for looped, n in found if looped]
+    assert in_loop == [len(grow_mod.flat_branches(N, grow_mod._ENV_SPEC_K))]
+    assert in_loop[0] <= len(kern.sizes)
+    assert sorted(n for looped, n in found if not looped) == sorted(
+        [len(kern.root_sizes), 2])

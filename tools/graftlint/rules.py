@@ -784,7 +784,9 @@ def jx011_pallas_hygiene(ctx: FileContext, project: ProjectContext) -> Iterator[
     mistakes that otherwise surface as Mosaic lowering errors (or silent
     garbage) on real TPU silicon only:
 
-      * an ``index_map`` lambda whose arity differs from the grid rank;
+      * an ``index_map`` lambda whose arity differs from the grid rank
+        (plus the scalar-prefetch operands, where the grid and the specs
+        come in a ``grid_spec=pltpu.PrefetchScalarGridSpec(...)``);
       * an ``index_map`` returning a different number of block coordinates
         than the BlockSpec's block_shape has dimensions;
       * ``in_specs`` count != the number of operands the wrapped call is
@@ -813,6 +815,16 @@ def jx011_pallas_hygiene(ctx: FileContext, project: ProjectContext) -> Iterator[
         ):
             continue
         kwargs = {kw.arg: kw.value for kw in node.keywords if kw.arg}
+        # a grid spec object (pltpu.PrefetchScalarGridSpec) carries the grid
+        # and the specs; each scalar-prefetch operand is one more leading
+        # operand and one more trailing index_map argument
+        prefetch: Optional[int] = 0
+        grid_spec = kwargs.get("grid_spec")
+        if isinstance(grid_spec, ast.Call):
+            spec_kw = {kw.arg: kw.value for kw in grid_spec.keywords if kw.arg}
+            kwargs = {**spec_kw, **kwargs}
+            if "num_scalar_prefetch" in spec_kw:
+                prefetch = const_int(spec_kw["num_scalar_prefetch"], consts)
         # -- grid rank ----------------------------------------------------
         grid_node = kwargs.get("grid")
         grid_rank: Optional[int] = None
@@ -832,14 +844,22 @@ def jx011_pallas_hygiene(ctx: FileContext, project: ProjectContext) -> Iterator[
                 if not _is_blockspec(spec):
                     continue
                 shape, index_map = _blockspec_parts(spec)
-                if index_map is not None and grid_rank is not None:
+                if (
+                    index_map is not None
+                    and grid_rank is not None
+                    and prefetch is not None
+                ):
                     arity = len(index_map.args.args)
-                    if arity != grid_rank:
+                    if arity != grid_rank + prefetch:
                         yield ctx.finding(
                             "JX011", spec,
                             "%s[%d] index_map takes %d argument(s) but the "
-                            "grid has rank %d — every grid axis indexes "
-                            "every block" % (where, i, arity, grid_rank),
+                            "grid has rank %d%s — every grid axis indexes "
+                            "every block" % (
+                                where, i, arity, grid_rank,
+                                " and %d scalar-prefetch operand(s) follow "
+                                "it" % prefetch if prefetch else "",
+                            ),
                             detail="%s[%d]:index_map_arity" % (where, i),
                         )
                 if (
@@ -876,15 +896,17 @@ def jx011_pallas_hygiene(ctx: FileContext, project: ProjectContext) -> Iterator[
         parent = ctx.parent(node)
         if (
             in_specs is not None
+            and prefetch is not None
             and isinstance(parent, ast.Call)
             and parent.func is node
             and not any(isinstance(a, ast.Starred) for a in parent.args)
         ):
-            if len(parent.args) != len(in_specs):
+            if len(parent.args) != len(in_specs) + prefetch:
                 yield ctx.finding(
                     "JX011", node,
                     "pallas_call declares %d in_specs but is invoked with "
-                    "%d operand(s)" % (len(in_specs), len(parent.args)),
+                    "%d operand(s)"
+                    % (len(in_specs), len(parent.args) - prefetch),
                     detail="in_specs_count",
                 )
 
@@ -942,7 +964,7 @@ def jx011_pallas_hygiene(ctx: FileContext, project: ProjectContext) -> Iterator[
         kernel = _resolve_kernel(ctx, node)
         if kernel is None:
             continue
-        if grid_node is None:
+        if grid_node is None and grid_spec is None:
             grid_rank = 0
         a = kernel.args
         params = [p.arg for p in a.posonlyargs + a.args]
