@@ -23,6 +23,22 @@ sys.path.insert(0, REPO)
 
 OUT = os.path.join(REPO, "docs", "Parameters.md")
 
+#: what this port does with a parameter where the name and the default do not
+#: say it: one indented line under the parameter's entry
+NOTES = {
+    "feature_fraction": (
+        "under 1.0 each tree draws `max(1, int(feature_fraction x F))` of the table's `F` "
+        "columns (one host stream, `feature_fraction_seed`) and the serial grower is handed "
+        "those columns alone: histograms, carries and split scan run at the drawn width, so a "
+        "tree costs about the drawn share of the columns, and `Booster.feature_draws()` is the "
+        "record of the draws. The draw stays a mask over all `F` columns (the same trees at "
+        "the full cost) where state lives in the table's column space: EFB-bundled datasets "
+        "(group-space histograms), `tree_learner` `feature`, `voting` and `data` (a sharded "
+        "matrix), forced splits, CEGB, `device_chunk_size > 1` (masks pre-drawn inside the "
+        "fused scan) and the native host learner (`device_type=cpu`)"
+    ),
+}
+
 
 def _sections():
     """Parse config.py's `# --- section ---` groupings in declaration order."""
@@ -107,6 +123,8 @@ def render() -> str:
         if aliases:
             entry += ", aliases: %s" % ", ".join("`%s`" % a for a in aliases)
         lines.append(entry)
+        if name in NOTES:
+            lines.append("  - " + NOTES[name])
     lines.append("")
 
     lines += [

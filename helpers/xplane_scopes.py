@@ -13,7 +13,8 @@ import collections
 import json
 import sys
 
-SCOPES = ("hist_build", "partition", "split_find", "apply_split", "oob_leaf")
+SCOPES = ("hist_build", "partition", "split_find", "apply_split", "oob_leaf",
+          "column_take")
 
 
 def innermost(text):

@@ -814,6 +814,15 @@ class Booster:
         oldest first). Empty where no rows were drawn."""
         return self._gbdt.sample_draws()
 
+    def feature_draws(self) -> List[Dict]:
+        """The column draws of this booster's training (``feature_fraction``
+        < 1), one dict a drawn tree: ``tree``, ``iteration`` and ``columns``
+        (int32[k]: the ``max(1, int(feature_fraction x F))`` drawn columns of
+        the table, in rising order; ``GBDT.feature_draws``, a bounded record,
+        oldest first). The tree was grown over these columns alone and names
+        no other. Empty where no tree draws."""
+        return self._gbdt.feature_draws()
+
     def num_feature(self) -> int:
         return self._gbdt.max_feature_idx + 1
 

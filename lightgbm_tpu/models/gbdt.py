@@ -67,6 +67,27 @@ def _packed_rows(mask: jax.Array) -> jax.Array:
     return jnp.packbits(mask > 0)[None, :]
 
 
+@jax.jit
+def _take_columns(bins: jax.Array, meta: Dict[str, jax.Array], cols: jax.Array):
+    """The drawn columns of the bin matrix in both layouts ([k, N] and
+    [N, k]) and of every per-feature table of ``meta``: what the grower is
+    handed for a tree grown on a feature_fraction draw."""
+    with jax.named_scope("column_take"):
+        drawn = jnp.take(bins, cols, axis=0)
+        return drawn, drawn.T, {
+            name: jnp.take(v, cols, axis=0) for name, v in meta.items()
+        }
+
+
+@jax.jit
+def _table_columns(split_feature: jax.Array, num_leaves: jax.Array,
+                   cols: jax.Array) -> jax.Array:
+    """A tree's ``split_feature`` mapped from positions in its draw back to
+    columns of the table (the nodes past the last split stay as they are)."""
+    made = jnp.arange(split_feature.shape[0]) < num_leaves - 1
+    return jnp.where(made, cols[split_feature], split_feature)
+
+
 #: the record of row draws (``GBDT.sample_draws``) holds at most this many
 #: bytes of packed bits, the oldest iteration dropped first: 2 x N / 8 bytes a
 #: GOSS iteration, so some 670 iterations at 200K rows and 12 at 10.5M
@@ -223,6 +244,8 @@ class GBDT:
         self._bag_mask = jnp.ones((self.num_data,), jnp.float32)
         self._bagging_active = False
         self._draws = collections.deque()  # (iteration, multiplier, bits): sample_draws
+        self._column_draws = collections.deque()  # (tree, int32[k]): feature_draws
+        self._fmask_drawn = None  # all-true [k] mask of a tree handed its drawn columns
         self._finish_fns = {}  # jitted renew+shrink+score-update steps per class
         self._pending_stop = None  # last iteration's device num_leaves scalars
         self._pending_chunk = None  # last chunk's stacked [n, K] num_leaves
@@ -545,16 +568,81 @@ class GBDT:
                         "multiplier": float(multiplier)})
         return out
 
-    def _sample_features(self) -> jax.Array:
+    def _draw_columns(self, tree: int) -> Optional[np.ndarray]:
+        """Tree ``tree``'s feature_fraction draw, from the host stream: the
+        ``k = max(1, int(feature_fraction x F))`` drawn used-feature indices
+        in rising order (int32[k]), noted for ``feature_draws`` and as one
+        ``feature.counters`` event. None at ``feature_fraction >= 1``: no
+        draw is made and the stream does not move."""
         cfg = self.config
         F = self.train_set.num_features
         if cfg.feature_fraction >= 1.0:
-            return self._fmask_all  # cached: no per-iter host->device upload
+            return None
         k = max(1, int(cfg.feature_fraction * F))
-        idx = self._feat_rng.choice(F, size=k, replace=False)
-        mask = np.zeros(F, bool)
-        mask[idx] = True
+        cols = np.sort(self._feat_rng.choice(F, size=k, replace=False)).astype(np.int32)
+        trace_mod.counters(
+            "feature.counters", cat="train", tree=tree,
+            iteration=tree // max(self.num_tree_per_iteration, 1),
+            columns=F, drawn=k)
+        draws = self._column_draws
+        while draws and draws[-1][0] >= tree:  # rolled back and drawn again
+            draws.pop()
+        draws.append((tree, cols))
+        while len(draws) > 1 and sum(d[1].nbytes for d in draws) > DRAW_STORE_BYTES:
+            draws.popleft()
+        return cols
+
+    def feature_draws(self) -> List[Dict]:
+        """The column draws this training made (``feature_fraction`` < 1),
+        oldest first, one dict a drawn tree that is still in the model:
+        ``tree``, ``iteration`` and ``columns`` (int32[k]: the drawn columns
+        of the table, in rising order). Empty where no tree draws. Bounded by
+        ``DRAW_STORE_BYTES``: the oldest draws of a long training are gone."""
+        if not getattr(self, "_column_draws", None):
+            return []
+        K = max(self.num_tree_per_iteration, 1)
+        used = np.asarray(self.train_set.used_feature_idx, np.int32)
+        return [{"tree": t, "iteration": t // K, "columns": used[cols]}
+                for t, cols in self._column_draws if t < len(self.models)]
+
+    def column_draw_fallback_reason(self) -> Optional[str]:
+        """Why a feature_fraction draw reaches the learner as a mask over all
+        F columns (None: the grower is handed the drawn columns alone and the
+        tree costs the drawn share of them). Every condition names state that
+        lives in the table's column space and that a gather of the drawn
+        columns would have to follow."""
+        if self._learner_kind() != "serial":
+            return "the %s-parallel learner's matrix is sharded" % self._learner_kind()
+        if self.train_set.is_bundled:
+            return "EFB: the histograms live in group space"
+        if self._forced_splits:
+            return "forced splits name columns of the table"
+        if self.cegb_params.enabled:
+            return "CEGB carries per-column acquisition state across trees"
+        if self.device_chunk() > 1:
+            return "the fused chunk path pre-draws masks inside its scan"
+        if self._native_decline() is None:
+            return "native host learner in use (device_type=cpu)"
+        return None
+
+    def _native_decline(self) -> Optional[str]:
+        """Why the native host learner (device_type=cpu, ops/grow_native.py)
+        does not grow this training's trees; None where it does."""
+        return grow_native.unsupported_reason(
+            self.config, self.feature_meta, self._forced_splits, self.cegb_params,
+            self.num_bins, self.num_group_bins,
+        )
+
+    def _columns_mask(self, cols: Optional[np.ndarray]) -> jax.Array:
+        """[F] bool: the draw as a mask over every column (all true for no draw)."""
+        if cols is None:
+            return self._fmask_all  # cached: no per-iter host->device upload
+        mask = np.zeros(self.train_set.num_features, bool)
+        mask[cols] = True
         return jnp.asarray(mask)
+
+    def _sample_features(self) -> jax.Array:
+        return self._columns_mask(self._draw_columns(len(self.models)))
 
     def _sample_feature_masks(self, n: int) -> jax.Array:
         """The next ``n`` iterations' feature_fraction masks pre-drawn with
@@ -568,12 +656,11 @@ class GBDT:
         K = self.num_tree_per_iteration
         if cfg.feature_fraction >= 1.0:
             return jnp.broadcast_to(self._fmask_all, (n, K, F))
-        k = max(1, int(cfg.feature_fraction * F))
         masks = np.zeros((n, K, F), bool)
+        base = len(self.models)
         for i in range(n):
             for c in range(K):
-                idx = self._feat_rng.choice(F, size=k, replace=False)
-                masks[i, c, idx] = True
+                masks[i, c, self._draw_columns(base + i * K + c)] = True
         return jnp.asarray(masks)
 
     # ------------------------------------------------------------------
@@ -829,13 +916,7 @@ class GBDT:
                     "row-shard" % self.objective.name
                 )
             return None
-        if (
-            grow_native.unsupported_reason(
-                cfg, self.feature_meta, self._forced_splits, self.cegb_params,
-                self.num_bins, self.num_group_bins,
-            )
-            is None
-        ):
+        if self._native_decline() is None:
             return "native host learner in use (device_type=cpu)"
         return None
 
@@ -1352,8 +1433,23 @@ class GBDT:
 
     def _train_tree(self, grad_k: jax.Array, hess_k: jax.Array):
         cfg = self.config
-        fmask = self._sample_features()
         learner = self._learner_kind()
+        # what the learner is handed: the table and an all-true mask or,
+        # under feature_fraction, the tree's draw: the drawn columns alone
+        # where the grower can work in their space, else a mask over all
+        bins, bins_nf, meta = self.bins_dev, self.bins_dev_nf, self.feature_meta
+        fmask, cols_dev = self._fmask_all, None
+        if cfg.feature_fraction < 1.0:
+            with trace_mod.span("train.feature_sample", cat="train"):
+                cols = self._draw_columns(len(self.models))
+                if self.column_draw_fallback_reason() is None:
+                    cols_dev = jnp.asarray(cols)
+                    bins, bins_nf, meta = _take_columns(bins, meta, cols_dev)
+                    if self._fmask_drawn is None:
+                        self._fmask_drawn = jnp.ones((len(cols),), bool)
+                    fmask = self._fmask_drawn
+                else:
+                    fmask = self._columns_mask(cols)
         common = dict(
             num_leaves=cfg.num_leaves,
             max_depth=cfg.max_depth,
@@ -1367,15 +1463,14 @@ class GBDT:
             hist_route=self._hist_route,
         )
         cegb_on = self.cegb_params.enabled
+        # the columns the learner's histograms are built over
+        F = meta["num_bin"].shape[0]
         # LRU pool cap, honored by every learner (the reference's
         # HistogramPool lives in SerialTreeLearner, which the parallel
         # learners inherit)
-        slots = self._hist_pool_slots()
+        slots = self._hist_pool_slots(F)
         if learner == "serial":
-            native_decline = grow_native.unsupported_reason(
-                cfg, self.feature_meta, self._forced_splits, self.cegb_params,
-                self.num_bins, self.num_group_bins,
-            )
+            native_decline = self._native_decline()
             if native_decline is None:
                 # device_type=cpu: the native host learner (grow_native.py)
                 # — the analogue of the reference's C++ CPU tree learner;
@@ -1395,7 +1490,6 @@ class GBDT:
             # reuses and returns it (aliased), skipping a full-buffer zeros
             # write per tree
             M = cfg.num_leaves
-            F = self.feature_meta["num_bin"].shape[0]
             rows = slots if slots is not None else M
             buf = getattr(self, "_hist_buf", None)
             if buf is None or buf.shape != (rows, F, self.num_bins, 3):
@@ -1423,7 +1517,7 @@ class GBDT:
             grow_kwargs = dict(
                 forced_splits=self._forced_splits, cegb=self.cegb_params,
                 cegb_state=self._cegb_state, hist_buf=buf,
-                bins_nf=self.bins_dev_nf, hist_pool_slots=slots,
+                bins_nf=bins_nf, hist_pool_slots=slots,
                 spec_buf=sbuf, **common,
             )
             # measured cost analysis (obs/costs.py, LIGHTGBM_TPU_COSTS=1):
@@ -1431,14 +1525,13 @@ class GBDT:
             harvest = None
             if costs_mod.enabled():
                 harvest = costs_mod.sds_args(
-                    (self.bins_dev, grad_k, hess_k, self._bag_mask, fmask,
-                     self.feature_meta),
+                    (bins, grad_k, hess_k, self._bag_mask, fmask, meta),
                     grow_kwargs,
                 )
             with sanitize_mod.transfer_scope("ops.grow_tree"):
                 out = grow_tree(
-                    self.bins_dev, grad_k, hess_k, self._bag_mask, fmask,
-                    self.feature_meta, **grow_kwargs,
+                    bins, grad_k, hess_k, self._bag_mask, fmask, meta,
+                    **grow_kwargs,
                 )
             if harvest is not None:
                 costs_mod.COSTS.harvest(
@@ -1447,6 +1540,12 @@ class GBDT:
             if sbuf is not None:
                 out, self._spec_buf = out[:-1], out[-1]
             out, self._hist_buf = out[:-1], out[-1]
+            if cols_dev is not None:
+                # grown in the draw's space: the tree names table columns
+                # before anything reads it by column
+                out = (out[0]._replace(split_feature=_table_columns(
+                    out[0].split_feature, out[0].num_leaves, cols_dev)),
+                ) + tuple(out[1:])
             if cegb_on:
                 tree, leaf_id, self._cegb_state = out
                 return tree, leaf_id
@@ -1521,13 +1620,15 @@ class GBDT:
         )
         return tree, jnp.asarray(leaf_id)
 
-    def _hist_pool_slots(self):
-        """histogram_pool_size (MB) -> LRU slot count, or None for unlimited
+    def _hist_pool_slots(self, F: Optional[int] = None):
+        """histogram_pool_size (MB) -> LRU slot count over ``F`` columns (the
+        table's where none is given), or None for unlimited
         (SerialTreeLearner ctor, serial_tree_learner.cpp:56-69)."""
         cfg = self.config
         if cfg.histogram_pool_size <= 0:
             return None
-        F = self.feature_meta["num_bin"].shape[0]
+        if F is None:
+            F = self.feature_meta["num_bin"].shape[0]
         per_leaf = F * self.num_bins * 3 * 4  # f32 (sum_grad, sum_hess, count)
         slots = int(cfg.histogram_pool_size * 1024 * 1024 / max(per_leaf, 1))
         slots = max(2 + len(self._forced_splits), slots)

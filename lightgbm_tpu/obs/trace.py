@@ -44,6 +44,10 @@ Span and counter sites (name [cat], where):
     booster that draws rows (GOSS, bagging, rf): the span around its
     ``_bagging``, and what it sampled this iteration (``rows``, ``in_bag``,
     ``top_k``, ``other_k``, ``multiplier``; models/gbdt.py ``_note_sample``)
+  * ``train.feature_sample`` [train] and ``feature.counters`` [train], ``ph:
+    "C"``: a training under ``feature_fraction`` < 1: the span around a tree's
+    column draw and its hand-over to the grower, and what was drawn
+    (``columns``, ``drawn``; models/gbdt.py ``_draw_columns``)
   * ``train.boundary`` [train]: from ``update``'s return to the next
     ``train.iteration``, child ``train.callbacks`` (engine._boost_loop)
   * ``grow.counters`` [grow], ``ph: "C"``: the grower's work counters of one

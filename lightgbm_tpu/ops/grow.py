@@ -195,11 +195,15 @@ class TreeArrays(NamedTuple):
 #: ``root_rows``: the rows of the root segment, which every later pass is a
 #: part of: the in-bag rows where the grower is rooted at the sample, N where
 #: the sample is a mask (masked mode, the sharded growers).
+#: ``hist_columns``: the columns this tree's histograms were built over, the
+#: static width of the matrix the grower was handed: a feature_fraction draw's
+#: where it was handed the drawn columns, the table's (its bundles') where the
+#: draw is a mask.
 #: Under shard_map the largest shard's counts.
 COUNTER_NAMES = (
     "steps", "slots_computed", "splits", "hist_rows_streamed",
     "hist_rows_needed", "part_rows_streamed", "part_rows_needed",
-    "part_rows_missing", "splits_default_left", "root_rows",
+    "part_rows_missing", "splits_default_left", "root_rows", "hist_columns",
 )
 
 
@@ -1416,7 +1420,7 @@ def grow_tree(
         counters=_counted(
             jnp.zeros((len(COUNTER_NAMES),), f32),
             hist_rows_streamed=root_streamed, hist_rows_needed=n_root,
-            root_rows=n_root,
+            root_rows=n_root, hist_columns=bins.shape[0],
         ),
     )
 

@@ -241,6 +241,27 @@ class TestBoosterSurface:
         np.testing.assert_allclose(bst2.predict(X), p, rtol=1e-12)
 
 
+    def test_feature_draws_is_the_record_of_the_column_draws(self):
+        """Beside ``sample_draws``: one dict a drawn tree, the drawn columns
+        of the table in rising order; empty where no tree draws."""
+        X, y = _data()
+        F = X.shape[1]
+        bst = lgb.train(dict(PARAMS, feature_fraction=0.5), lgb.Dataset(X, label=y),
+                        num_boost_round=3)
+        draws = bst.feature_draws()
+        assert [sorted(d) for d in draws] == [["columns", "iteration", "tree"]] * 3
+        assert [d["tree"] for d in draws] == [d["iteration"] for d in draws] == [0, 1, 2]
+        for d, t in zip(draws, bst._gbdt.trees()):
+            cols = d["columns"]
+            assert len(cols) == max(1, int(0.5 * F)) and np.all(np.diff(cols) > 0)
+            assert 0 <= cols[0] and cols[-1] < F
+            assert set(t.split_feature[: t.num_leaves - 1]) <= set(cols.tolist())
+        assert bst.sample_draws() == []
+        plain = lgb.train(PARAMS, lgb.Dataset(X, label=y), num_boost_round=2)
+        assert plain.feature_draws() == []
+        assert "column draws" in lgb.Booster.feature_draws.__doc__
+
+
 #: obs/ modules that still import the training path: debts (ROADMAP.md,
 #: "Debts left by PR 30"). This list can only shrink.
 _OBS_IMPORTS_TRAINING = {"tune", "irscan", "memwatch"}

@@ -73,7 +73,7 @@ def rows_sent_by_default(tree, binned):
 
 def test_the_two_counters_ride_with_the_seven():
     assert COUNTER_NAMES[7:9] == ("part_rows_missing", "splits_default_left")
-    assert COUNTER_NAMES[9:] == ("root_rows",)
+    assert COUNTER_NAMES[9:] == ("root_rows", "hist_columns")
 
 
 def test_counters_equal_the_counts_by_numpy_on_a_table_with_missing_values():

@@ -115,7 +115,7 @@ def _compile_grower(one_chip, F, N, with_bins_nf=False):
     return compiled, text
 
 
-@pytest.mark.parametrize("F", [64, 968])
+@pytest.mark.parametrize("F", [64, 968, 1600])
 def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
     """A speculative batch reads 8 rows of each carry
     (``grow._carry_rows``). Read by ``buf[idx]``, the TPU compiler cut a
@@ -123,7 +123,8 @@ def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
     (``mini-gather-slice``: at 968 columns, none at 64) and materialised every
     piece, a copy of the whole carry a step; read by a stacked unroll of
     dynamic slices, it relaid the whole carry out instead (a ``copy`` to
-    ``{1,0,3,2}``). Neither may come back, at a narrow table or a wide one.
+    ``{1,0,3,2}``). Neither may come back, at a narrow table, a wide one or
+    the drawn columns of one (1600 of 2000: ``feature_fraction`` 0.8).
     The loop body does not depend on the rows, so 4096 do. Nothing runs, and
     nothing here is a device number."""
     compiled, text = _compile_grower(one_chip, F, 4096)
