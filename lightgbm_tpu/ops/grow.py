@@ -339,29 +339,34 @@ class GrowState(NamedTuple):
     spec_rhist: jax.Array  # [M, F, B, 3] cached right-child histograms
 
 
-def _decision_go_left(col, threshold, default_left, missing_type, default_bin, nan_bin, is_cat, member_val):
+def _decision_go_left(col, threshold, default_left, missing_type, default_bin, nan_bin, is_cat=None, member_val=None):
     """Bin-space split decision (dense_bin.hpp Split / CategoricalDecisionInner).
 
     ``member_val`` is the split's left-side membership ALREADY LOOKED UP at
     ``col`` (the caller gathers from its [B]-bool bitset — per-segment, per
     vmapped lane, or per flat row); categorical decisions are that pure
-    bitset lookup — no default-direction logic (tree.h:275).
+    bitset lookup — no default-direction logic (tree.h:275). ``is_cat``
+    None is the numerical form, for a table that has no categorical column:
+    no membership is asked for.
     """
     go_left = jnp.where(
         _in_missing_bin(col, missing_type, default_bin, nan_bin, is_cat),
         default_left, col <= threshold,
     )
+    if is_cat is None:
+        return go_left
     return jnp.where(is_cat, member_val, go_left)
 
 
-def _in_missing_bin(col, missing_type, default_bin, nan_bin, is_cat):
+def _in_missing_bin(col, missing_type, default_bin, nan_bin, is_cat=None):
     """Rows that ``_decision_go_left`` sends by the default direction: their
     bin is the feature's missing bin (the zero bin under missing=Zero, the
     NaN bin under missing=NaN)."""
-    return ~is_cat & (
+    missing = (
         ((missing_type == MISSING_ZERO) & (col == default_bin))
         | ((missing_type == MISSING_NAN) & (col == nan_bin))
     )
+    return missing if is_cat is None else ~is_cat & missing
 
 
 def _ceil_log2(n: int) -> int:
@@ -517,11 +522,11 @@ def make_bucket_kernels(
     num_bin_arr = feature_meta["num_bin"].astype(jnp.int32)
     missing_arr = feature_meta["missing_type"].astype(jnp.int32)
     default_bin_arr = feature_meta["default_bin"].astype(jnp.int32)
-    is_cat_arr = feature_meta.get("is_categorical")
-    if is_cat_arr is None:
-        is_cat_arr = jnp.zeros((F,), bool)
-    else:
-        is_cat_arr = is_cat_arr.astype(bool)
+    # the static "no cat -> no cat code" switch, as in ops/split.py: the
+    # dataset writes the key only when a column is categorical
+    has_cat = "is_categorical" in feature_meta
+    if has_cat:
+        is_cat_arr = feature_meta["is_categorical"].astype(bool)
     bundled = "group_id" in feature_meta
     if bundled:
         gid_arr = feature_meta["group_id"].astype(jnp.int32)  # [F]
@@ -579,12 +584,23 @@ def make_bucket_kernels(
         prefix-sum rank — O(L) scatter instead of an O(L log L) stable sort.
         Integer-exact and idempotent: re-partitioning an already-partitioned
         segment yields the same layout, so work done for a speculated-but-
-        unapplied split stays valid when that leaf wins later."""
+        unapplied split stays valid when that leaf wins later.
+
+        A lane pays an element gather only where its index is random: its
+        bin in its slot's split column. Its row is no gather: inside a slot
+        the index into ``order`` rises by one a lane, so slot k's lanes are
+        the window of ``order`` that starts at ``begin[k] - offs[k]``, W
+        slices chosen by the lane's slot. And the categorical split's
+        membership lookup is traced only for a table that has a categorical
+        column (``"is_categorical" in feature_meta``, static); without one
+        the decision is the numerical comparison alone and ``member`` is
+        not read."""
         W = begin.shape[0]
         miss = missing_arr[feat]
         dbin = default_bin_arr[feat]
         nanb = num_bin_arr[feat] - 1
-        iscat = is_cat_arr[feat]
+        # a categorical split's left-side bitset and which slots have one
+        cat = (is_cat_arr[feat], member) if has_cat else ()
         rows_of = (gid_arr[feat] if bundled else feat).astype(jnp.int32)
         # the W split features' columns, laid flat OUTSIDE the lattice
         # switch: a branch that flattens the whole [F, N] matrix for its
@@ -603,7 +619,7 @@ def make_bucket_kernels(
 
         def make_branch(Lb):
             def branch(order, begin, pcnt, offs, ends, cols, feat, thr,
-                       dleft, miss, dbin, nanb, iscat, member):
+                       dleft, miss, dbin, nanb, *cat):
                 t = jnp.arange(Lb, dtype=jnp.int32)
                 j = jnp.minimum(
                     jnp.searchsorted(ends, t, side="right").astype(jnp.int32),
@@ -611,23 +627,35 @@ def make_bucket_kernels(
                 )
                 q = t - offs[j]
                 valid = q < pcnt[j]
-                src = jnp.clip(
-                    begin[j] + jnp.minimum(q, jnp.maximum(pcnt[j] - 1, 0)),
-                    0, N - 1,
-                )
-                rows = order[src]
+                # lane t of slot k reads order[begin[k] + t - offs[k]]: a
+                # slice a slot, not a gather (on a v5e 4.98 ms a call at
+                # 752,128 lanes; PERF.md, PR 36). Padded by Lb on both
+                # sides no start is cut; the lanes past a slot's pcnt read
+                # its neighbours or the padding and are never written
+                order_p = jnp.pad(order, (Lb, Lb))
+                starts = begin - offs + Lb
+                rows = jax.lax.dynamic_slice(order_p, (starts[0],), (Lb,))
+                for k in range(1, W):
+                    rows = jnp.where(
+                        j == k,
+                        jax.lax.dynamic_slice(order_p, (starts[k],), (Lb,)),
+                        rows,
+                    )
                 # per-row feature column through ONE flat gather (each row's
                 # slot picks its own split feature's column)
                 colraw = jnp.take(cols, j * N + rows).astype(jnp.int32)
                 colv = decode_col(colraw, feat[j]) if bundled else colraw
+                cat_j = ()  # the lane's (is categorical, is a member)
+                if has_cat:
+                    iscat, member = cat
+                    cat_j = (iscat[j], member[j, jnp.clip(colv, 0, B - 1)])
                 gl = _decision_go_left(
-                    colv, thr[j], dleft[j], miss[j], dbin[j], nanb[j],
-                    iscat[j], member[j, jnp.clip(colv, 0, B - 1)],
+                    colv, thr[j], dleft[j], miss[j], dbin[j], nanb[j], *cat_j
                 )
                 is_left = valid & gl
                 is_right = valid & ~gl
                 by_default = jnp.sum(valid & _in_missing_bin(
-                    colv, miss[j], dbin[j], nanb[j], iscat[j]
+                    colv, miss[j], dbin[j], nanb[j], *cat_j[:1]
                 ), dtype=jnp.int32)
                 # segmented inclusive count of lefts (resets at slot starts);
                 # int adds are reassociation-exact
@@ -663,7 +691,7 @@ def make_bucket_kernels(
             _lattice_index(_part_sizes_arr, L),
             [make_branch(Lb) for Lb in _part_sizes],
             order, begin, pcnt, offs, ends, cols, feat, thr, dleft, miss,
-            dbin, nanb, iscat, member,
+            dbin, nanb, *cat,
         )
 
     def segment_histogram_batch(vals_all, order, begin, cnt, sizes=SIZES):

@@ -252,6 +252,15 @@ def test_one_grower_serves_sampled_and_unsampled_trees(one_chip, spec_pallas):
     assert kern.root_sizes == (4096, N) and set(kern.root_sizes) <= set(kern.sizes)
 
 
+def _switches(comps):
+    """(computation, its branches' names) of every index conditional."""
+    for name, lines in comps.items():
+        for line in lines:
+            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}", line)
+            if m:
+                yield name, re.findall(r"%([^\s,]+)", m.group(1))
+
+
 def _switches_over_the_kernel(text):
     """The index conditionals of an optimised HLO module whose branches run
     the histogram kernel (``hist_pallas_fb`` in a custom call's metadata,
@@ -268,15 +277,10 @@ def _switches_over_the_kernel(text):
             if ref in comps and ref != name and ref not in seen
         )
 
-    found = []
-    for name, lines in comps.items():
-        for line in lines:
-            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}", line)
-            if m:
-                branches = re.findall(r"%([^\s,]+)", m.group(1))
-                if any(runs_kernel(b) for b in branches):
-                    found.append((name in in_loop, len(branches)))
-    return found
+    return [
+        (name in in_loop, len(branches)) for name, branches in _switches(comps)
+        if any(runs_kernel(b) for b in branches)
+    ]
 
 
 def test_the_histogram_switch_has_no_more_branches_than_the_lanes_form(
@@ -298,3 +302,68 @@ def test_the_histogram_switch_has_no_more_branches_than_the_lanes_form(
     assert in_loop[0] <= len(kern.sizes)
     assert sorted(n for looped, n in found if not looped) == sorted(
         [len(kern.root_sizes), 2])
+
+
+def _lane_gathers_by_branch(text):
+    """Of an optimised HLO module that holds one index conditional: for each
+    of its branches, the result types of the gathers that give a value a
+    lane (more than a hundred elements; the left counts' read of 8 lanes is
+    none), with the element type of what each gathers from."""
+    comps, _ = _loop_computations(text)
+    switches = [branches for _, branches in _switches(comps)]
+    assert len(switches) == 1, switches
+
+    def gathers(name, seen=()):
+        found = []
+        for line in comps[name]:
+            m = re.search(r"= (\w+)\[(\d+)\]\S* gather\(%(\S+?),", line)
+            if m and int(m.group(2)) > 100:
+                src = next(l for l in comps[name] if re.search(rf"%{re.escape(m.group(3))} = ", l))
+                found.append((m.group(1), int(m.group(2)), re.search(r"= (\w+)\[", src).group(1)))
+            for ref in re.findall(r"calls=%([^\s,)}]+)", line):
+                if ref in comps and ref not in seen:
+                    found += gathers(ref, seen + (name,))
+        return found
+
+    return [gathers(b) for b in switches[0]]
+
+
+@pytest.mark.parametrize("categorical", [False, True])
+def test_a_partition_lane_gathers_only_its_bin(one_chip, categorical):
+    """``partition_batch`` alone at the Bosch cell's width and batch: a lane
+    of the flat pass reads its row as a window of ``order`` (eight slices
+    chosen by the lane's slot, fused into one loop) and gathers one value,
+    its bin in its slot's split column (``u8``). On a table that has no
+    categorical column (no ``is_categorical`` in the meta) no branch holds
+    the membership lookup, a ``pred`` gather from the ``[8, 255]`` bitset
+    that cost a v5e 10.3 ns a lane (PERF.md, PR 36); with the key the lookup
+    is there, so this test is known to see what it guards. 5000 rows: the
+    branches differ by their lanes alone. Nothing runs, and nothing here is
+    a device number."""
+    F, N, W = 968, 5000, 8
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = {k: arg((F,), jnp.int32) for k in ("num_bin", "missing_type", "default_bin")}
+    if categorical:
+        meta["is_categorical"] = arg((F,), jnp.bool_)
+
+    def fn(bins, meta, order, begin, pcnt, feat, thr, dleft, member):
+        kern = grow_mod.make_bucket_kernels(bins, meta, B, kb=W)
+        assert kern.part_sizes[-1] == -(-N // 256) * 256 + W * 256
+        return kern.partition_batch(order, begin, pcnt, feat, thr, dleft, member)
+
+    i32 = jnp.int32
+    text = jax.jit(fn).lower(
+        arg((F, N), jnp.uint8), meta, arg((N,), i32), arg((W,), i32), arg((W,), i32), arg((W,), i32),
+        arg((W,), i32), arg((W,), jnp.bool_), arg((W, B), jnp.bool_),
+    ).compile().as_text()
+    by_branch = _lane_gathers_by_branch(text)
+    sizes = grow_mod._branch_steps(-(-N // 256) + W)
+    assert len(by_branch) == len(sizes)
+    for units, found in zip(sizes, by_branch):
+        want = [("u8", units * 256, "u8")]
+        if categorical:
+            want.append(("pred", units * 256, "pred"))
+        assert sorted(found) == sorted(want), (units, found)
