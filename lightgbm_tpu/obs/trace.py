@@ -19,7 +19,7 @@ running process):
  * ``0``: nothing is recorded, ring included.
 
 Every event is a Chrome-trace dict: ``ph`` (``X`` complete span, ``C``
-counter, ``i`` instant), ``name``, ``cat``, ``pid``, ``tid``, ``ts`` and
+counter), ``name``, ``cat``, ``pid``, ``tid``, ``ts`` and
 ``dur`` in microseconds on ``now_us()``'s monotonic clock, plus ``id`` (unique
 in the process) and ``parent`` (the id of the span that was open on the same
 thread when this one began, None at the root: a thread-local stack, not time
@@ -55,6 +55,31 @@ Span and counter sites (name [cat], where):
     on the host (``GBDT._materialize``)
   * file mode only: ``serve.*`` [serve], ``loop.*`` [loop], ``cli.*`` [cli],
     ``resil.*`` [resil]
+
+Device phases (``SCOPES``): the ``jax.named_scope`` every device op of the
+grower's program (ops/grow.py) and of the iteration's small programs
+(models/gbdt.py) lies under, so that a profile's ``tf_op`` names its phase
+(helpers/xplane_scopes.py). A name with a slash is a sub-phase of the part
+before it, entered by its whole name wherever it is (a switch branch sits
+between an outer scope and an inner one); the sub-phases of a phase sum to
+it. Zero cost on the device: a scope is op metadata alone.
+  * ``tree_init``: a tree's accumulands, root totals, the sample's order and
+    the initial carries; ``tree_out``: the finished tree's arrays
+  * ``hist_build``: ``/root`` the root's one-slot pass, ``/feed`` the row
+    gathers through ``order``, ``/kernel`` the Pallas call or the XLA body,
+    ``/relayout`` the kernel's output moved into the scan's layout
+  * ``partition``: ``/split_columns`` the take of the W split columns,
+    ``/lanes`` the window slices, the bin gather, the decision and the
+    segmented scan, ``/scatter`` the write back into ``order``
+  * ``split_find`` (the scan), ``/prep`` the children's histograms read,
+    subtracted and laid out for it
+  * ``apply_split``: the step's choice, the applied prefix and the tree's
+    bookkeeping; ``carry_write``: the children's histograms written into the
+    two carries
+  * ``leaf_ids``: the per-row leaf ids rebuilt from the segment layout;
+    ``oob_leaf``: the leaves of the rows out of a sampled tree's bag
+  * models/gbdt.py: ``gradients``, ``score_update``, ``column_take`` (the
+    drawn columns' gather, a module of its own)
 
 Device correlation: when jax is already imported, a recorded ``span()`` also
 enters ``jax.profiler.TraceAnnotation(name)``, so the program's spans lie in
@@ -109,6 +134,33 @@ _JIT_EVENTS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
     "/jax/core/compile/backend_compile_duration": "jit.compile",
 }
+
+#: the device phases (module docstring), outer names first
+SCOPES = (
+    "tree_init", "tree_out",
+    "hist_build", "hist_build/root", "hist_build/feed", "hist_build/kernel",
+    "hist_build/relayout",
+    "partition", "partition/split_columns", "partition/lanes",
+    "partition/scatter",
+    "split_find", "split_find/prep",
+    "apply_split", "carry_write", "leaf_ids", "oob_leaf",
+    "gradients", "score_update", "column_take",
+)
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost of ``SCOPES`` on an op's name path (an HLO ``op_name``,
+    a profile's ``tf_op``), such as
+    ``jit(grow_tree)/while/body/apply_split/partition/partition/lanes/gather``
+    -> ``partition/lanes``; None where the op lies under none."""
+    path = "/" + op_name + "/"
+    best, at = None, (-1, 0)
+    for s in SCOPES:
+        i = path.rfind("/" + s + "/")
+        if i >= 0 and (i, len(s)) > at:
+            best, at = s, (i, len(s))
+    return best
+
 
 _IDS = itertools.count(1)
 _LOCAL = threading.local()
@@ -482,13 +534,6 @@ def complete_at(name: str, cat: str, t0_us: float, t1_us: float,
     if ring or tr is not None:
         _emit("X", name, cat, t0_us, args, _under_open_span(args), tr, ring,
               dur=round(max(t1_us - t0_us, 0.0), 3))
-
-
-def instant(name: str, cat: str = "", **args) -> None:
-    tr, ring = _sinks(cat)
-    if ring or tr is not None:
-        _emit("i", name, cat, now_us(), args, _under_open_span(args), tr,
-              ring, s="t")
 
 
 def counters(name: str, cat: str = "", **args) -> None:

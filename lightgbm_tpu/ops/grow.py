@@ -199,11 +199,18 @@ class TreeArrays(NamedTuple):
 #: static width of the matrix the grower was handed: a feature_fraction draw's
 #: where it was handed the drawn columns, the table's (its bundles') where the
 #: draw is a mask.
+#: ``hist_rows_gathered``: the rows the histogram's input gather reads: the
+#: switch branch's static rows in the flat form (the lattice's round-up the
+#: kernel skips), what ``hist_rows_streamed`` books elsewhere, N for the
+#: whole-table root pass and in masked mode. ``part_rows_taken``: the rows
+#: the partition's take of its split columns asks for, W x N a call
+#: whatever the rows the call splits (N a split in the sequential grower).
 #: Under shard_map the largest shard's counts.
 COUNTER_NAMES = (
     "steps", "slots_computed", "splits", "hist_rows_streamed",
     "hist_rows_needed", "part_rows_streamed", "part_rows_needed",
     "part_rows_missing", "splits_default_left", "root_rows", "hist_columns",
+    "hist_rows_gathered", "part_rows_taken",
 )
 
 
@@ -596,26 +603,29 @@ def make_bucket_kernels(
         the decision is the numerical comparison alone and ``member`` is
         not read."""
         W = begin.shape[0]
-        miss = missing_arr[feat]
-        dbin = default_bin_arr[feat]
-        nanb = num_bin_arr[feat] - 1
-        # a categorical split's left-side bitset and which slots have one
-        cat = (is_cat_arr[feat], member) if has_cat else ()
-        rows_of = (gid_arr[feat] if bundled else feat).astype(jnp.int32)
-        # the W split features' columns, laid flat OUTSIDE the lattice
-        # switch: a branch that flattens the whole [F, N] matrix for its
-        # gather rebuilds that copy at every call (on a v5e 58 ms a call at
-        # 968 x 750K, 72% of an iteration there; PERF.md, PR 28). Always
-        # from ``bins``, never ``bins_nf``: a matrix that both this take
-        # and the segment gathers read gets one layout in the loop's carry,
-        # and the side it does not suit relays the whole of it out at every
-        # step (PERF.md, PR 31)
-        cols = jnp.take(bins, rows_of, axis=0).reshape(-1)  # [W * N]
+        with jax.named_scope("partition/lanes"):
+            miss = missing_arr[feat]
+            dbin = default_bin_arr[feat]
+            nanb = num_bin_arr[feat] - 1
+            # a categorical split's left-side bitset and which slots have one
+            cat = (is_cat_arr[feat], member) if has_cat else ()
+        with jax.named_scope("partition/split_columns"):
+            rows_of = (gid_arr[feat] if bundled else feat).astype(jnp.int32)
+            # the W split features' columns, laid flat OUTSIDE the lattice
+            # switch: a branch that flattens the whole [F, N] matrix for its
+            # gather rebuilds that copy at every call (on a v5e 58 ms a call
+            # at 968 x 750K, 72% of an iteration there; PERF.md, PR 28).
+            # Always from ``bins``, never ``bins_nf``: a matrix that both
+            # this take and the segment gathers read gets one layout in the
+            # loop's carry, and the side it does not suit relays the whole
+            # of it out at every step (PERF.md, PR 31)
+            cols = jnp.take(bins, rows_of, axis=0).reshape(-1)  # [W * N]
 
-        padded = _part_padded(pcnt)  # [W]
-        ends = jnp.cumsum(padded)
-        offs = ends - padded
-        L = ends[-1]
+        with jax.named_scope("partition/lanes"):
+            padded = _part_padded(pcnt)  # [W]
+            ends = jnp.cumsum(padded)
+            offs = ends - padded
+            branch_idx = _lattice_index(_part_sizes_arr, ends[-1])
 
         def make_branch(Lb):
             def branch(order, begin, pcnt, offs, ends, cols, feat, thr,
@@ -682,19 +692,22 @@ def make_bucket_kernels(
                 )
                 write = is_left | is_right
                 gt = jnp.where(write, begin[j] + tgt_local, N + t)
-                order2 = order.at[gt].set(rows, unique_indices=True)
+                with jax.named_scope("partition/scatter"):
+                    order2 = order.at[gt].set(rows, unique_indices=True)
                 return order2, left_cnt, by_default
 
             return branch
 
-        return jax.lax.switch(
-            _lattice_index(_part_sizes_arr, L),
-            [make_branch(Lb) for Lb in _part_sizes],
-            order, begin, pcnt, offs, ends, cols, feat, thr, dleft, miss,
-            dbin, nanb, *cat,
-        )
+        with jax.named_scope("partition/lanes"):
+            return jax.lax.switch(
+                branch_idx,
+                [make_branch(Lb) for Lb in _part_sizes],
+                order, begin, pcnt, offs, ends, cols, feat, thr, dleft, miss,
+                dbin, nanb, *cat,
+            )
 
-    def segment_histogram_batch(vals_all, order, begin, cnt, sizes=SIZES):
+    def segment_histogram_batch(vals_all, order, begin, cnt, sizes=SIZES,
+                                phases=("hist_build/feed", "hist_build/kernel")):
         """[W, F, B, 3] histograms of W disjoint segments via ONE lattice-
         switch launch (over ``sizes``, a part of the lattice that ends in
         N): one fused gather for all segments, then a vmapped chunked pass
@@ -703,6 +716,8 @@ def make_bucket_kernels(
         sampled tree's root pass; W=KB is the speculative batch's ``lanes``
         form, which serves the impls that have no flat kernel
         (``segment_histogram_flat`` in ``grow_tree`` serves the rest).
+        ``phases``: the scopes (obs/trace.SCOPES) of the gathers and of the
+        pass; the root's pass books both under one.
 
         Cost tracks leaf size like the reference's ordered-index histograms
         (dense_bin.hpp:71); one gather from the precomputed [N, 3]
@@ -724,18 +739,19 @@ def make_bucket_kernels(
                     seg = order[jnp.clip(begin_j + pos, 0, N - 1)]
                     return seg, pos < cnt_j
 
-                seg, valid = jax.vmap(geo)(begin, cnt)  # [W, S]
-                flat = seg.reshape(-1)
-                vals = jnp.take(vals_all, flat, axis=0).reshape(W, S, 3)
-                vals = vals * valid[..., None].astype(f32)
-                if bins_nf is not None:
-                    b_seg = jnp.take(bins_nf, flat, axis=0).reshape(
-                        W, S, Frows
-                    ).transpose(0, 2, 1)
-                else:
-                    b_seg = jnp.take(bins, flat, axis=1).reshape(
-                        Frows, W, S
-                    ).transpose(1, 0, 2)
+                with jax.named_scope(phases[0]):
+                    seg, valid = jax.vmap(geo)(begin, cnt)  # [W, S]
+                    flat = seg.reshape(-1)
+                    vals = jnp.take(vals_all, flat, axis=0).reshape(W, S, 3)
+                    vals = vals * valid[..., None].astype(f32)
+                    if bins_nf is not None:
+                        b_seg = jnp.take(bins_nf, flat, axis=0).reshape(
+                            W, S, Frows
+                        ).transpose(0, 2, 1)
+                    else:
+                        b_seg = jnp.take(bins, flat, axis=1).reshape(
+                            Frows, W, S
+                        ).transpose(1, 0, 2)
                 return jax.vmap(
                     lambda b, v: leaf_histogram(
                         b, v, B_hist, chunk=chunk, hist_dtype=hist_dtype,
@@ -758,10 +774,14 @@ def make_bucket_kernels(
 
             return branch
 
-        return jax.lax.switch(
-            _lattice_index(jnp.asarray(sizes, jnp.int32), jnp.max(cnt)),
-            [make_branch(S) for S in sizes], vals_all, order, begin, cnt
-        )
+        with jax.named_scope(phases[0]):
+            branch_idx = _lattice_index(
+                jnp.asarray(sizes, jnp.int32), jnp.max(cnt))
+        with jax.named_scope(phases[1]):
+            return jax.lax.switch(
+                branch_idx, [make_branch(S) for S in sizes], vals_all, order,
+                begin, cnt
+            )
 
     def hist_extent(cnt, sizes=SIZES):
         sizes = jnp.asarray(sizes, jnp.int32)
@@ -900,8 +920,9 @@ def grow_tree(
     # histograms run over groups at group width, then remap to feature space.
     bundled = "group_id" in feature_meta
     if bundled:
-        gid_arr = feature_meta["group_id"].astype(jnp.int32)  # [F]
-        off_arr = feature_meta["bin_offset"].astype(jnp.int32)  # [F]
+        with jax.named_scope("tree_init"):
+            gid_arr = feature_meta["group_id"].astype(jnp.int32)  # [F]
+            off_arr = feature_meta["bin_offset"].astype(jnp.int32)  # [F]
         B_hist = num_group_bins if num_group_bins is not None else B
     else:
         B_hist = B
@@ -988,23 +1009,33 @@ def grow_tree(
     _LAST_GROW_MODE = "spec" if KB else "seq"
     _LAST_SPEC_HIST = ("flat" if use_flat else "lanes") if KB else None
 
-    num_bin_arr = feature_meta["num_bin"].astype(jnp.int32)
-    missing_arr = feature_meta["missing_type"].astype(jnp.int32)
-    default_bin_arr = feature_meta["default_bin"].astype(jnp.int32)
-    mono_arr = feature_meta["monotone"].astype(jnp.int32)
+    # the per-feature tables in the forms the tree's work reads them
+    with jax.named_scope("tree_init"):
+        num_bin_arr = feature_meta["num_bin"].astype(jnp.int32)
+        missing_arr = feature_meta["missing_type"].astype(jnp.int32)
+        default_bin_arr = feature_meta["default_bin"].astype(jnp.int32)
+        mono_arr = feature_meta["monotone"].astype(jnp.int32)
+        if bundled:
+            # feature-space gather plan for the [G, B_hist] -> [F, B] remap:
+            # sub-bin s != default maps to group bin off + (s - (s >
+            # default)); the default row is recovered from leaf totals
+            # (efb.py encoding)
+            s_iota = jnp.arange(B, dtype=jnp.int32)[None, :]  # [1, B]
+            s0_col = default_bin_arr[:, None]
+            efb_valid = (s_iota < num_bin_arr[:, None]) & (s_iota != s0_col)  # [F, B]
+            efb_gidx = jnp.where(
+                efb_valid, off_arr[:, None] + s_iota - (s_iota > s0_col), 0
+            )
+            f_iota = jnp.arange(F, dtype=jnp.int32)
+        is_cat_arr = feature_meta.get("is_categorical")
+        if is_cat_arr is None:
+            is_cat_arr = jnp.zeros((F,), bool)
+        else:
+            is_cat_arr = is_cat_arr.astype(bool)
 
     if bundled:
-        # feature-space gather plan for the [G, B_hist] -> [F, B] remap:
-        # sub-bin s != default maps to group bin off + (s - (s > default));
-        # the default row is recovered from leaf totals (efb.py encoding)
-        s_iota = jnp.arange(B, dtype=jnp.int32)[None, :]  # [1, B]
-        s0_col = default_bin_arr[:, None]
-        efb_valid = (s_iota < num_bin_arr[:, None]) & (s_iota != s0_col)  # [F, B]
-        efb_gidx = jnp.where(
-            efb_valid, off_arr[:, None] + s_iota - (s_iota > s0_col), 0
-        )
-        f_iota = jnp.arange(F, dtype=jnp.int32)
 
+        @jax.named_scope("hist_build/relayout")
         def remap_hist(group_hist, sum_g, sum_h, sum_n):
             """[G, B_hist, 3] group histogram -> [F, B, 3] feature histogram.
 
@@ -1023,6 +1054,7 @@ def grow_tree(
             rest = totals[None, :] - jnp.sum(fh, axis=1)  # [F, 3]
             return fh.at[f_iota, default_bin_arr].set(rest)
 
+        @jax.named_scope("hist_build/relayout")
         def remap_hist_local(group_hist):
             """Shard-local remap: totals recovered from the group histogram
             itself — every row lands in exactly one bin of every group, so
@@ -1037,29 +1069,26 @@ def grow_tree(
             s = r + (r >= default_bin_arr[f]).astype(jnp.int32)
             return jnp.where(in_range, s, default_bin_arr[f])
 
-    is_cat_arr = feature_meta.get("is_categorical")
-    if is_cat_arr is None:
-        is_cat_arr = jnp.zeros((F,), bool)
-    else:
-        is_cat_arr = is_cat_arr.astype(bool)
-
     # Bucketed partition / segment-histogram kernels (make_bucket_kernels
     # above), shared by the sequential and the speculative body.
     if bucketed:
-        _kern = make_bucket_kernels(
-            bins, feature_meta, B, num_group_bins=num_group_bins,
-            bins_nf=bins_nf, chunk=chunk, hist_dtype=hist_dtype,
-            feature_sharded=feature_sharded, kb=KB, hist_route=hist_route,
-        )
+        # the tables it builds; its kernels scope their own calls
+        with jax.named_scope("tree_init"):
+            _kern = make_bucket_kernels(
+                bins, feature_meta, B, num_group_bins=num_group_bins,
+                bins_nf=bins_nf, chunk=chunk, hist_dtype=hist_dtype,
+                feature_sharded=feature_sharded, kb=KB, hist_route=hist_route,
+            )
         partition_batch = jax.named_scope("partition")(_kern.partition_batch)
         hist_extent, part_extent = _kern.hist_extent, _kern.part_extent
 
         @jax.named_scope("hist_build")
-        def segment_histogram_batch(order, begin, cnt, sizes=_kern.sizes):
+        def segment_histogram_batch(order, begin, cnt, sizes=_kern.sizes,
+                                    **phases):
             # vals_all (the per-tree [N, 3] accumulands) binds below, before
             # the first call
             return _kern.segment_histogram_batch(
-                vals_all, order, begin, cnt, sizes)
+                vals_all, order, begin, cnt, sizes, **phases)
 
         def partition_segment(order, begin, pcnt, f, threshold, default_left, member):
             """One split's partition — the W=1 case of partition_batch."""
@@ -1132,23 +1161,25 @@ def grow_tree(
                 nsteps = Lb // C
 
                 def branch(order, begin, cnt):
-                    padded = (cnt + C - 1) // C * C  # [KB]
-                    ends = jnp.cumsum(padded)
-                    offs = ends - padded
-                    t = jnp.arange(Lb, dtype=jnp.int32)
-                    j = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
-                    j = jnp.minimum(j, KB - 1)
-                    q = t - offs[j]
-                    valid = q < cnt[j]
-                    src = jnp.clip(begin[j] + jnp.minimum(q, jnp.maximum(cnt[j] - 1, 0)), 0, N - 1)
-                    rows = order[src]
-                    vals = jnp.take(vals_all, rows, axis=0) * valid[:, None].astype(f32)
-                    b_seg = (
-                        jnp.take(bins_nf, rows, axis=0).T
-                        if bins_nf is not None
-                        else jnp.take(bins, rows, axis=1)
-                    )  # [Frows, Lb]
+                    with jax.named_scope("hist_build/feed"):
+                        padded = (cnt + C - 1) // C * C  # [KB]
+                        ends = jnp.cumsum(padded)
+                        offs = ends - padded
+                        t = jnp.arange(Lb, dtype=jnp.int32)
+                        j = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
+                        j = jnp.minimum(j, KB - 1)
+                        q = t - offs[j]
+                        valid = q < cnt[j]
+                        src = jnp.clip(begin[j] + jnp.minimum(q, jnp.maximum(cnt[j] - 1, 0)), 0, N - 1)
+                        rows = order[src]
+                        vals = jnp.take(vals_all, rows, axis=0) * valid[:, None].astype(f32)
+                        b_seg = (
+                            jnp.take(bins_nf, rows, axis=0).T
+                            if bins_nf is not None
+                            else jnp.take(bins, rows, axis=1)
+                        )  # [Frows, Lb]
                     if flat_pallas:
+                        # its own scopes split the kernel from its relayout
                         return hist_pallas.histogram_pallas_slots(
                             b_seg, vals, ends, B_hist, chunk=C,
                             dtype_name=hist_dtype,
@@ -1177,11 +1208,14 @@ def grow_tree(
 
                 return branch
 
-            return jax.lax.switch(
-                _flat_plan(cnt)[0],
-                [make_branch(Lb, C) for Lb, C in _flat_branches],
-                order, begin, cnt,
-            )
+            with jax.named_scope("hist_build/feed"):
+                branch_idx = _flat_plan(cnt)[0]
+            with jax.named_scope("hist_build/kernel"):
+                return jax.lax.switch(
+                    branch_idx,
+                    [make_branch(Lb, C) for Lb, C in _flat_branches],
+                    order, begin, cnt,
+                )
 
     coupled_arr = feature_meta.get("cegb_coupled")
     lazy_arr = feature_meta.get("cegb_lazy")
@@ -1226,7 +1260,8 @@ def grow_tree(
     # [N, 3] (grad*bag, hess*bag, bag) computed once per tree — the bucketed
     # branches gather rows of this instead of three separate takes
     if bucketed:
-        vals_all = leaf_values(grad, hess, bag_mask)
+        with jax.named_scope("tree_init"):
+            vals_all = leaf_values(grad, hess, bag_mask)
 
     neg_inf = jnp.float32(-jnp.inf)
 
@@ -1338,214 +1373,220 @@ def grow_tree(
             base.b.at[rows].set(pk.b),
         )
 
-    # ---- root ----------------------------------------------------------
-    # with a bucketed partition the [N, 3] values tensor already exists
-    # (vals_all); masked_values(ones) would rebuild the identical array
-    # (ones * bag_mask == bag_mask) — ~6ms/tree on TPU at 1M
-    root_vals = vals_all if bucketed else masked_values(jnp.ones((N,), f32))
+    # the tree's setup; the root's pass, its split and the carries' first
+    # rows carry their own phases
+    with jax.named_scope("tree_init"):
+        # ---- root ----------------------------------------------------------
+        # with a bucketed partition the [N, 3] values tensor already exists
+        # (vals_all); masked_values(ones) would rebuild the identical array
+        # (ones * bag_mask == bag_mask) — ~6ms/tree on TPU at 1M
+        root_vals = vals_all if bucketed else masked_values(jnp.ones((N,), f32))
 
-    @jax.named_scope("hist_build")
-    def root_whole():
-        return leaf_histogram(
-            bins, root_vals, B_hist, chunk=chunk, axis_name=hist_axis,
-            hist_dtype=hist_dtype, feature_sharded=feature_sharded,
-            route=hist_route,
-        )
+        @jax.named_scope("hist_build/root")
+        def root_whole():
+            return leaf_histogram(
+                bins, root_vals, B_hist, chunk=chunk, axis_name=hist_axis,
+                hist_dtype=hist_dtype, feature_sharded=feature_sharded,
+                route=hist_route,
+            )
 
-    # the bucketed grower on one device, its matrix whole, is rooted at the
-    # sample: the root segment is the in-bag rows, first in ``order`` and in
-    # their own order
-    rooted = bucketed and axis_name is None and not feature_sharded
-    if rooted:
-        in_bag = bag_mask > 0
-        n_root = jnp.sum(in_bag, dtype=jnp.int32)
-        sampled = n_root < N
+        # the bucketed grower on one device, its matrix whole, is rooted at the
+        # sample: the root segment is the in-bag rows, first in ``order`` and in
+        # their own order
+        rooted = bucketed and axis_name is None and not feature_sharded
+        if rooted:
+            in_bag = bag_mask > 0
+            n_root = jnp.sum(in_bag, dtype=jnp.int32)
+            sampled = n_root < N
 
-        def root_sample():
-            order = jnp.argsort(~in_bag, stable=True).astype(jnp.int32)
-            hist = segment_histogram_batch(
-                order, jnp.zeros((1,), jnp.int32), n_root[None],
-                _kern.root_sizes)[0]
-            return order, hist, hist_extent(n_root[None], _kern.root_sizes)
+            def root_sample():
+                order = jnp.argsort(~in_bag, stable=True).astype(jnp.int32)
+                hist = segment_histogram_batch(
+                    order, jnp.zeros((1,), jnp.int32), n_root[None],
+                    _kern.root_sizes,
+                    phases=("hist_build/root", "hist_build/root"))[0]
+                return order, hist, hist_extent(n_root[None], _kern.root_sizes)
 
-        # every row in the bag: the identity order and one pass over the
-        # table as it lies, with no gather of it
-        order0, root_hist, root_streamed = jax.lax.cond(
-            sampled, root_sample,
-            lambda: (jnp.arange(N, dtype=jnp.int32), root_whole(),
-                     jnp.int32(N)),
-        )
-    else:
-        n_root = root_streamed = N
-        root_hist = root_whole()
-        if bucketed:
-            order0 = jnp.arange(N, dtype=jnp.int32)
-    # Root totals from the histogram of feature 0 would miss rows in padded bins;
-    # sum the mask directly instead (psum'd under shard_map like GBDT's root sync,
-    # serial_tree_learner.cpp:271 BeforeTrain).
-    root_g = jnp.sum(grad * bag_mask)
-    root_h = jnp.sum(hess * bag_mask)
-    root_n = jnp.sum(bag_mask)
-    if axis_name is not None:
-        # shard-linear root reductions ride the same partial-accumulation
-        # seam as the histograms (HistogramSource, ops/histogram.py)
-        _root_src = histogram_source(axis_name)
-        root_g = _root_src.combine(root_g)
-        root_h = _root_src.combine(root_h)
-        root_n = _root_src.combine(root_n)
-    if bundled:
-        if axis_name is not None and not psum_hist:
-            # voting-parallel shard-local mode: remap with LOCAL totals (the
-            # linearity argument on remap_hist); the split_fn's elected psum
-            # then combines feature-space histograms exactly
-            root_hist = remap_hist_local(root_hist)
+            # every row in the bag: the identity order and one pass over the
+            # table as it lies, with no gather of it
+            order0, root_hist, root_streamed = jax.lax.cond(
+                sampled, root_sample,
+                lambda: (jnp.arange(N, dtype=jnp.int32), root_whole(),
+                         jnp.int32(N)),
+            )
         else:
-            root_hist = remap_hist(root_hist, root_g, root_h, root_n)
-
-    no_con_min = jnp.full((M,), -jnp.inf, f32)
-    no_con_max = jnp.full((M,), jnp.inf, f32)
-
-    if cegb_state is not None:
-        feature_used0, used_in_data0 = cegb_state
-    else:
-        feature_used0 = jnp.zeros((F,), bool)
-        used_in_data0 = jnp.zeros((F, N) if cegb.has_lazy else (1, 1), bool)
-    if cegb.has_lazy:
-        root_unused = (~used_in_data0).astype(f32) @ bag_mask  # [F]
+            n_root = root_streamed = N
+            root_hist = root_whole()
+            if bucketed:
+                order0 = jnp.arange(N, dtype=jnp.int32)
+        # Root totals from the histogram of feature 0 would miss rows in padded bins;
+        # sum the mask directly instead (psum'd under shard_map like GBDT's root sync,
+        # serial_tree_learner.cpp:271 BeforeTrain).
+        root_g = jnp.sum(grad * bag_mask)
+        root_h = jnp.sum(hess * bag_mask)
+        root_n = jnp.sum(bag_mask)
         if axis_name is not None:
-            root_unused = jax.lax.psum(root_unused, axis_name)
-        unused0 = jnp.zeros((M, F), f32).at[0].set(root_unused)
-    else:
-        unused0 = jnp.zeros((M, F), f32)
+            # shard-linear root reductions ride the same partial-accumulation
+            # seam as the histograms (HistogramSource, ops/histogram.py)
+            _root_src = histogram_source(axis_name)
+            root_g = _root_src.combine(root_g)
+            root_h = _root_src.combine(root_h)
+            root_n = _root_src.combine(root_n)
+        if bundled:
+            if axis_name is not None and not psum_hist:
+                # voting-parallel shard-local mode: remap with LOCAL totals (the
+                # linearity argument on remap_hist); the split_fn's elected psum
+                # then combines feature-space histograms exactly
+                root_hist = remap_hist_local(root_hist)
+            else:
+                root_hist = remap_hist(root_hist, root_g, root_h, root_n)
 
-    def expand_packed(res: SplitResult, idx: int) -> PackedBest:
-        """Scatter one leaf's SplitResult into [M]-leading packed arrays
-        (gain initialized to -inf everywhere else)."""
-        row = _pack_best(res)
-        f0 = jnp.zeros((M, row.f.shape[-1]), f32).at[:, 0].set(-jnp.inf)
-        return PackedBest(
-            f0.at[idx].set(row.f),
-            jnp.zeros((M, row.i.shape[-1]), jnp.int32).at[idx].set(row.i),
-            jnp.zeros((M, row.b.shape[-1]), bool).at[idx].set(row.b),
-        )
+        no_con_min = jnp.full((M,), -jnp.inf, f32)
+        no_con_max = jnp.full((M,), jnp.inf, f32)
 
-    tree0 = PackedTree(
-        num_leaves=jnp.int32(1),
-        node_f=jnp.zeros((M, 3), f32),
-        node_i=jnp.zeros((M, 4), jnp.int32),
-        node_b=jnp.zeros((M, 1 + B), bool),
-        leaf_f=jnp.zeros((M, 3), f32).at[0].set(
-            jnp.stack(
-                [calculate_leaf_output(root_g, root_h, params), root_n, root_h]
+        if cegb_state is not None:
+            feature_used0, used_in_data0 = cegb_state
+        else:
+            feature_used0 = jnp.zeros((F,), bool)
+            used_in_data0 = jnp.zeros((F, N) if cegb.has_lazy else (1, 1), bool)
+        if cegb.has_lazy:
+            root_unused = (~used_in_data0).astype(f32) @ bag_mask  # [F]
+            if axis_name is not None:
+                root_unused = jax.lax.psum(root_unused, axis_name)
+            unused0 = jnp.zeros((M, F), f32).at[0].set(root_unused)
+        else:
+            unused0 = jnp.zeros((M, F), f32)
+
+        def expand_packed(res: SplitResult, idx: int) -> PackedBest:
+            """Scatter one leaf's SplitResult into [M]-leading packed arrays
+            (gain initialized to -inf everywhere else)."""
+            row = _pack_best(res)
+            f0 = jnp.zeros((M, row.f.shape[-1]), f32).at[:, 0].set(-jnp.inf)
+            return PackedBest(
+                f0.at[idx].set(row.f),
+                jnp.zeros((M, row.i.shape[-1]), jnp.int32).at[idx].set(row.i),
+                jnp.zeros((M, row.b.shape[-1]), bool).at[idx].set(row.b),
             )
-        ),
-        # leaf_parent -1, leaf_depth 0 (root depth 0, tree.cpp ctor)
-        leaf_i=jnp.concatenate(
-            [jnp.full((M, 1), -1, jnp.int32), jnp.zeros((M, 1), jnp.int32)],
-            axis=1,
-        ),
-        # the root's histogram pass reads every row of its segment once
-        counters=_counted(
-            jnp.zeros((len(COUNTER_NAMES),), f32),
-            hist_rows_streamed=root_streamed, hist_rows_needed=n_root,
-            root_rows=n_root, hist_columns=bins.shape[0],
-        ),
-    )
 
-    # The [M, F, B, 3] carry only needs slice 0 initialized: every other
-    # leaf's slice is written (smaller-pass + subtraction) when that leaf is
-    # created, before any read. A caller-donated scratch buffer therefore
-    # skips the 22MB-at-bench-shape zeros write every tree; its stale contents
-    # are finite floats whose garbage candidate gains are masked by the
-    # leaf-exists checks. Returned (aliased, zero-copy) when donated so the
-    # caller can re-donate it for the next tree.
-    if hist_buf is not None:
-        hist0 = hist_buf.at[0].set(root_hist)
-    else:
-        hist0 = jnp.zeros((P, F, B, 3), f32).at[0].set(root_hist)
-    if pooled:
-        slot_of0 = jnp.full((M,), -1, jnp.int32).at[0].set(0)
-        slot_leaf0 = jnp.full((P,), -1, jnp.int32).at[0].set(0)
-        slot_age0 = jnp.zeros((P,), jnp.int32).at[0].set(1)
-    else:
-        slot_of0 = jnp.zeros((1,), jnp.int32)
-        slot_leaf0 = jnp.zeros((1,), jnp.int32)
-        slot_age0 = jnp.zeros((1,), jnp.int32)
+        tree0 = PackedTree(
+            num_leaves=jnp.int32(1),
+            node_f=jnp.zeros((M, 3), f32),
+            node_i=jnp.zeros((M, 4), jnp.int32),
+            node_b=jnp.zeros((M, 1 + B), bool),
+            leaf_f=jnp.zeros((M, 3), f32).at[0].set(
+                jnp.stack(
+                    [calculate_leaf_output(root_g, root_h, params), root_n, root_h]
+                )
+            ),
+            # leaf_parent -1, leaf_depth 0 (root depth 0, tree.cpp ctor)
+            leaf_i=jnp.concatenate(
+                [jnp.full((M, 1), -1, jnp.int32), jnp.zeros((M, 1), jnp.int32)],
+                axis=1,
+            ),
+            # the root's histogram pass reads every row of its segment once
+            counters=_counted(
+                jnp.zeros((len(COUNTER_NAMES),), f32),
+                hist_rows_streamed=root_streamed, hist_rows_needed=n_root,
+                root_rows=n_root, hist_columns=bins.shape[0],
+                hist_rows_gathered=root_streamed,
+            ),
+        )
 
-    # [M, 5] leaf aux: sums at col 0-2, monotone windows at col 3-4 — one
-    # scatter per split updates all five (vs five chained pairs)
-    laux0 = jnp.stack(
-        [
-            jnp.zeros((M,), f32).at[0].set(root_g),
-            jnp.zeros((M,), f32).at[0].set(root_h),
-            jnp.zeros((M,), f32).at[0].set(root_n),
-            no_con_min,
-            no_con_max,
-        ],
-        axis=-1,
-    )
+        # The [M, F, B, 3] carry only needs slice 0 initialized: every other
+        # leaf's slice is written (smaller-pass + subtraction) when that leaf is
+        # created, before any read. A caller-donated scratch buffer therefore
+        # skips the 22MB-at-bench-shape zeros write every tree; its stale contents
+        # are finite floats whose garbage candidate gains are masked by the
+        # leaf-exists checks. Returned (aliased, zero-copy) when donated so the
+        # caller can re-donate it for the next tree.
+        with jax.named_scope("carry_write"):
+            if hist_buf is not None:
+                hist0 = hist_buf.at[0].set(root_hist)
+            else:
+                hist0 = jnp.zeros((P, F, B, 3), f32).at[0].set(root_hist)
+        if pooled:
+            slot_of0 = jnp.full((M,), -1, jnp.int32).at[0].set(0)
+            slot_leaf0 = jnp.full((P,), -1, jnp.int32).at[0].set(0)
+            slot_age0 = jnp.zeros((P,), jnp.int32).at[0].set(1)
+        else:
+            slot_of0 = jnp.zeros((1,), jnp.int32)
+            slot_leaf0 = jnp.zeros((1,), jnp.int32)
+            slot_age0 = jnp.zeros((1,), jnp.int32)
 
-    if cegb_on and pooled:
-        empty = PackedBest(
-            jnp.zeros((M, len(_BEST_F)), f32).at[:, 0].set(-jnp.inf),
-            jnp.zeros((M, len(_BEST_I)), jnp.int32),
-            jnp.zeros((M, 1 + B), bool),
+        # [M, 5] leaf aux: sums at col 0-2, monotone windows at col 3-4 — one
+        # scatter per split updates all five (vs five chained pairs)
+        laux0 = jnp.stack(
+            [
+                jnp.zeros((M,), f32).at[0].set(root_g),
+                jnp.zeros((M,), f32).at[0].set(root_h),
+                jnp.zeros((M,), f32).at[0].set(root_n),
+                no_con_min,
+                no_con_max,
+            ],
+            axis=-1,
         )
-        best0 = rescan_resident(
-            tree0, hist0, slot_leaf0, slot_age0, laux0, feature_used0, unused0,
-            empty, feature_used0, None,
-        )
-    elif cegb_on:
-        root_best = rescan_all(
-            tree0, hist0,
-            laux0[:, _LAUX_SG], laux0[:, _LAUX_SH], laux0[:, _LAUX_ND],
-            no_con_min, no_con_max, feature_used0, unused0,
-        )
-        best0 = _pack_best(root_best)
-    else:
-        root_kw = {"two_way": two_way} if split_fn is find_best_split else {}
-        with jax.named_scope("split_find"):
-            root_split = split_fn(
-                root_hist, root_g, root_h, root_n,
-                no_con_min[0], no_con_max[0],
-                feature_meta, feature_mask, params, **root_kw,
+
+        if cegb_on and pooled:
+            empty = PackedBest(
+                jnp.zeros((M, len(_BEST_F)), f32).at[:, 0].set(-jnp.inf),
+                jnp.zeros((M, len(_BEST_I)), jnp.int32),
+                jnp.zeros((M, 1 + B), bool),
             )
-        best0 = expand_packed(root_split, 0)
+            best0 = rescan_resident(
+                tree0, hist0, slot_leaf0, slot_age0, laux0, feature_used0, unused0,
+                empty, feature_used0, None,
+            )
+        elif cegb_on:
+            root_best = rescan_all(
+                tree0, hist0,
+                laux0[:, _LAUX_SG], laux0[:, _LAUX_SH], laux0[:, _LAUX_ND],
+                no_con_min, no_con_max, feature_used0, unused0,
+            )
+            best0 = _pack_best(root_best)
+        else:
+            root_kw = {"two_way": two_way} if split_fn is find_best_split else {}
+            with jax.named_scope("split_find"):
+                root_split = split_fn(
+                    root_hist, root_g, root_h, root_n,
+                    no_con_min[0], no_con_max[0],
+                    feature_meta, feature_mask, params, **root_kw,
+                )
+            best0 = expand_packed(root_split, 0)
 
-    state0 = GrowState(
-        it=jnp.int32(0),
-        leaf_id=jnp.zeros((1,) if bucketed else (N,), jnp.int32),
-        tree=tree0,
-        best=best0,
-        laux=laux0,
-        hist=hist0,
-        feature_used=feature_used0,
-        unused_cnt=unused0,
-        used_in_data=used_in_data0,
-        order=order0 if bucketed else jnp.zeros((1,), jnp.int32),
-        leaf_begin=jnp.zeros((M,) if bucketed else (1,), jnp.int32),
-        leaf_phys=(
-            jnp.zeros((M,), jnp.int32).at[0].set(n_root)
-            if bucketed
-            else jnp.zeros((1,), jnp.int32)
-        ),
-        slot_of=slot_of0,
-        slot_leaf=slot_leaf0,
-        slot_age=slot_age0,
-        spec_flag=jnp.zeros((M,) if KB else (1,), bool),
-        spec_lphys=jnp.zeros((M,) if KB else (1,), jnp.int32),
-        # donated scratch (like hist_buf): stale contents are read only
-        # through spec_flag-gated selects and spec_flag starts all-False,
-        # so skipping the [M, F, B, 3] zeros write per tree is safe
-        spec_rhist=(
-            (spec_buf if spec_buf is not None else jnp.zeros((M, F, B, 3), f32))
-            if KB
-            else jnp.zeros((1, 1, 1, 1), f32)
-        ),
-    )
+        state0 = GrowState(
+            it=jnp.int32(0),
+            leaf_id=jnp.zeros((1,) if bucketed else (N,), jnp.int32),
+            tree=tree0,
+            best=best0,
+            laux=laux0,
+            hist=hist0,
+            feature_used=feature_used0,
+            unused_cnt=unused0,
+            used_in_data=used_in_data0,
+            order=order0 if bucketed else jnp.zeros((1,), jnp.int32),
+            leaf_begin=jnp.zeros((M,) if bucketed else (1,), jnp.int32),
+            leaf_phys=(
+                jnp.zeros((M,), jnp.int32).at[0].set(n_root)
+                if bucketed
+                else jnp.zeros((1,), jnp.int32)
+            ),
+            slot_of=slot_of0,
+            slot_leaf=slot_leaf0,
+            slot_age=slot_age0,
+            spec_flag=jnp.zeros((M,) if KB else (1,), bool),
+            spec_lphys=jnp.zeros((M,) if KB else (1,), jnp.int32),
+            # donated scratch (like hist_buf): stale contents are read only
+            # through spec_flag-gated selects and spec_flag starts all-False,
+            # so skipping the [M, F, B, 3] zeros write per tree is safe
+            spec_rhist=(
+                (spec_buf if spec_buf is not None else jnp.zeros((M, F, B, 3), f32))
+                if KB
+                else jnp.zeros((1, 1, 1, 1), f32)
+            ),
+        )
 
-    # scopes: what is not inside partition / hist_build / split_find below is
-    # the carry's row reads and writes, and reads as apply_split alone
+    # scopes: what is not inside partition / hist_build / split_find /
+    # carry_write below is the tree's bookkeeping, and reads as apply_split
     @jax.named_scope("apply_split")
     def apply_split(s: GrowState, best_leaf, rec: SplitResult) -> GrowState:
         """Apply one split of ``best_leaf`` by ``rec`` (Split,
@@ -1567,29 +1608,32 @@ def grow_tree(
             leaf_phys = (
                 s.leaf_phys.at[best_leaf].set(left_phys).at[new_leaf].set(right_phys)
             )
-            part_rows = (part_extent(pphys[None]), pphys, by_default)
+            # [streamed, needed, sent by default, taken] of the partition
+            part_rows = (part_extent(pphys[None]), pphys, by_default, N)
         else:
-            row = gid_arr[f] if bundled else f
-            col = jax.lax.dynamic_slice(bins, (row, 0), (1, N))[0].astype(jnp.int32)
-            if bundled:
-                col = decode_col(col, f)
-            go_left = _decision_go_left(
-                col,
-                rec.threshold,
-                rec.default_left,
-                missing_arr[f],
-                default_bin_arr[f],
-                num_bin_arr[f] - 1,
-                is_cat_arr[f],
-                rec.cat_bitset[jnp.clip(col, 0, B - 1)],
-            )
-            in_leaf = s.leaf_id == best_leaf
-            leaf_id = jnp.where(in_leaf & ~go_left, new_leaf, s.leaf_id)
-            order, leaf_begin, leaf_phys = s.order, s.leaf_begin, s.leaf_phys
-            part_rows = (N, jnp.sum(in_leaf), jnp.sum(in_leaf & _in_missing_bin(
-                col, missing_arr[f], default_bin_arr[f], num_bin_arr[f] - 1,
-                is_cat_arr[f],
-            )))
+            with jax.named_scope("partition/split_columns"):
+                row = gid_arr[f] if bundled else f
+                col = jax.lax.dynamic_slice(bins, (row, 0), (1, N))[0].astype(jnp.int32)
+            with jax.named_scope("partition/lanes"):
+                if bundled:
+                    col = decode_col(col, f)
+                go_left = _decision_go_left(
+                    col,
+                    rec.threshold,
+                    rec.default_left,
+                    missing_arr[f],
+                    default_bin_arr[f],
+                    num_bin_arr[f] - 1,
+                    is_cat_arr[f],
+                    rec.cat_bitset[jnp.clip(col, 0, B - 1)],
+                )
+                in_leaf = s.leaf_id == best_leaf
+                leaf_id = jnp.where(in_leaf & ~go_left, new_leaf, s.leaf_id)
+                order, leaf_begin, leaf_phys = s.order, s.leaf_begin, s.leaf_phys
+                part_rows = (N, jnp.sum(in_leaf), jnp.sum(in_leaf & _in_missing_bin(
+                    col, missing_arr[f], default_bin_arr[f], num_bin_arr[f] - 1,
+                    is_cat_arr[f],
+                )), N)
 
         # ---- wire the tree (5 scatters, PackedTree) ----------------------
         t = s.tree
@@ -1707,17 +1751,20 @@ def grow_tree(
             if hist_axis is not None:
                 # collective AFTER the bucket switch: shards may pick different
                 # bucket branches, so no psum may live inside them
-                small_hist = histogram_source(hist_axis).combine(small_hist)
+                with jax.named_scope("hist_build/kernel"):
+                    small_hist = histogram_source(hist_axis).combine(small_hist)
             # [streamed, needed] of the smaller child's pass and of the
             # larger child's, where that one is summed from data too
             small_rows = (hist_extent(small_cnt[None]), small_cnt)
             large_cnt = pphys - small_cnt
             large_rows = (hist_extent(large_cnt[None]), large_cnt)
         else:
-            small_mask = (leaf_id == small_idx).astype(f32)
-            with jax.named_scope("hist_build"):
+            with jax.named_scope("hist_build/feed"):
+                small_mask = (leaf_id == small_idx).astype(f32)
+                small_vals = masked_values(small_mask)
+            with jax.named_scope("hist_build/kernel"):
                 small_hist = leaf_histogram(
-                    bins, masked_values(small_mask), B_hist, chunk=chunk,
+                    bins, small_vals, B_hist, chunk=chunk,
                     axis_name=hist_axis, hist_dtype=hist_dtype,
                     feature_sharded=feature_sharded, route=hist_route,
                 )
@@ -1743,12 +1790,14 @@ def grow_tree(
                 lg_cnt = jnp.where(left_smaller, right_phys, left_phys)
                 h = segment_histogram(order, lg_begin, lg_cnt)
                 if hist_axis is not None:
-                    h = histogram_source(hist_axis).combine(h)
+                    with jax.named_scope("hist_build/kernel"):
+                        h = histogram_source(hist_axis).combine(h)
             else:
-                lmask = (leaf_id == large_idx).astype(f32)
-                with jax.named_scope("hist_build"):
+                with jax.named_scope("hist_build/feed"):
+                    lvals = masked_values((leaf_id == large_idx).astype(f32))
+                with jax.named_scope("hist_build/kernel"):
                     h = leaf_histogram(
-                        bins, masked_values(lmask), B_hist, chunk=chunk,
+                        bins, lvals, B_hist, chunk=chunk,
                         axis_name=hist_axis, hist_dtype=hist_dtype,
                         feature_sharded=feature_sharded, route=hist_route,
                     )
@@ -1770,10 +1819,11 @@ def grow_tree(
             # so the collective inside the miss branch executes uniformly.
             pslot = s.slot_of[best_leaf]
             cached = (pslot >= 0) if use_subtract else jnp.asarray(False)
-            parent_hist = s.hist[jnp.maximum(pslot, 0)]
-            large_hist = jax.lax.cond(
-                cached, lambda: parent_hist - small_hist, large_direct
-            )
+            with jax.named_scope("split_find/prep"):
+                parent_hist = s.hist[jnp.maximum(pslot, 0)]
+                large_hist = jax.lax.cond(
+                    cached, lambda: parent_hist - small_hist, large_direct
+                )
             direct = (~cached).astype(f32)  # the larger child read its rows
             # slots: the larger child inherits the parent's slot on a hit
             # (the reference's in-place Subtract); otherwise evict the LRU.
@@ -1809,7 +1859,9 @@ def grow_tree(
             )
             stamp = s.it + 2  # > the root's stamp of 1; free slots stay 0
             slot_age = ages.at[slot_pair].set(jnp.stack([stamp, stamp]))
-            hist = s.hist.at[slot_pair].set(jnp.stack([small_hist, large_hist]))
+            with jax.named_scope("carry_write"):
+                hist = s.hist.at[slot_pair].set(
+                    jnp.stack([small_hist, large_hist]))
             child_rows = jnp.stack(
                 [
                     jnp.where(left_smaller, small_slot, large_slot),
@@ -1817,11 +1869,12 @@ def grow_tree(
                 ]
             )
         else:
-            parent_hist = s.hist[best_leaf]
-            if use_subtract:
-                large_hist = parent_hist - small_hist
-            else:
-                large_hist = large_direct()
+            with jax.named_scope("split_find/prep"):
+                parent_hist = s.hist[best_leaf]
+                if use_subtract:
+                    large_hist = parent_hist - small_hist
+                else:
+                    large_hist = large_direct()
             direct = 0.0 if use_subtract else 1.0
             slot_of, slot_leaf, slot_age = s.slot_of, s.slot_leaf, s.slot_age
             # ONE stacked scatter, not two chained .at[].set: XLA updates the
@@ -1829,9 +1882,10 @@ def grow_tree(
             # full-buffer copy per chained update (~2 x 22MB per split at
             # M=255/F=28/B=256 — measured 40x slower on CPU, and HBM traffic
             # that would cost ~14ms/iter on TPU)
-            hist = s.hist.at[jnp.stack([small_idx, large_idx])].set(
-                jnp.stack([small_hist, large_hist])
-            )
+            with jax.named_scope("carry_write"):
+                hist = s.hist.at[jnp.stack([small_idx, large_idx])].set(
+                    jnp.stack([small_hist, large_hist])
+                )
             child_rows = None  # hist rows ARE leaf rows; set below
 
         # ---- next-round candidate refresh --------------------------------
@@ -1852,8 +1906,10 @@ def grow_tree(
         else:
             if child_rows is None:
                 child_rows = child_idx  # unpooled: hist rows are leaf rows
-            ch_hist = hist[child_rows]  # leaf rows unpooled, slot rows pooled
-            ch_aux = laux[child_idx]  # [2, 5]
+            with jax.named_scope("split_find/prep"):
+                # leaf rows unpooled, slot rows pooled
+                ch_hist = hist[child_rows]
+                ch_aux = laux[child_idx]  # [2, 5]
             ch_split = split2(
                 ch_hist, ch_aux[:, _LAUX_SG], ch_aux[:, _LAUX_SH],
                 ch_aux[:, _LAUX_ND], ch_aux[:, _LAUX_MIN], ch_aux[:, _LAUX_MAX],
@@ -1870,8 +1926,10 @@ def grow_tree(
             tree.counters, splits=1,
             hist_rows_streamed=small_rows[0] + direct * large_rows[0],
             hist_rows_needed=small_rows[1] + direct * large_rows[1],
+            # the W=1 pass gathers the rows it streams
+            hist_rows_gathered=small_rows[0] + direct * large_rows[0],
             part_rows_streamed=part_rows[0], part_rows_needed=part_rows[1],
-            part_rows_missing=part_rows[2],
+            part_rows_missing=part_rows[2], part_rows_taken=part_rows[3],
             splits_default_left=rec.default_left & (missing_arr[f] != MISSING_NONE),
         ))
         return GrowState(
@@ -1898,46 +1956,50 @@ def grow_tree(
     # ---- forced splits preamble (ForceSplits) ---------------------------
     state = state0
     if forced_splits:
-        aborted = jnp.asarray(False)
-        for (leaf_i, feat_i, thr_i) in forced_splits[: M - 1]:
-            if pooled:
-                # P >= len(forced_splits)+2 is enforced above, so preamble
-                # leaves are never evicted before their forced split applies
-                hist_slice = state.hist[
-                    jnp.maximum(state.slot_of[leaf_i], 0), feat_i
-                ]
-            else:
-                hist_slice = state.hist[leaf_i, feat_i]
-            if axis_name is not None and not psum_hist:
-                # voting-parallel keeps shard-local histograms; a forced split
-                # needs the global column (the elected-slice psum's little sibling)
-                hist_slice = jax.lax.psum(hist_slice, axis_name)
-            rec = gather_info_for_threshold(
-                hist_slice,
-                state.laux[leaf_i, _LAUX_SG],
-                state.laux[leaf_i, _LAUX_SH],
-                state.laux[leaf_i, _LAUX_ND],
-                jnp.int32(thr_i),
-                num_bin_arr[feat_i],
-                missing_arr[feat_i],
-                default_bin_arr[feat_i],
-                is_cat_arr[feat_i],
-                params,
-            )._replace(feature=jnp.int32(feat_i))
-            valid = rec.gain > neg_inf
-            if max_depth > 0:
-                valid &= state.tree.leaf_i[leaf_i, 1] < max_depth
-            can = (~aborted) & valid
-            applied = apply_split(state, jnp.int32(leaf_i), rec)
-            state = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(can, a, b), applied, state
-            )
-            aborted = aborted | ~valid
+        with jax.named_scope("apply_split"):
+            aborted = jnp.asarray(False)
+            for (leaf_i, feat_i, thr_i) in forced_splits[: M - 1]:
+                if pooled:
+                    # P >= len(forced_splits)+2 is enforced above, so preamble
+                    # leaves are never evicted before their forced split applies
+                    hist_slice = state.hist[
+                        jnp.maximum(state.slot_of[leaf_i], 0), feat_i
+                    ]
+                else:
+                    hist_slice = state.hist[leaf_i, feat_i]
+                if axis_name is not None and not psum_hist:
+                    # voting-parallel keeps shard-local histograms; a forced split
+                    # needs the global column (the elected-slice psum's little sibling)
+                    hist_slice = jax.lax.psum(hist_slice, axis_name)
+                with jax.named_scope("split_find"):
+                    rec = gather_info_for_threshold(
+                        hist_slice,
+                        state.laux[leaf_i, _LAUX_SG],
+                        state.laux[leaf_i, _LAUX_SH],
+                        state.laux[leaf_i, _LAUX_ND],
+                        jnp.int32(thr_i),
+                        num_bin_arr[feat_i],
+                        missing_arr[feat_i],
+                        default_bin_arr[feat_i],
+                        is_cat_arr[feat_i],
+                        params,
+                    )._replace(feature=jnp.int32(feat_i))
+                valid = rec.gain > neg_inf
+                if max_depth > 0:
+                    valid &= state.tree.leaf_i[leaf_i, 1] < max_depth
+                can = (~aborted) & valid
+                applied = apply_split(state, jnp.int32(leaf_i), rec)
+                state = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(can, a, b), applied, state
+                )
+                aborted = aborted | ~valid
 
     # ---- best-gain loop --------------------------------------------------
+    @jax.named_scope("apply_split")
     def cond(s: GrowState):
         return (s.it < M - 1) & (jnp.max(s.best.f[:, 0]) > 0.0)
 
+    @jax.named_scope("apply_split")
     def body(s: GrowState) -> GrowState:
         best_leaf = jnp.argmax(s.best.f[:, 0]).astype(jnp.int32)
         rec = _unpack_best_row(s.best, best_leaf)
@@ -1995,7 +2057,8 @@ def grow_tree(
         )(order2, small_begin, small_cnt)
         if hist_axis is not None:
             # ONE collective for the whole batch (vs one per split)
-            small_hist = histogram_source(hist_axis).combine(small_hist)
+            with jax.named_scope("hist_build/kernel"):
+                small_hist = histogram_source(hist_axis).combine(small_hist)
         if bundled:
             small_hist = jax.vmap(remap_hist)(
                 small_hist,
@@ -2006,17 +2069,18 @@ def grow_tree(
         # for a cached slot, hist row b_j already holds the LEFT child's
         # histogram (committed at cache time) and the right child's parks in
         # spec_rhist; for computing slots it still holds the parent's
-        parent_hist = _carry_rows(s.hist, b_top)
-        large_hist = parent_hist - small_hist
-        ls4 = left_smaller[:, None, None, None]
-        c4 = cached[:, None, None, None]
-        lhist = jnp.where(
-            c4, parent_hist, jnp.where(ls4, small_hist, large_hist)
-        )
-        rhist = jnp.where(
-            c4, _carry_rows(s.spec_rhist, b_top),
-            jnp.where(ls4, large_hist, small_hist),
-        )
+        with jax.named_scope("split_find/prep"):
+            parent_hist = _carry_rows(s.hist, b_top)
+            large_hist = parent_hist - small_hist
+            ls4 = left_smaller[:, None, None, None]
+            c4 = cached[:, None, None, None]
+            lhist = jnp.where(
+                c4, parent_hist, jnp.where(ls4, small_hist, large_hist)
+            )
+            rhist = jnp.where(
+                c4, _carry_rows(s.spec_rhist, b_top),
+                jnp.where(ls4, large_hist, small_hist),
+            )
 
         # ---- children: aux, monotone windows, one batched scan ----------
         mono_f = mono_arr[feat]
@@ -2028,7 +2092,8 @@ def grow_tree(
         r_min = jnp.where(mono_f > 0, mid, pmin)
         r_max = jnp.where(mono_f < 0, mid, pmax)
 
-        ch_hist = jnp.concatenate([lhist, rhist], axis=0)  # [2KB, F, B, 3]
+        with jax.named_scope("split_find/prep"):
+            ch_hist = jnp.concatenate([lhist, rhist], axis=0)  # [2KB, F, B, 3]
         with jax.named_scope("split_find"):
             ch_res = jax.vmap(
                 lambda h, sg, sh, nd, mn, mx: find_best_split(
@@ -2108,14 +2173,20 @@ def grow_tree(
                 t.counters, steps=1, slots_computed=jnp.sum(compute),
                 splits=p,
                 # lanes: every one of the KB lanes passes the largest
-                # computing slot's bucket; flat: one concatenated pass
+                # computing slot's bucket; flat: one concatenated pass,
+                # whose gather reads the whole switch branch
                 hist_rows_streamed=(
                     flat_extent(small_cnt) if use_flat
+                    else hist_extent(small_cnt) * KB
+                ),
+                hist_rows_gathered=(
+                    _flat_rows[_flat_plan(small_cnt)[0]] if use_flat
                     else hist_extent(small_cnt) * KB
                 ),
                 hist_rows_needed=jnp.sum(small_cnt),
                 part_rows_streamed=part_extent(pphys_c),
                 part_rows_needed=jnp.sum(pphys_c),
+                part_rows_taken=KB * N,
                 part_rows_missing=by_default,
                 splits_default_left=jnp.sum(
                     applied & dleft & (missing_arr[feat] != MISSING_NONE)
@@ -2156,10 +2227,11 @@ def grow_tree(
         # built); the right child's goes to its new slot on apply, or parks
         # in spec_rhist keyed by the parent on cache
         lrow_hist = jnp.where(applied | cache_set, b_top, drop)
-        hist = s.hist.at[jnp.concatenate([lrow_hist, rrow])].set(
-            jnp.concatenate([lhist, rhist])
-        )
-        spec_rhist = s.spec_rhist.at[crow].set(rhist)
+        with jax.named_scope("carry_write"):
+            hist = s.hist.at[jnp.concatenate([lrow_hist, rrow])].set(
+                jnp.concatenate([lhist, rhist])
+            )
+            spec_rhist = s.spec_rhist.at[crow].set(rhist)
         spec_lphys = s.spec_lphys.at[crow].set(left_phys)
         spec_flag = (
             s.spec_flag.at[crow].set(True)
@@ -2274,33 +2346,35 @@ def grow_tree(
         final = state
 
     if bucketed:
-        # reconstruct per-row leaf ids from the segment layout: position ->
-        # owning segment (empty leaves keyed past N so they claim nothing),
-        # then scatter through the permutation.
-        key = jnp.where(
-            final.leaf_phys > 0,
-            final.leaf_begin,
-            N + jnp.arange(M, dtype=jnp.int32),
-        )
-        ordl = jnp.argsort(key)
-        slot = jnp.searchsorted(key[ordl], jnp.arange(N, dtype=jnp.int32), side="right") - 1
-        pos_leaf = ordl[jnp.clip(slot, 0, M - 1)].astype(jnp.int32)
-        out_leaf_id = jnp.zeros((N,), jnp.int32).at[final.order].set(pos_leaf)
-        if rooted:
-            # the rows past the root segment are in no leaf's segment
-            out_leaf_id = jax.lax.cond(
-                sampled,
-                lambda: jnp.where(in_bag, out_leaf_id, leaves_by_tree(final.tree)),
-                lambda: out_leaf_id,
+        with jax.named_scope("leaf_ids"):
+            # reconstruct per-row leaf ids from the segment layout: position
+            # -> owning segment (empty leaves keyed past N so they claim
+            # nothing), then scatter through the permutation.
+            key = jnp.where(
+                final.leaf_phys > 0,
+                final.leaf_begin,
+                N + jnp.arange(M, dtype=jnp.int32),
             )
+            ordl = jnp.argsort(key)
+            slot = jnp.searchsorted(key[ordl], jnp.arange(N, dtype=jnp.int32), side="right") - 1
+            pos_leaf = ordl[jnp.clip(slot, 0, M - 1)].astype(jnp.int32)
+            out_leaf_id = jnp.zeros((N,), jnp.int32).at[final.order].set(pos_leaf)
+            if rooted:
+                # the rows past the root segment are in no leaf's segment
+                out_leaf_id = jax.lax.cond(
+                    sampled,
+                    lambda: jnp.where(in_bag, out_leaf_id, leaves_by_tree(final.tree)),
+                    lambda: out_leaf_id,
+                )
     else:
         out_leaf_id = final.leaf_id
 
-    out_tree = _unpack_tree(final.tree, M)
-    if axis_name is not None:
-        # per-shard counts: the slowest shard sets the time
-        out_tree = out_tree._replace(
-            counters=jax.lax.pmax(out_tree.counters, axis_name))
+    with jax.named_scope("tree_out"):
+        out_tree = _unpack_tree(final.tree, M)
+        if axis_name is not None:
+            # per-shard counts: the slowest shard sets the time
+            out_tree = out_tree._replace(
+                counters=jax.lax.pmax(out_tree.counters, axis_name))
     out = (out_tree, out_leaf_id)
     if cegb_on:
         out = out + ((final.feature_used, final.used_in_data),)

@@ -330,9 +330,9 @@ def _fb_call(bins, values, slot_of_chunk, n_live, num_slots, num_bins, C,
              dtype_name, interpret):
     """The one ``pallas_call`` of the routed kernel: ``bins`` [F, n*C] and
     ``values`` [n*C, K] cut into n chunks of C rows, chunk c summed into
-    slot ``slot_of_chunk[c]`` while c < ``n_live``. -> [W, F, B, K]; a
-    slot that owns no live chunk is never written (the caller knows which
-    those are)."""
+    slot ``slot_of_chunk[c]`` while c < ``n_live``. -> the kernel's raw
+    output, which ``_fb_relayout`` turns into [W, F, B, K]; a slot that owns
+    no live chunk is never written (the caller knows which those are)."""
     F, L = bins.shape
     K = values.shape[1]
     B = num_bins
@@ -343,13 +343,13 @@ def _fb_call(bins, values, slot_of_chunk, n_live, num_slots, num_bins, C,
     Fp = -(-F // FB) * FB
     if Fp != F:
         # padded feature rows histogram the padded bins (all zero) against
-        # real values; their rows are sliced off below
+        # real values; ``_fb_relayout`` slices their rows off
         bins = jnp.pad(bins, ((0, Fp - F), (0, 0)))
 
     def src(c, live_ref):
         return jnp.minimum(c, jnp.maximum(live_ref[0] - 1, 0))
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel_fb, hi_n=HI, pieces=P),
         name="hist_pallas_fb",
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -379,6 +379,12 @@ def _fb_call(bins, values, slot_of_chunk, n_live, num_slots, num_bins, C,
         bins, values.T,
     )
 
+
+def _fb_relayout(out, F, num_bins, dtype_name):
+    """``_fb_call``'s output -> [W, F, B, K] histograms."""
+    W, Fp, HI = out.shape[:3]
+    P = _pieces_for(dtype_name)
+    K = out.shape[3] // (P * LO)
     # out[w, f, hi, (p*K + k)*LO + lo] -> hist[w, f, hi*LO + lo, k]; the
     # pieces are added largest first, in an order no fusion can change
     parts = out.reshape(W, Fp, HI, P, K, LO)
@@ -386,7 +392,7 @@ def _fb_call(bins, values, slot_of_chunk, n_live, num_slots, num_bins, C,
     for p in range(1, P):
         total = total + parts[:, :, :, p]
     hist = total.transpose(0, 1, 2, 4, 3).reshape(W, Fp, HI * LO, K)
-    return hist[:, :F, :B, :]
+    return hist[:, :F, :num_bins, :]
 
 
 @functools.partial(
@@ -411,10 +417,11 @@ def _histogram_pallas_fb(
         pad = n_chunks * C - N
         bins = jnp.pad(bins, ((0, 0), (0, pad)))
         values = jnp.pad(values, ((0, pad), (0, 0)))
-    return _fb_call(
+    out = _fb_call(
         bins, values, jnp.zeros((n_chunks,), jnp.int32), jnp.int32(n_chunks),
         1, num_bins, C, dtype_name, interpret,
-    )[0]
+    )
+    return _fb_relayout(out, bins.shape[0], num_bins, dtype_name)[0]
 
 
 @functools.partial(
@@ -440,7 +447,9 @@ def histogram_pallas_slots(
     the rest of ``L`` (a caller's static round-up) is neither fetched nor
     summed. Each segment's histogram is bit for bit what
     :func:`histogram_pallas` gives for it alone, whatever ``chunk`` and
-    whatever lies beside it (``_kernel_fb``)."""
+    whatever lies beside it (``_kernel_fb``). The speculative grower's
+    batch: its kernel and the relayout of its output carry the grower's
+    phases (obs/trace.SCOPES)."""
     W = ends.shape[0]
     L = bins.shape[1]
     if chunk % SUB or chunk > _max_chunk_for("pallas") or L % chunk:
@@ -448,18 +457,22 @@ def histogram_pallas_slots(
             "chunk %d must be a multiple of %d under the kernel's cap that "
             "divides the %d flat rows" % (chunk, SUB, L)
         )
-    ends = ends.astype(jnp.int32)
-    starts = jnp.arange(L // chunk, dtype=jnp.int32) * chunk
-    slot_of_chunk = jnp.minimum(
-        jnp.searchsorted(ends, starts, side="right").astype(jnp.int32), W - 1
-    )
-    hist = _fb_call(
-        bins, values, slot_of_chunk, ends[-1] // chunk, W, num_bins, chunk,
-        dtype_name, interpret,
-    )
-    # a slot with no chunk was never written: whatever the buffer held
-    owns_rows = jnp.diff(ends, prepend=0) > 0
-    return jnp.where(owns_rows[:, None, None, None], hist, 0.0)
+    with jax.named_scope("hist_build/kernel"):
+        ends = ends.astype(jnp.int32)
+        starts = jnp.arange(L // chunk, dtype=jnp.int32) * chunk
+        slot_of_chunk = jnp.minimum(
+            jnp.searchsorted(ends, starts, side="right").astype(jnp.int32),
+            W - 1,
+        )
+        out = _fb_call(
+            bins, values, slot_of_chunk, ends[-1] // chunk, W, num_bins,
+            chunk, dtype_name, interpret,
+        )
+    with jax.named_scope("hist_build/relayout"):
+        hist = _fb_relayout(out, bins.shape[0], num_bins, dtype_name)
+        # a slot with no chunk was never written: whatever the buffer held
+        owns_rows = jnp.diff(ends, prepend=0) > 0
+        return jnp.where(owns_rows[:, None, None, None], hist, 0.0)
 
 
 def histogram_pallas(

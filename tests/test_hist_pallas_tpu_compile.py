@@ -367,3 +367,31 @@ def test_a_partition_lane_gathers_only_its_bin(one_chip, categorical):
         if categorical:
             want.append(("pred", units * 256, "pred"))
         assert sorted(found) == sorted(want), (units, found)
+
+
+@pytest.mark.parametrize("F", [64, 2000])
+def test_every_op_of_the_cells_grower_lies_under_a_phase(one_chip, spec_pallas, F):
+    """tests/test_grow_phase_scopes.py's check on the cells' grower as the
+    chip builds it: the speculative step's flat Pallas pass compiled for the
+    described v5e, narrow and at Epsilon's width (where XLA expands the
+    carry write into a loop of its own, which runs under the write's name).
+    Every op the device runs lies under a phase of ``obs/trace.SCOPES`` or
+    is XLA's own, and the kernel's calls are booked to the root's pass and
+    to the step's kernel. Nothing runs, and nothing here is a device
+    number."""
+    from test_grow_phase_scopes import unscoped_instructions
+
+    from lightgbm_tpu.obs import trace
+
+    _, text = _compile_grower(one_chip, F, 5000, with_bins_nf=True)
+    assert grow_mod._LAST_SPEC_HIST == "flat"
+    found = unscoped_instructions(text)
+    assert not found, found[:10]
+    kernel = {trace.scope_of(re.search(r'op_name="([^"]*)"', line).group(1))
+              for line in text.splitlines()
+              if " custom-call(" in line and "hist_pallas_fb" in line}
+    assert kernel == {"hist_build/root", "hist_build/kernel"}
+    names = set(map(trace.scope_of, re.findall(r'op_name="([^"]*)"', text)))
+    assert {"hist_build/feed", "hist_build/relayout", "partition/split_columns",
+            "partition/lanes", "partition/scatter", "split_find/prep",
+            "carry_write", "leaf_ids", "oob_leaf"} <= names
