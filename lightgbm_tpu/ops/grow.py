@@ -615,11 +615,28 @@ def make_bucket_kernels(
             # switch: a branch that flattens the whole [F, N] matrix for its
             # gather rebuilds that copy at every call (on a v5e 58 ms a call
             # at 968 x 750K, 72% of an iteration there; PERF.md, PR 28).
-            # Always from ``bins``, never ``bins_nf``: a matrix that both
-            # this take and the segment gathers read gets one layout in the
-            # loop's carry, and the side it does not suit relays the whole
-            # of it out at every step (PERF.md, PR 31)
-            cols = jnp.take(bins, rows_of, axis=0).reshape(-1)  # [W * N]
+            # Read as W slices of one row, not by a take: the compiler sank
+            # ``jnp.take(bins, rows_of, axis=0)`` into every branch and cut
+            # it into full-height ``mini-gather-slice`` pieces, 23 of
+            # u8[968,32640] a branch at 968 x 750K, so that a step read the
+            # whole matrix for its W rows; and the slices laid flat by one
+            # concatenate, not stacked by a loop, whose writes of one row
+            # into a [W, N] buffer cost a v5e more than the reads (PERF.md,
+            # PR 38). But a matrix GSPMD shards along the features keeps the
+            # take: its slices at a traced row would all-gather the whole
+            # matrix to every device. Always from ``bins``, never
+            # ``bins_nf``: a matrix that both this read and the segment
+            # gathers read gets one layout in the loop's carry, and the side
+            # it does not suit relays the whole of it out at every step
+            # (PERF.md, PR 31)
+            if feature_sharded:
+                cols = jnp.take(bins, rows_of, axis=0).reshape(-1)
+            else:
+                cols = jnp.concatenate([
+                    jax.lax.dynamic_index_in_dim(
+                        bins, rows_of[k], 0, keepdims=False)
+                    for k in range(W)
+                ])  # [W * N]
 
         with jax.named_scope("partition/lanes"):
             padded = _part_padded(pcnt)  # [W]

@@ -5,7 +5,8 @@ categorical membership up only on a table that has a categorical column; what
 it returns is held here element for element: ``order``, the left counts and
 the rows that went by a split's default direction, for one slot and for
 eight, every missing type, a categorical table, a bundled one, and the batch
-shapes that bite."""
+shapes that bite. The split columns are read from ``bins`` row by row; the
+take they were read by before is held to the same bytes."""
 import functools
 import zlib
 
@@ -137,6 +138,42 @@ def test_the_lane_pass_is_a_stable_partition_of_each_segment(W, kind, shape):
         assert np.array_equal(np.asarray(got[0]), want[0])
         assert np.array_equal(np.asarray(got[1]), want[1])
         assert int(got[2]) == want[2]
+
+
+@pytest.mark.parametrize("feats", ["one_feature", "dead_slots"])
+@pytest.mark.parametrize("kind", ["nan", "categorical", "bundled"])
+@pytest.mark.parametrize("W", [1, 8])
+def test_the_split_columns_read_row_by_row_give_the_take_s_bytes(W, kind, feats):
+    """The W split columns are read from ``bins`` as W slices of one row;
+    ``jnp.take(bins, rows_of, axis=0)``, which read them before and still
+    reads a matrix sharded along the features (``feature_sharded``), is the
+    reference here, and what the pass returns is its own byte for byte:
+    every slot on one feature, and slots of no rows whose feature is what
+    the grower leaves there (0 on the top-k's filler rows, -1 where a leaf's
+    scan found no split), out of range or not."""
+    rng = np.random.RandomState(zlib.crc32(f"{W} {kind} {feats}".encode()))
+    bins, meta, _, _ = table(kind)
+    shape = "zero_width" if feats == "dead_slots" else "any_order"
+    cases = []
+    for i in range(3):
+        begin, pcnt = (np.asarray(a, np.int32) for a in batch(shape, W, rng))
+        feat = np.full(W, rng.randint(0, F), np.int32)
+        if feats == "dead_slots":
+            feat = rng.randint(0, F, W).astype(np.int32)
+            feat[pcnt == 0] = (-1, 0, -1)[i]
+        thr = rng.randint(0, meta["num_bin"][feat]).astype(np.int32)
+        order = rng.permutation(N).astype(np.int32)
+        cases.append((order, begin, pcnt, feat, thr, rng.rand(W) < 0.5, rng.rand(W, B) < 0.5))
+    took = jax.jit(make_bucket_kernels(
+        jnp.asarray(bins), {k: jnp.asarray(v) for k, v in meta.items()}, B,
+        num_group_bins=64, feature_sharded=True, kb=W if W > 1 else 0).partition_batch)
+    for case in cases:
+        mine = partition(kind, W)(*map(jnp.asarray, case))
+        want = took(*map(jnp.asarray, case))
+        for a, b in zip(mine, want):
+            assert a.dtype == b.dtype and np.array_equal(np.asarray(a), np.asarray(b))
+        if (case[3] >= 0).all():  # and the partition is the stable one
+            assert np.array_equal(np.asarray(mine[0]), stable_partition(kind, *case)[0])
 
 
 def test_the_membership_is_read_only_on_a_table_with_a_categorical_column():

@@ -137,6 +137,36 @@ def test_a_grower_step_copies_no_histogram_carry(one_chip, spec_pallas, F):
         assert compiled.memory_analysis().temp_size_in_bytes < carry_bytes // 2
 
 
+@pytest.mark.parametrize("F", [968, 2000])
+def test_a_grower_step_reads_8_rows_of_the_bin_matrix(one_chip, spec_pallas, F):
+    """The partition reads the 8 split features' columns of ``bins`` ``[F, N]``
+    as 8 slices of one row, laid flat by one concatenate. Taken by
+    ``jnp.take(bins, rows_of, axis=0)``, the TPU compiler sank the gather
+    into every branch of the partition's lattice switch and cut it into
+    full-height column pieces (``mini-gather-slice``: 23 of
+    ``u8[968,32640]`` a branch at 968 x 750K, PERF.md, PR 38), so that every
+    step read the whole matrix for 8 of its rows. Under
+    ``partition/split_columns`` the loop may hold no instruction that makes
+    a full-height ``u8[F, n]`` value, and it holds no piece at all. 40,000
+    rows: there the take is cut into two pieces a branch at both widths
+    (at 32,768 into none), so this test is known to see what it guards.
+    Nothing runs, and nothing here is a device number."""
+    N = 40000
+    _, text = _compile_grower(one_chip, F, N, with_bins_nf=True)
+    comps, in_loop = _loop_computations(text)
+    loop = [line for name in in_loop for line in comps[name]]
+    assert not [line for line in loop if "mini-gather-slice" in line]
+    full_height = re.compile(rf"= u8\[{F},\d+\]\S* ([a-z][a-z0-9-]*)\(")
+    found = [
+        line.strip()[:160] for line in loop
+        if "/split_columns/" in line and (m := full_height.search(line))
+        and m.group(1) not in ("parameter", "get-tuple-element", "bitcast", "tuple")
+    ]
+    assert not found, found
+    # the read itself: slices of one row of the matrix
+    assert re.search(rf"= u8\[1,{N}\]\S* dynamic-slice\(.*/split_columns/", text)
+
+
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$")
 _INSTRUCTION = re.compile(
     r"^\s+(?:ROOT )?%(\S+) = .*? ([a-z][a-z0-9-]*)\((?:%([^\s,)]+))?"
@@ -197,25 +227,31 @@ def _loop_copies_of_a_handed_in_matrix(text, shape_a, shape_b):
 @pytest.mark.parametrize("both_layouts", [True, False])
 def test_a_grower_step_copies_no_bin_matrix(one_chip, spec_pallas, F, both_layouts):
     """The grower's loop carries the bin matrix, and a carried value has one
-    layout. The histogram's segment gathers want it rows major, the
-    partition's take of the 8 split features' columns wants it columns major,
-    and the compiler sinks that take into every branch of the partition's
-    lattice switch: with one matrix for both, every grower step copies the
-    whole of it to the other layout (on a v5e 2.5 ms a step at 968 x 750K,
-    a ninth of an iteration there; PERF.md, PR 31). Handed both layouts as
-    two values, as the serial learner hands them, the loop copies neither;
-    handed one, it shows the copy once a partition branch, so this test is
+    layout. The histogram's segment gathers want it rows major; the
+    partition's take of the 8 split features' columns wanted it columns
+    major, and with one matrix for both every grower step copied the whole
+    of it to the other layout, once a branch of the partition's lattice
+    switch (on a v5e 2.5 ms a step at 968 x 750K, a ninth of an iteration
+    there; PERF.md, PR 31). Handed both layouts as two values, as the serial
+    learner hands them, the loop copies neither. Since the take became 8
+    slices of one row (PERF.md, PR 38) the loop copies no matrix handed one
+    layout either: the compiler relays it out once, before the loop. A copy
+    of the handed-in matrix planted in the loop is found, so this test is
     known to see what it guards. 5000 rows, so that most segment buckets
     have another shape than the table. Nothing runs, and nothing here is a
     device number."""
     N = 5000
     _, text = _compile_grower(one_chip, F, N, with_bins_nf=both_layouts)
     found = _loop_copies_of_a_handed_in_matrix(text, (F, N), (N, F))
-    if both_layouts:
-        assert not found, found
-    else:
-        branches = grow_mod._branch_steps(-(-N // 256) + grow_mod._ENV_SPEC_K)
-        assert len(found) == len(branches), found
+    assert not found, found
+    comps, in_loop = _loop_computations(text)
+    handed = re.compile(rf"\s+%(\S+) = u8\[{F},{N}\]\S* get-tuple-element\(%(\S+)\)")
+    line, name = next(
+        (line, m.group(1)) for comp in sorted(in_loop) for line in comps[comp]
+        if (m := handed.match(line)) and any(
+            re.match(rf"\s+%{re.escape(m.group(2))} = .* parameter\(", d) for d in comps[comp]))
+    planted = text.replace(line, f"{line}\n  %planted = u8[{F},{N}]{{0,1}} copy(%{name})")
+    assert len(_loop_copies_of_a_handed_in_matrix(planted, (F, N), (N, F))) == 1
 
 
 def _bucket_kernels(F, N):
