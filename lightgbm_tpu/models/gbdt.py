@@ -701,40 +701,46 @@ class GBDT:
             grad, hess = self._bagging(self.iter_, grad, hess)
 
         pending = []
+        grower_calls = 0
         for k in range(K):
             tree_arrays = None
             leaf_id = None
-            if self.class_need_train[k] and self.train_set.num_features > 0:
-                # ph.mark records host dispatch time; it only BLOCKS under
-                # the LIGHTGBM_TPU_TIMERS=sync opt-in — an always-on sync
-                # here serialized every phase whenever timing was enabled,
-                # destroying the pipelining being measured (utils/timer.py)
-                with timers.phase("tree growth") as ph:
-                    tree_arrays, leaf_id = self._train_tree(grad[k], hess[k])
-                    ph.mark(tree_arrays)
-            if tree_arrays is not None:
-                nl_dev = tree_arrays.num_leaves
-                with timers.phase("renew+score update") as ph:
-                    # one jitted dispatch: renew + shrink + masked score add
-                    tree_arrays = self._finish_tree(tree_arrays, leaf_id, k, nl_dev)
-                    ph.mark(self.scores)
-                with timers.phase("valid scores"):
-                    self._update_valid_scores(tree_arrays, k)
-                if abs(init_scores[k]) > K_EPSILON:
-                    tree_arrays = tree_arrays._replace(
-                        leaf_value=tree_arrays.leaf_value + self._f32_dev(init_scores[k])
-                    )
-                self._device_trees.append((tree_arrays, k))
-                self.models.append(None)  # lazily converted
-                try:
-                    nl_dev.copy_to_host_async()
-                except AttributeError:
-                    # plain numpy / non-jax arrays have no async copy; the
-                    # blocking int() in _consume_pending_stop still works
-                    pass
-                pending.append((nl_dev, k, init_scores[k]))
-            else:
-                if len(self.models) < K:
+            # more than one class: each class's grow and finish dispatch in
+            # a span of its own (one tree an iteration records none)
+            in_class = (trace_mod.span("train.class_tree", cat="train", class_id=k)
+                        if K > 1 else contextlib.nullcontext())
+            with in_class:
+                if self.class_need_train[k] and self.train_set.num_features > 0:
+                    # ph.mark records host dispatch time; it only BLOCKS under
+                    # the LIGHTGBM_TPU_TIMERS=sync opt-in — an always-on sync
+                    # here serialized every phase whenever timing was enabled,
+                    # destroying the pipelining being measured (utils/timer.py)
+                    with timers.phase("tree growth") as ph:
+                        tree_arrays, leaf_id = self._train_tree(grad[k], hess[k])
+                        grower_calls += 1
+                        ph.mark(tree_arrays)
+                if tree_arrays is not None:
+                    nl_dev = tree_arrays.num_leaves
+                    with timers.phase("renew+score update") as ph:
+                        # one jitted dispatch: renew + shrink + masked score add
+                        tree_arrays = self._finish_tree(tree_arrays, leaf_id, k, nl_dev)
+                        ph.mark(self.scores)
+                    with timers.phase("valid scores"):
+                        self._update_valid_scores(tree_arrays, k)
+                    if abs(init_scores[k]) > K_EPSILON:
+                        tree_arrays = tree_arrays._replace(
+                            leaf_value=tree_arrays.leaf_value + self._f32_dev(init_scores[k])
+                        )
+                    self._device_trees.append((tree_arrays, k))
+                    self.models.append(None)  # lazily converted
+                    try:
+                        nl_dev.copy_to_host_async()
+                    except AttributeError:
+                        # plain numpy / non-jax arrays have no async copy; the
+                        # blocking int() in _consume_pending_stop still works
+                        pass
+                    pending.append((nl_dev, k, init_scores[k]))
+                elif len(self.models) < K:
                     output = 0.0
                     if not self.class_need_train[k]:
                         if self.objective is not None:
@@ -760,6 +766,10 @@ class GBDT:
                     t = Tree(1)
                     self.models.append(t)
                     self._device_trees.append((None, k))
+        if K > 1:
+            # what the class loop did: host integers, no device read
+            trace_mod.counters("class.counters", cat="train", iteration=self.iter_,
+                               classes=K, trees=len(pending), grower_calls=grower_calls)
 
         if pending and not self._defer_stop_check:
             # boosting variants whose _after_train_iter mutates model state

@@ -48,6 +48,12 @@ Span and counter sites (name [cat], where):
     "C"``: a training under ``feature_fraction`` < 1: the span around a tree's
     column draw and its hand-over to the grower, and what was drawn
     (``columns``, ``drawn``; models/gbdt.py ``_draw_columns``)
+  * ``train.class_tree`` [train] and ``class.counters`` [train], ``ph: "C"``:
+    a booster of more than one tree an iteration (``num_class`` > 1): the
+    span around each class's grow and finish dispatch (``class_id``), and
+    what the iteration's class loop did (``classes``, ``trees`` grown,
+    ``grower_calls``: the grower's dispatches; models/gbdt.py
+    ``train_one_iter``). One tree an iteration records neither
   * ``train.boundary`` [train]: from ``update``'s return to the next
     ``train.iteration``, child ``train.callbacks`` (engine._boost_loop)
   * ``grow.counters`` [grow], ``ph: "C"``: the grower's work counters of one
